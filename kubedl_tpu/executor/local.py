@@ -445,6 +445,14 @@ class LocalPodExecutor:
             env[f"KUBEDL_LABEL_{k.upper().replace('-', '_')}"] = v
         if placement is not None:
             env.update(placement.env())
+        on_slice = placement is not None and bool(placement.slice_name)
+        if ((on_slice or container.resources.tpu_chips() > 0)
+                and env.get("JAX_PLATFORMS") != "cpu"):
+            # JAX falls back to the CPU with a warning when it finds no
+            # TPU; a pod that was granted chips must fail instead of
+            # training on the host and reporting success. JAX_PLATFORMS=cpu
+            # said outright (tests, rehearsals) asks for the host and stays.
+            env["JAX_PLATFORMS"] = "tpu,cpu"
         if self.launch_hook is not None:
             env.update(self.launch_hook(pod) or {})
         # volume mounts exported as env so host processes can find them

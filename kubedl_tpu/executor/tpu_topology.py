@@ -165,11 +165,16 @@ class Placement:
     num_workers: int = 1
 
     def env(self) -> Dict[str, str]:
+        """The admitter's grant, under names libtpu does not read. A
+        locally executed pod loads libtpu itself, and libtpu learns the
+        attached chips from the host's own TPU_WORKER_ID / TPU_TOPOLOGY /
+        TPU_WORKER_HOSTNAMES: the pool's slice is a scheduling fact, not
+        a description of this host, and must not overwrite those. The
+        worker's index is KUBEDL_PROCESS_ID; the grid follows from
+        TPU_SLICE_TYPE (parse_slice_type)."""
         return {
-            "TPU_WORKER_ID": str(self.worker_id),
             "TPU_SLICE_NAME": self.slice_name,
             "TPU_SLICE_TYPE": self.slice_type,
-            "TPU_TOPOLOGY": self.topology,
             "TPU_NUM_WORKERS": str(self.num_workers),
         }
 

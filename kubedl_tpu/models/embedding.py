@@ -32,8 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kubedl_tpu.utils.jax_compat import shard_map
-
 from kubedl_tpu.parallel.mesh import BATCH_AXES
 
 # Default mesh axis carrying table rows. "tensor" is the model-parallel axis;
@@ -153,11 +151,12 @@ def sparse_lookup(
         emb = jax.lax.psum(emb, axis)
         return pool(emb, ids_l, w_l)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis, None), ids_spec, ids_spec),
         out_specs=out_spec,
+        check_vma=False,
     )(table, ids, weights)
 
 

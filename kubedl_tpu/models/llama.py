@@ -19,6 +19,7 @@ TPU-first choices:
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -468,8 +469,17 @@ def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
         else:
             attn = ring_attention(q, k, v, mesh=mesh, causal=True)
     elif config.use_flash:
-        attn = flash_attention(q, k, v, causal=True, window=window,
-                               softcap=config.attn_logit_softcap or None)
+        flash = functools.partial(
+            flash_attention, causal=True, window=window,
+            softcap=config.attn_logit_softcap or None)
+        if mesh is not None and mesh.size > 1:
+            # GSPMD cannot partition a Mosaic kernel: each device runs it
+            # on its own batch and head shard (attention mixes neither)
+            spec = rules.spec("batch", "heads", None, None)
+            flash = jax.shard_map(
+                flash, mesh=mesh, in_specs=(spec, spec, spec),
+                out_specs=spec, check_vma=False)
+        attn = flash(q, k, v)
     else:
         from kubedl_tpu.ops.flash_attention import attention_reference
 

@@ -48,8 +48,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from kubedl_tpu.utils.jax_compat import shard_map
-
 from kubedl_tpu.parallel.mesh import ShardingRules
 
 
@@ -631,10 +629,11 @@ def _dropless_mlp_sharded(
         _dropless_shard_fn, top_k=top_k, e=e, e_loc=e_loc, n_e=n_e,
         quota=quota, expert_axis=expert_axis, token_axes=token_axes,
         tensor_axes=mlp_axes, fused=fused, a2a_chunks=a2a_chunks)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(token_axes, None), P()),
+        check_vma=False,
     )(hf, {k: params[k] for k in ("router", "w1", "w3", "w2")})
 
 

@@ -23,7 +23,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kubedl_tpu.utils.jax_compat import tpu_compiler_params
+from kubedl_tpu.ops import interpret
 
 # Swept on v5e (bf16 MXU inputs, causal fwd): at seq 2048, 512/512 hits
 # 53 TF/s vs 47 for 1024/1024 and ~3.5x over 128/128; bigger K/V tiles
@@ -42,33 +42,6 @@ FLASH_MIN_SEQ = 1024
 # parallelism — the streamed path serves long-context inference prefill.
 STREAM_MIN_SEQ = 8192
 NEG_INF = -1e30
-
-_warned_shapes: set = set()
-
-
-def _warn_unfused_fallback(d: int, block_q: int, block_k: int) -> None:
-    """One warning per shape when caller-supplied block sizes are not
-    128-aligned and the call silently degrades to unfused attention — a
-    masked perf regression otherwise invisible on real TPU. (Head dims are
-    lane-aligned by zero-padding, and short sequences dispatch to the
-    unfused path by measured policy, neither of which warns.)"""
-    key = (d, block_q, block_k)
-    if key in _warned_shapes:
-        return
-    _warned_shapes.add(key)
-    import warnings
-
-    warnings.warn(
-        f"flash_attention: caller-supplied blocks ({block_q},{block_k}) not "
-        f"128-aligned for the TPU MXU; falling back to unfused attention",
-        stacklevel=3,
-    )
-
-
-def _interpret() -> bool:
-    """Pallas TPU kernels run in interpret mode on CPU (tests/virtual mesh)."""
-    return jax.default_backend() == "cpu"
-
 
 # ---------------------------------------------------------------------------
 # forward kernel
@@ -183,7 +156,7 @@ def _fwd(q, k, v, sm_scale, causal, window, block_q, block_k, true_len,
             bytes_accessed=q.size * 2 + k.size * 2 + v.size * 2,
             transcendentals=bh * seq * seq,
         ),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(q, k, v)
     return out, lse
 
@@ -275,10 +248,10 @@ def _fwd_streamed(q, k, v, sm_scale, causal, window, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(q, k, v)
     return out, lse
 
@@ -413,7 +386,7 @@ def _bwd(sm_scale, causal, window, block_q, block_k, true_len, res, dout,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(q, k, v, dout, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -435,7 +408,7 @@ def _bwd(sm_scale, causal, window, block_q, block_k, true_len, res, dout,
             jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
         ],
-        interpret=_interpret(),
+        interpret=interpret(),
     )(q, k, v, dout, lse, delta)
     return dq, dk, dv
 
@@ -580,7 +553,7 @@ def flash_attention(
         min_seq = FLASH_MIN_SEQ
     # < 128 can never tile onto the MXU regardless of min_seq (silent: it's
     # a hardware constraint, not a degradation a caller could fix)
-    if not _interpret() and (sq < min_seq or sq < 128):
+    if not interpret() and (sq < min_seq or sq < 128):
         return attention_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                                    window=window, softcap=softcap)
 
@@ -608,15 +581,14 @@ def flash_attention(
     else:
         block_q = block_k = max(sq, 1)
 
-    # Mosaic requires MXU-tileable blocks on real TPU: short sequences
-    # (< 128) take the plain-XLA path — at those sizes the fused kernel
-    # has no advantage anyway. CPU interpret mode is exempt.
-    if not _interpret() and (block_q % 128 or block_k % 128):
-        _warn_unfused_fallback(d, block_q, block_k)
-        return attention_reference(
-            q[..., :d], k[..., :d], v[..., :d], causal=causal,
-            sm_scale=sm_scale, window=window, softcap=softcap,
-        )
+    # Mosaic requires MXU-tileable blocks. The clamp above keeps the
+    # defaults aligned, so only blocks the caller chose can land here;
+    # CPU interpret mode is exempt.
+    if not interpret() and (block_q % 128 or block_k % 128):
+        raise ValueError(
+            f"flash_attention: blocks ({block_q},{block_k}) are not "
+            f"multiples of 128 and cannot tile onto the MXU; pass "
+            f"128-aligned block_q/block_k or leave the defaults")
 
     # The whole-sequence kernels (fwd at <= STREAM_MIN_SEQ, bwd always)
     # budget VMEM for a padded length of at most STREAM_MIN_SEQ. Exotic

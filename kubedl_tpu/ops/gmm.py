@@ -48,8 +48,9 @@ Shared mechanics:
     two pre-activation products in backward (flash-attention-style
     rematerialization) rather than saving them.
 
-Like ops/flash_attention.py, kernels run in interpret mode off-TPU so
-CPU tests exercise the real kernel logic.
+Like ops/flash_attention.py, kernels run in interpret mode on the CPU
+backend only, so CPU tests exercise the real kernel logic and any other
+backend compiles them or raises.
 """
 from __future__ import annotations
 
@@ -61,15 +62,19 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kubedl_tpu.utils.jax_compat import tpu_compiler_params
+from kubedl_tpu.ops import interpret
 
 TILE_M = 128
 _TILE_N = 256
 _TILE_K = 256
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _scale_spec(tn: int) -> pl.BlockSpec:
+    """Block of a per-expert scale carried as [E, 1, N]. Mosaic wants a
+    block's second-to-last dimension divisible by 8 or equal to the
+    array's, which a (1, tn) block of [E, N] is not; scale_ref[0] is
+    then [1, tn] and broadcasts over the tile's rows as [tn] did."""
+    return pl.BlockSpec((1, 1, tn), lambda i, j, kk, te: (te[i], 0, j))
 
 
 def _row_tile_of(m: int, tile_expert, name: str) -> int:
@@ -210,9 +215,9 @@ def _gmm_raw(lhs, rhs, tile_expert, out_scale=None):
         in_specs = [
             pl.BlockSpec((tm, tk), lambda i, j, kk, te: (i, kk)),
             pl.BlockSpec((1, tk, tn), lambda i, j, kk, te: (te[i], kk, j)),
-            pl.BlockSpec((1, tn), lambda i, j, kk, te: (te[i], j)),
+            _scale_spec(tn),
         ]
-        operands = (tile_expert, lhs, rhs, out_scale)
+        operands = (tile_expert, lhs, rhs, out_scale[:, None, :])
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -223,12 +228,12 @@ def _gmm_raw(lhs, rhs, tile_expert, out_scale=None):
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, bytes_accessed=0, transcendentals=0),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(*operands)
 
 
@@ -251,8 +256,8 @@ def _gmm_swiglu_raw(lhs, w1, w3, tile_expert, scale1, scale3):
                 pl.BlockSpec((tm, tk), lambda i, j, kk, te: (i, kk)),
                 pl.BlockSpec((1, tk, tn), lambda i, j, kk, te: (te[i], kk, j)),
                 pl.BlockSpec((1, tk, tn), lambda i, j, kk, te: (te[i], kk, j)),
-                pl.BlockSpec((1, tn), lambda i, j, kk, te: (te[i], j)),
-                pl.BlockSpec((1, tn), lambda i, j, kk, te: (te[i], j)),
+                _scale_spec(tn),
+                _scale_spec(tn),
             ],
             out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk, te: (i, j)),
             scratch_shapes=[
@@ -261,13 +266,13 @@ def _gmm_swiglu_raw(lhs, w1, w3, tile_expert, scale1, scale3):
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
             flops=4 * m * k * n, bytes_accessed=0, transcendentals=m * n),
-        interpret=_interpret(),
-    )(tile_expert, lhs, w1, w3, scale1, scale3)
+        interpret=interpret(),
+    )(tile_expert, lhs, w1, w3, scale1[:, None, :], scale3[:, None, :])
 
 
 # -- transposed (weight-gradient) --------------------------------------------
@@ -309,12 +314,12 @@ def _tgmm_raw(lhs, dout, tile_expert, first_tile, n_experts):
             scratch_shapes=[],
         ),
         out_shape=jax.ShapeDtypeStruct((n_experts, k, n), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, bytes_accessed=0, transcendentals=0),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(tile_expert, first_tile, lhs, dout)
 
 

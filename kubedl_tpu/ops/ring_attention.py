@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kubedl_tpu.utils.jax_compat import shard_map
 
 NEG_INF = -1e30
 
@@ -141,6 +140,7 @@ def ring_attention(
     fn = functools.partial(
         _ring_attention_sharded, axis_name=axis_name, sm_scale=sm_scale, causal=causal
     )
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(q_spec, q_spec, q_spec), out_specs=q_spec,
+        check_vma=False,
     )(q, k, v)

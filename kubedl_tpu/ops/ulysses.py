@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kubedl_tpu.utils.jax_compat import shard_map
 
 
 def _ulysses_sharded(q, k, v, *, axis_name, sm_scale, causal, use_flash):
@@ -89,6 +88,7 @@ def ulysses_attention(
         _ulysses_sharded, axis_name=axis_name, sm_scale=sm_scale,
         causal=causal, use_flash=use_flash,
     )
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(q_spec, q_spec, q_spec), out_specs=q_spec,
+        check_vma=False,
     )(q, k, v)

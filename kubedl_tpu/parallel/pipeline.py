@@ -36,8 +36,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kubedl_tpu.api.validation import validate_pipeline_shapes
-from kubedl_tpu.utils.jax_compat import shard_map
-
 from kubedl_tpu.parallel.mesh import BATCH_AXES
 
 
@@ -195,11 +193,12 @@ def pipeline_apply(
     x_spec = P(None, batch_axes, *([None] * (x_rank - 2)))
     out_spec = P(stage_axis, None, batch_axes, *([None] * (x_rank - 2)))
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         pipelined,
         mesh=mesh,
         in_specs=(params_spec, x_spec),
         out_specs=(out_spec, P()),
+        check_vma=False,
     )(stacked_params, x_microbatches)
     return out[-1], aux
 
@@ -338,11 +337,12 @@ def pipeline_apply_1f1b(
     x_spec = P(None, batch_axes, *([None] * (x_rank - 2)))
     out_spec = P(stage_axis, None, batch_axes, *([None] * (x_rank - 2)))
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         pipelined,
         mesh=mesh,
         in_specs=(params_spec, x_spec),
         out_specs=(out_spec, P()),
+        check_vma=False,
     )(permuted, x_microbatches)
     return out[-1], aux
 

@@ -72,12 +72,23 @@ def make_train_step(
     )
     repl = NamedSharding(mesh, P())
 
+    params_treedef = jax.tree_util.tree_structure(param_sharding)
+
+    def _like_params(node) -> bool:
+        return jax.tree_util.tree_structure(node) == params_treedef
+
     def _init(params):
-        opt_state = tx.init(params)
+        # Optimizer moments have the params' shapes but no data
+        # dependence on them (zeros), so nothing propagates the params'
+        # shardings onto them: left alone they start replicated on every
+        # device, and the step compiles twice (replicated moments in,
+        # sharded out). Pin every params-shaped subtree of the state.
+        opt_state = jax.tree_util.tree_map(
+            lambda node: jax.lax.with_sharding_constraint(node, param_sharding)
+            if _like_params(node) else node,
+            tx.init(params), is_leaf=_like_params)
         return TrainState(params=params, opt_state=opt_state, step=jnp.zeros((), jnp.int32))
 
-    # Optimizer moments have param shapes; with out_shardings unspecified
-    # XLA propagates the params' shardings onto them.
     init_jit = jax.jit(_init, in_shardings=(param_sharding,))
 
     def _step(state: TrainState, batch):

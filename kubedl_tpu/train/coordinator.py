@@ -83,33 +83,69 @@ def _resolve_local(address: str) -> str:
         return f"127.0.0.1:{port or '8471'}"
 
 
-def _honor_platform_env() -> None:
-    """Make JAX_PLATFORMS=cpu authoritative even when a sitecustomize has
-    already pinned a different platform programmatically (config beats env
-    in JAX). Test/CI pods set the env to get the hermetic virtual-device
-    CPU mesh; without this they would silently dial the real accelerator."""
-    want = os.environ.get("JAX_PLATFORMS", "")
-    if want != "cpu":
+# Where JAX_COMPILATION_CACHE_DIR is set (JAXJob spec.compilationCacheDir
+# injects it) JAX reads it itself. Otherwise compiled programs are kept
+# here: one fixed path inside the checkout, because the path is part of
+# the cache key and a pod's working directory is a temporary one.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
+
+
+def place_compile_cache() -> None:
+    """The one place that gives JAX's persistent compile cache a default
+    directory; every training program and bench.py pass through it."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
     import jax
 
-    if (jax.config.jax_platforms or "") == "cpu":
-        return
-    jax.config.update("jax_platforms", "cpu")
-    from jax._src import xla_bridge as xb
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
 
-    if xb.backends_are_initialized():
-        from jax.extend.backend import clear_backends
 
-        clear_backends()
+def report_devices() -> None:
+    """One line saying which devices this process got. JAX falls back to
+    the CPU with a warning when it finds no accelerator, so a pod's log
+    must say where it ran (the executor makes a pod that was granted
+    chips fail in that case: executor/local.py)."""
+    import jax
+
+    try:
+        devices = jax.devices()
+    except RuntimeError as e:
+        if "libtpu" in str(e) and "lockfile" in str(e):
+            # libtpu's own text advises removing its lock file; the lock
+            # is what keeps two processes off one chip
+            raise SystemExit(
+                "another process on this host holds the TPU. A chip "
+                "belongs to one process at a time, and the local executor "
+                "gives every pod that asks for chips all of the host's: "
+                "run one such pod per host at a time (do not remove "
+                f"libtpu's lock file). JAX said: {e}") from e
+        raise
+    print(f"devices: platform={devices[0].platform} "
+          f"device_kind={devices[0].device_kind} count={len(devices)}",
+          flush=True)
+
+
+def start_local() -> None:
+    """initialize() without the rendezvous, for programs that are one JAX
+    process each and meet their peers over the transport plane (MPMD
+    pipeline stages, the RL fleet)."""
+    place_compile_cache()
+    report_devices()
 
 
 def initialize(info: Optional[ProcessInfo] = None) -> ProcessInfo:
     """Idempotently initialize jax.distributed from the injected env."""
-    _honor_platform_env()
     info = info or process_info()
-    if not info.is_distributed or info.coordinator_address is None:
-        return info
+    place_compile_cache()
+    if info.is_distributed and info.coordinator_address is not None:
+        _rendezvous(info)
+    report_devices()
+    return info
+
+
+def _rendezvous(info: ProcessInfo) -> None:
     import jax
 
     addr = _resolve_local(info.coordinator_address)
@@ -126,4 +162,3 @@ def initialize(info: Optional[ProcessInfo] = None) -> ProcessInfo:
     except RuntimeError as e:
         if "already initialized" not in str(e):
             raise
-    return info

@@ -93,9 +93,9 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2  # permanent config error (utils/exit_codes.py)
 
-    from kubedl_tpu.train.coordinator import _honor_platform_env
+    from kubedl_tpu.train import coordinator
 
-    _honor_platform_env()
+    coordinator.start_local()
 
     import jax
     import numpy as np

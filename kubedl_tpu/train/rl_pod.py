@@ -394,9 +394,9 @@ def main(argv=None) -> int:
               f"this entrypoint runs under JAXJob spec.rl",
               file=sys.stderr)
         return 2  # permanent config error
-    from kubedl_tpu.train.coordinator import _honor_platform_env
+    from kubedl_tpu.train import coordinator
 
-    _honor_platform_env()
+    coordinator.start_local()
     try:
         cfg = _rl_env_config(args)
     except ValueError as e:

@@ -408,6 +408,16 @@ def main(argv=None) -> int:
             return EXIT_XLA_COMPILE_ERROR
         raise
 
+    # where the state landed: a sharded job's bytes are spread over its
+    # devices, not sitting on the first (the CPU backend reports none)
+    if jax.local_devices()[0].memory_stats() is not None:
+        # the unsharded init copy is freed only once the init program,
+        # dispatched asynchronously, has ended
+        jax.block_until_ready(state)
+        print("device memory after init: " + " ".join(
+            f"{d.id}={d.memory_stats()['bytes_in_use'] / 2**20:.0f}MiB"
+            for d in mesh.local_devices), flush=True)
+
     # checkpointing (Orbax)
     mngr = None
     start_step = staged[0] if staged is not None else 0
@@ -765,7 +775,7 @@ def main(argv=None) -> int:
         if prof is not None:
             prof.stop()
 
-    jax.device_get(state.step)  # full sync (remote platforms)
+    jax.block_until_ready(state.step)
     total = time.perf_counter() - t_start
     steps_done = args.steps - start_step
     print(f"done: {steps_done} steps in {total:.1f}s "

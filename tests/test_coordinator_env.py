@@ -89,3 +89,58 @@ def test_tf_chief_job_coordinator_is_rank_zero():
     addr, ids, n = coord_contract(store, "tensorflow")
     assert addr.startswith("j-chief-0.")
     assert ids["j-chief-0"] == 0
+
+
+# -- what every training program does before its first JAX call -------------
+
+
+def test_compile_cache_default_is_one_fixed_place_in_the_checkout(monkeypatch):
+    import os
+
+    import jax
+
+    from kubedl_tpu.train import coordinator
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        # set from outside: JAX reads the variable itself, code sets nothing
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        coordinator.place_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == was
+        # not set: one fixed path under the repo root, whatever the cwd
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        monkeypatch.chdir("/")
+        coordinator.place_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_report_devices_names_platform_kind_and_count(capsys):
+    import jax
+
+    from kubedl_tpu.train import coordinator
+
+    coordinator.report_devices()
+    assert capsys.readouterr().out == (
+        f"devices: platform=cpu device_kind=cpu count={len(jax.devices())}\n")
+
+
+def test_chip_held_by_another_process_is_a_readable_failure(monkeypatch):
+    import jax
+
+    from kubedl_tpu.train import coordinator
+
+    def held():
+        raise RuntimeError(
+            "Unable to initialize backend 'tpu': ABORTED: Internal error when "
+            "accessing libtpu multi-process lockfile. Run \"$ sudo rm "
+            "/tmp/libtpu_lockfile\".")
+
+    monkeypatch.setattr(jax, "devices", held)
+    with pytest.raises(SystemExit) as exc:
+        coordinator.report_devices()
+    assert "one process at a time" in str(exc.value)
+    assert "do not remove" in str(exc.value)

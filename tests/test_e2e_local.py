@@ -114,7 +114,11 @@ def test_gang_admission_on_tpu_slice():
         script = (
             "import os, sys, time\n"
             "assert os.environ['TPU_SLICE_TYPE'] == 'v5e-16', os.environ.get('TPU_SLICE_TYPE')\n"
-            "assert os.environ['TPU_WORKER_ID'] == os.environ['KUBEDL_LABEL_REPLICA_INDEX']\n"
+            "assert os.environ['TPU_NUM_WORKERS'] == '2', os.environ.get('TPU_NUM_WORKERS')\n"
+            # names libtpu reads describe the host, not the pool's slice:
+            # the executor passes the host's own through (none are set here)
+            f"assert os.environ.get('TPU_TOPOLOGY') == {os.environ.get('TPU_TOPOLOGY')!r}\n"
+            f"assert os.environ.get('TPU_WORKER_ID') == {os.environ.get('TPU_WORKER_ID')!r}\n"
             "time.sleep(0.5)\n"
             "sys.exit(0)\n"
         )
@@ -134,6 +138,33 @@ def test_gang_admission_on_tpu_slice():
         while op.store.list("PodGroup") and time.monotonic() < deadline:
             time.sleep(0.05)
         assert op.store.list("PodGroup") == []
+    finally:
+        op.stop()
+
+
+@pytest.mark.parametrize("said,chips,want", [
+    (None, 1, "tpu,cpu"),   # granted a chip: no silent fall-back to the host
+    ("cpu", 1, "cpu"),      # cpu said outright (tests, rehearsals) stays
+    (None, 0, None),        # no chips asked for: nothing is imposed
+])
+def test_pod_granted_chips_must_run_on_tpu(monkeypatch, said, chips, want):
+    """JAX falls back to the CPU with a warning when it finds no TPU, so a
+    pod that was granted chips could train on the host and report
+    success. The executor pins such a pod's platform unless its
+    environment asks for the CPU outright."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    if said is not None:
+        monkeypatch.setenv("JAX_PLATFORMS", said)
+    op = make_operator(enable_gang_scheduling=True, tpu_slices=["v5e-1"])
+    try:
+        script = (
+            "import os, sys\n"
+            f"sys.exit(0 if os.environ.get('JAX_PLATFORMS') == {want!r} else 1)\n"
+        )
+        job = op.apply(job_manifest(
+            name="platform-job", workers=1,
+            command=[sys.executable, "-c", script], chips=chips))
+        assert op.wait_for_condition(job, "Succeeded", timeout=30)
     finally:
         op.stop()
 

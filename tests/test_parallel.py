@@ -1,6 +1,8 @@
 """Mesh construction, ring attention vs reference, and the sharded Llama
 train step — all on the 8-virtual-CPU-device mesh (SURVEY.md §4: multi-host
 logic exercised without TPUs)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -108,10 +110,15 @@ def test_llama_loss_decreases_single_device():
     assert losses[-1] < losses[0]
 
 
-def test_llama_sharded_train_step_dp_fsdp_tp():
+@pytest.mark.parametrize("use_flash", [False, True])
+def test_llama_sharded_train_step_dp_fsdp_tp(use_flash):
+    """use_flash=True is the presets' default: GSPMD cannot partition the
+    Mosaic kernel, so on a mesh it runs inside a shard_map over the batch
+    and heads axes (interpret mode here; tests/test_tpu_compile.py holds
+    the same step to the TPU's compiler)."""
     mesh = build_mesh({"data": 2, "fsdp": 2, "tensor": 2})
     rules = ShardingRules()
-    cfg = tiny_cfg()
+    cfg = dataclasses.replace(tiny_cfg(), use_flash=use_flash)
     params = llama.init(cfg, jax.random.PRNGKey(0))
     spec_tree = llama.param_specs(cfg, rules)
 
@@ -123,9 +130,14 @@ def test_llama_sharded_train_step_dp_fsdp_tp():
         loss, tx, mesh, spec_tree, rules.spec("batch", None), rules
     )
     state = init_state(params)
+    # the moments start where the params are, not replicated: else every
+    # device holds them whole and the step compiles a second time
+    mu = state.opt_state[0].mu
+    assert mu["embed"].sharding.spec == rules.spec("vocab", "embed")
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab_size)
+    want = float(llama.loss_fn(params, tokens, tiny_cfg()))
     state, metrics = train_step(state, tokens)
-    assert np.isfinite(float(metrics["loss"]))
+    np.testing.assert_allclose(float(metrics["loss"]), want, rtol=1e-4)
     assert int(state.step) == 1
     # params actually sharded: embed spec P("tensor", "fsdp")
     emb_shard = state.params["embed"].sharding

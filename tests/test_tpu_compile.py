@@ -1,0 +1,212 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler.
+
+Interpret mode (every other kernel test) cannot see what Mosaic refuses:
+block shapes off the (8, 128) tiling, kernels GSPMD cannot partition.
+The compiler is installed here and compiles for a v5e that is described,
+not attached, so these cost no chip time. Nothing runs: a compile that
+passes says nothing about results (chip_smoke.py's kernel phase does).
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load libtpu, and every xdist worker imports
+this file. For the same reason all of these live in this one file.
+"""
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+from kubedl_tpu.models import llama
+from kubedl_tpu.models.moe import _row_tile
+from kubedl_tpu.ops.flash_attention import flash_attention
+from kubedl_tpu.ops.gmm import gmm, gmm_scaled, gmm_swiglu
+from kubedl_tpu.parallel.mesh import ShardingRules, build_mesh
+from kubedl_tpu.parallel.train_step import make_train_step
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one; keep these silent
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The kernels ask jax.default_backend() whether to interpret, and
+    here it still says cpu: steer it in the test, not in the program."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    return compiled.as_text()
+
+
+def _kernels(text: str) -> int:
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("shape,window", [
+    ((4, 16, 2048, 128), None),
+    ((4, 8, 1024, 128), None),
+    ((4, 8, 1024, 128), 256),
+])
+def test_flash_fwd_bwd_compiles(one_chip, on_tpu, shape, window):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def f(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32))
+
+    text = _compile(jax.value_and_grad(f, argnums=(0, 1, 2)), x, x, x)
+    # forward, dq and dk/dv
+    assert _kernels(text) >= 3, "flash fell back to attention_reference"
+
+
+def test_flash_unaligned_blocks_raise(one_chip, on_tpu):
+    x = jax.ShapeDtypeStruct((1, 8, 1024, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        _compile(lambda q: flash_attention(q, q, q, block_q=200), x)
+
+
+# (rows, d, ffn, experts): the bench MoE cell (batch 8 x 1,024 tokens,
+# top-2 of 4) and a 64-expert shape (OLMoE widths, top-8)
+GMM_SHAPES = [(16384, 1024, 2816, 4), (65536, 2048, 1024, 64)]
+
+
+def _gmm_args(one_chip, rows, d, ffn, e):
+    tile = _row_tile(rows, e)
+    m = rows + e * tile
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    return dict(
+        tile=tile,
+        x=sds((m, d), jnp.bfloat16),
+        w=sds((e, d, ffn), jnp.bfloat16),
+        q=sds((e, d, ffn), jnp.int8),
+        s=sds((e, ffn), jnp.float32),
+        te=sds((m // tile,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("rows,d,ffn,e", GMM_SHAPES)
+def test_gmm_fwd_bwd_compiles(one_chip, on_tpu, rows, d, ffn, e):
+    a = _gmm_args(one_chip, rows, d, ffn, e)
+
+    def f(x, w, te):
+        return jnp.sum(gmm(x, w, te, row_tile=a["tile"]).astype(jnp.float32))
+
+    text = _compile(jax.value_and_grad(f, argnums=(0, 1)), a["x"], a["w"], a["te"])
+    assert _kernels(text) >= 3  # gmm, dlhs gmm, tgmm
+
+
+@pytest.mark.parametrize("rows,d,ffn,e", GMM_SHAPES)
+def test_gmm_swiglu_fwd_bwd_compiles(one_chip, on_tpu, rows, d, ffn, e):
+    a = _gmm_args(one_chip, rows, d, ffn, e)
+
+    def f(x, w1, w3, te, s1, s3):
+        return jnp.sum(gmm_swiglu(
+            x, w1, w3, te, s1, s3, row_tile=a["tile"]).astype(jnp.float32))
+
+    text = _compile(jax.value_and_grad(f, argnums=(0, 1, 2)),
+                    a["x"], a["w"], a["w"], a["te"], a["s"], a["s"])
+    assert _kernels(text) >= 7  # fused fwd + 2 remat + 2 dlhs + 2 tgmm
+
+
+@pytest.mark.parametrize("rows,d,ffn,e", GMM_SHAPES)
+def test_gmm_scaled_int8_fwd_bwd_compiles(one_chip, on_tpu, rows, d, ffn, e):
+    a = _gmm_args(one_chip, rows, d, ffn, e)
+
+    def f(x, q, te, s):
+        return jnp.sum(gmm_scaled(
+            x, q.astype(x.dtype), te, s,
+            row_tile=a["tile"]).astype(jnp.float32))
+
+    text = _compile(jax.value_and_grad(f, argnums=(0, 3)),
+                    a["x"], a["q"], a["te"], a["s"])
+    assert _kernels(text) >= 2  # scaled fwd + dlhs gmm
+
+
+# loss_fn trains on tokens[:, :-1]: 1,025 gives the model 1,024, the
+# shortest sequence flash_attention takes on a TPU (FLASH_MIN_SEQ); at
+# 1,024 the model sees 1,023 and the step holds no flash kernel at all
+SEQ_LEN = 1025
+
+
+def _abstract_params(config, sharding_of):
+    shapes = jax.eval_shape(
+        lambda k: llama.init(config, k), jax.random.PRNGKey(0))
+    return jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, sharding_of(shapes))
+
+
+def test_moe_loss_default_fused_compiles(one_chip, on_tpu):
+    """llama.loss_fn on the bench MoE widths (150M backbone, 4 experts,
+    top-2) with moe_fused left at its default; depth cut to two layers."""
+    config = llama.LlamaConfig.bench_150m(
+        n_layers=2, n_experts=4, expert_top_k=2)
+    assert config.moe_fused is None
+    params = _abstract_params(
+        config, lambda t: jax.tree_util.tree_map(lambda _: one_chip, t))
+    tokens = jax.ShapeDtypeStruct((8, SEQ_LEN), jnp.int32, sharding=one_chip)
+    text = _compile(
+        jax.value_and_grad(lambda p, t: llama.loss_fn(p, t, config)),
+        params, tokens)
+    assert "gmm_swiglu" in text
+    assert _kernels(text) >= 10
+
+
+def test_sharded_train_step_with_flash_compiles(topo, on_tpu):
+    """bench-1b widths under fsdp: 4 on the described 2x2 mesh, flash
+    attention on: Mosaic kernels cannot be partitioned by GSPMD, so this
+    compiles only while flash sits inside a shard_map. Depth cut to two
+    layers; batch 8 x 1,025 as chip_smoke.py --chips 4 runs it."""
+    config = llama.LlamaConfig.bench_1b(n_layers=2)
+    assert config.use_flash
+    mesh = build_mesh({"fsdp": 4}, devices=topo.devices)
+    rules = ShardingRules()
+    spec_tree = llama.param_specs(config, rules)
+    init_state, train_step = make_train_step(
+        lambda p, t: llama.loss_fn(p, t, config, mesh=mesh, rules=rules),
+        optax.adamw(3e-4), mesh, spec_tree, rules.spec("batch", None), rules)
+    params = _abstract_params(
+        config, lambda t: jax.tree_util.tree_map(
+            lambda s: NamedSharding(mesh, s), spec_tree))
+    state = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(init_state.jit, params),
+        init_state.jit.lower(params).compile().output_shardings)
+    tokens = jax.ShapeDtypeStruct(
+        (8, SEQ_LEN), jnp.int32,
+        sharding=NamedSharding(mesh, rules.spec("batch", None)))
+    compiled = train_step.lower(state, tokens).compile()
+    text = compiled.as_text()
+    assert _kernels(text) >= 3, "flash is not in the sharded step"
+    assert "all-gather" in text or "all-reduce" in text
+    # the parameters are spread: one device holds about a quarter
+    ma = compiled.memory_analysis()
+    n_bytes = sum(
+        x.size * x.dtype.itemsize
+        for x in jax.tree_util.tree_leaves(state))
+    assert ma.argument_size_in_bytes < 0.3 * n_bytes
