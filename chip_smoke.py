@@ -335,7 +335,7 @@ def train_job(op: Operator, workdir: str, name: str, pool: str, chips: int,
         "memory": re.findall(r"(\d+)=(\d+)MiB", "".join(
             re.findall(r"^device memory after init: (.*)$", log, re.M))),
         # JAX_LOG_COMPILES=1 (set in the manifest) makes JAX say so
-        "step_from_cache": "Persistent compilation cache hit for 'jit__step'" in log,
+        "step_from_cache": "Persistent compilation cache hit for 'jit_train_step'" in log,
     }
     print(f"{name}: devices {out['device']}")
     print(f"{name}: first step (compile"

@@ -1,0 +1,248 @@
+#!/usr/bin/env python3
+"""Device time by named scope, read by hand from one profiler trace.
+
+    python hack/scope_shares.py <trace dir or .xplane.pb> [out.json]
+
+The model's and the step's `jax.named_scope`s (embed, attn > attn_core,
+mlp, head_loss, optimizer, grad_norm: PERF.md section 3) reach each
+device operation's `op_name`, not its name. On a TPU the profiler keeps
+the `op_name` as the stat `tf_op` of the operation's *event metadata*,
+which `jax.profiler.ProfileData` (jax 0.9) does not hand out: its
+`event.stats` holds only `device_offset_ps`, `device_duration_ps` and
+`Time Scale Multiplier`. So this reads the `.xplane.pb` itself, with a
+protobuf wire reader of a few lines and the field numbers of
+`xplane.proto` below, and nothing but the standard library. Until a
+`benchmark` PR hands reducers the `op_name` (PERF.md Open question 13),
+this is how the per-scope shares of PERF.md section 5 are read.
+
+Per device plane it prints the device time of each scope as a share of
+the traced window (first operation's start to the last one's end), the
+share that is remat recompute (`rematted_computation` in the `op_name`),
+what no scope covers, each named kernel's calls and time, the runs of
+each program on the modules line, and the host's `train.*` / `bench.*`
+spans with the device gaps that fall under each.
+"""
+from __future__ import annotations
+
+import glob
+import json
+import os
+import re
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks import trace as tr  # interval arithmetic only; no JAX
+
+# innermost first: an operation under attn/attn_core counts as attn_core
+SCOPES = ("attn_core", "attn", "mlp", "head_loss", "embed", "optimizer",
+          "grad_norm")
+DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+HOST_SPAN = re.compile(r"^(train|bench|ckpt|reshard)\.|^train$")
+OP_NAME_STAT = "tf_op"
+
+
+def scope_of(op_name: str) -> str:
+    """A scope is one component of the name stack, bare or wrapped by a
+    transformation: mlp, jvp(mlp), transpose(jvp(mlp)), checkpoint/mlp."""
+    for scope in SCOPES:
+        if re.search(rf"(?:^|[/(]){scope}(?:[/)]|$)", op_name):
+            return scope
+    return "unscoped"
+
+
+# -- the protobuf wire format, as far as xplane.proto needs it ---------------
+
+
+def fields(buf: bytes):
+    """(field number, wire type, value) of one message: varints as ints,
+    length-delimited fields as bytes, fixed-width ones as their bytes."""
+    i, n = 0, len(buf)
+
+    def varint():
+        nonlocal i
+        val = shift = 0
+        while True:
+            b = buf[i]
+            i += 1
+            val |= (b & 0x7F) << shift
+            shift += 7
+            if b < 0x80:
+                return val
+
+    while i < n:
+        key = varint()
+        num, wire = key >> 3, key & 7
+        if wire == 0:
+            yield num, wire, varint()
+            continue
+        if wire not in (1, 2, 5):
+            raise ValueError(f"wire type {wire} is not in xplane.proto")
+        size = varint() if wire == 2 else 8 if wire == 1 else 4
+        yield num, wire, buf[i:i + size]
+        i += size
+
+
+def first(msg: bytes, num: int, default=None):
+    for n, _, v in fields(msg):
+        if n == num:
+            return v
+    return default
+
+
+def read_plane(buf: bytes) -> dict:
+    """XPlane: name=2, lines=3, event_metadata=4, stat_metadata=5 (both
+    maps: key=1, value=2). XLine: name=2, timestamp_ns=3, events=4.
+    XEvent: metadata_id=1, offset_ps=2, duration_ps=3. XEventMetadata:
+    name=2, stats=5. XStat: metadata_id=1, str_value=5, ref_value=7.
+    XStatMetadata: name=2."""
+    plane = {"name": "", "lines": [], "events_meta": {}, "stat_names": {}}
+    raw_meta = {}
+    for num, _, val in fields(buf):
+        if num == 2:
+            plane["name"] = val.decode()
+        elif num == 3:
+            plane["lines"].append(val)
+        elif num == 4:
+            raw_meta[first(val, 1, 0)] = first(val, 2, b"")
+        elif num == 5:
+            plane["stat_names"][first(val, 1, 0)] = (
+                first(first(val, 2, b""), 2, b"").decode())
+    for mid, meta in raw_meta.items():
+        stats = {}
+        for num, _, val in fields(meta):
+            if num != 5:
+                continue
+            name = plane["stat_names"].get(first(val, 1, 0), "")
+            text = first(val, 5)
+            if text is None and first(val, 7) is not None:
+                text = plane["stat_names"].get(first(val, 7), "").encode()
+            if text is not None:
+                stats[name] = text.decode(errors="replace")
+        plane["events_meta"][mid] = (first(meta, 2, b"").decode(errors="replace"), stats)
+    return plane
+
+
+def line_events(plane: dict, line: bytes):
+    """(name, stats of the metadata, start_ns, duration_ns) of a line."""
+    t0_ns = first(line, 3, 0)
+    for num, _, val in fields(line):
+        if num != 4:
+            continue
+        name, stats = plane["events_meta"].get(first(val, 1, 0), ("", {}))
+        yield (name, stats, t0_ns + first(val, 2, 0) // 1000,
+               first(val, 3, 0) // 1000)
+
+
+def find_xplane(path: str) -> str:
+    if os.path.isdir(path):
+        found = sorted(glob.glob(os.path.join(
+            path, "**", "*.xplane.pb"), recursive=True))
+        if not found:
+            raise SystemExit(f"no .xplane.pb under {path}")
+        return found[-1]
+    return path
+
+
+def summarize(path: str) -> dict:
+    path = find_xplane(path)
+    with open(path, "rb") as f:
+        planes = [read_plane(v) for n, _, v in fields(f.read()) if n == 1]
+    out = {"file": path, "op_name_stat": OP_NAME_STAT, "devices": [],
+           "host_spans": {}}
+    gaps_of_first = None
+    for plane in planes:
+        if not DEVICE_PLANE.match(plane["name"]):
+            continue
+        dev = {"plane": plane["name"]}
+        for line in plane["lines"]:
+            line_name = first(line, 2, b"").decode()
+            if line_name == "XLA Modules":
+                runs = {}
+                for name, _, _, dur in line_events(plane, line):
+                    runs.setdefault(re.sub(r"\(\d+\)$", "", name), []).append(dur / 1e6)
+                dev["modules_ms"] = runs
+            if line_name != "XLA Ops":
+                continue
+            by_scope, kernels, spans = {}, {}, []
+            remat = named = 0
+            for name, stats, start, dur in line_events(plane, line):
+                if dur <= 0:
+                    continue
+                spans.append((start, start + dur))
+                op_name = stats.get(OP_NAME_STAT, "")
+                named += bool(op_name)
+                scope = scope_of(op_name)
+                by_scope[scope] = by_scope.get(scope, 0) + dur
+                if "rematted_computation" in op_name:
+                    remat += dur
+                m = re.match(r"^%((?:flash|gmm)\w*?)\.\d+ = ", name)
+                if m:
+                    k = kernels.setdefault(m[1], [0, 0])
+                    k[0] += 1
+                    k[1] += dur
+            if not spans:
+                continue
+            busy = tr.union(spans)
+            window = busy[-1][1] - busy[0][0]
+            dev.update({
+                "events": len(spans), "events_with_op_name": named,
+                "window_s": window / 1e9,
+                "busy_share": tr.length(busy) / window,
+                "share_of_window": {s: d / window for s, d in sorted(
+                    by_scope.items(), key=lambda kv: -kv[1])},
+                "remat_recompute_share": remat / window,
+                "kernels": {k: {"calls": c, "seconds": d / 1e9,
+                                "ms_a_call": d / c / 1e6}
+                            for k, (c, d) in sorted(kernels.items())},
+            })
+            if gaps_of_first is None:
+                gaps_of_first = tr.gaps(busy)
+        if "share_of_window" in dev:
+            out["devices"].append(dev)
+    gaps_of_first = gaps_of_first or []
+    for plane in planes:
+        if plane["name"] != "/host:CPU":
+            continue
+        for line in plane["lines"]:
+            for name, _, start, dur in line_events(plane, line):
+                if not HOST_SPAN.search(name):
+                    continue
+                h = out["host_spans"].setdefault(
+                    name, {"count": 0, "seconds": 0.0, "device_gap_s": 0.0})
+                h["count"] += 1
+                h["seconds"] += dur / 1e9
+                h["device_gap_s"] += sum(
+                    tr.overlap(gap, (start, start + dur))
+                    for gap in gaps_of_first) / 1e9
+    out["device_gap_s_first_device"] = tr.length(gaps_of_first) / 1e9
+    if out["devices"]:
+        n = len(out["devices"])
+        mean = {}
+        for dev in out["devices"]:
+            for s, v in dev["share_of_window"].items():
+                mean[s] = mean.get(s, 0.0) + v / n
+        out["mean_share_of_window"] = dict(sorted(mean.items(), key=lambda kv: -kv[1]))
+        out["mean_remat_recompute_share"] = sum(
+            d["remat_recompute_share"] for d in out["devices"]) / n
+    return out
+
+
+def main(argv) -> int:
+    if not argv:
+        print(__doc__, file=sys.stderr)
+        return 2
+    text = json.dumps(summarize(argv[0]), indent=1)
+    if len(argv) > 1:
+        os.makedirs(os.path.dirname(os.path.abspath(argv[1])) or ".", exist_ok=True)
+        with open(argv[1], "w") as f:
+            f.write(text)
+    else:
+        print(text)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -434,6 +434,54 @@ def _proj(h, layer, name, lora=None, adapter_ids=None):
     return out
 
 
+# The named scopes below (embed, attn > attn_core, mlp, head_loss) reach
+# each instruction's op_name in a profile, the same names whatever
+# implements the work, so a share read by scope compares a flash step with
+# a plain-XLA one. They are metadata: no sharding, remat boundary or
+# instruction name moves with them (PERF.md section 3).
+
+
+@jax.named_scope("attn_core")
+def _attention_core(q, k, v, config: LlamaConfig, mesh, rules, context_size,
+                    window):
+    """softmax(q k^T) v over [b, heads, t, head_dim] by whichever of ring,
+    ulysses, flash or plain XLA the config and the mesh select."""
+    softcap = config.attn_logit_softcap or None
+    if context_size > 1:
+        if config.has_windows:
+            raise NotImplementedError(
+                "sliding_window + context parallelism is not implemented "
+                "(a windowed ring would skip most hops; use full attention "
+                "on the context mesh or a single-shard windowed model)")
+        if config.attn_logit_softcap:
+            raise NotImplementedError(
+                "attn_logit_softcap + context parallelism is not "
+                "implemented (the ring/all-to-all paths run uncapped "
+                "online softmax)")
+        if config.context_parallel == "ulysses":
+            from kubedl_tpu.ops.ulysses import ulysses_attention
+
+            return ulysses_attention(
+                q, k, v, mesh=mesh, causal=True, use_flash=config.use_flash)
+        return ring_attention(q, k, v, mesh=mesh, causal=True)
+    if config.use_flash:
+        flash = functools.partial(
+            flash_attention, causal=True, window=window, softcap=softcap)
+        if mesh is not None and mesh.size > 1:
+            # GSPMD cannot partition a Mosaic kernel: each device runs it
+            # on its own batch and head shard (attention mixes neither)
+            spec = rules.spec("batch", "heads", None, None)
+            flash = jax.shard_map(
+                flash, mesh=mesh, in_specs=(spec, spec, spec),
+                out_specs=spec, check_vma=False)
+        return flash(q, k, v)
+    from kubedl_tpu.ops.flash_attention import attention_reference
+
+    return attention_reference(q, k, v, causal=True, window=window,
+                               softcap=softcap)
+
+
+@jax.named_scope("attn")
 def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
                      context_size, window=None):
     b, t, d = x.shape
@@ -450,41 +498,7 @@ def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
         rep = nq // nkv
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
-    if context_size > 1:
-        if config.has_windows:
-            raise NotImplementedError(
-                "sliding_window + context parallelism is not implemented "
-                "(a windowed ring would skip most hops; use full attention "
-                "on the context mesh or a single-shard windowed model)")
-        if config.attn_logit_softcap:
-            raise NotImplementedError(
-                "attn_logit_softcap + context parallelism is not "
-                "implemented (the ring/all-to-all paths run uncapped "
-                "online softmax)")
-        if config.context_parallel == "ulysses":
-            from kubedl_tpu.ops.ulysses import ulysses_attention
-
-            attn = ulysses_attention(
-                q, k, v, mesh=mesh, causal=True, use_flash=config.use_flash)
-        else:
-            attn = ring_attention(q, k, v, mesh=mesh, causal=True)
-    elif config.use_flash:
-        flash = functools.partial(
-            flash_attention, causal=True, window=window,
-            softcap=config.attn_logit_softcap or None)
-        if mesh is not None and mesh.size > 1:
-            # GSPMD cannot partition a Mosaic kernel: each device runs it
-            # on its own batch and head shard (attention mixes neither)
-            spec = rules.spec("batch", "heads", None, None)
-            flash = jax.shard_map(
-                flash, mesh=mesh, in_specs=(spec, spec, spec),
-                out_specs=spec, check_vma=False)
-        attn = flash(q, k, v)
-    else:
-        from kubedl_tpu.ops.flash_attention import attention_reference
-
-        attn = attention_reference(q, k, v, causal=True, window=window,
-                                   softcap=config.attn_logit_softcap or None)
+    attn = _attention_core(q, k, v, config, mesh, rules, context_size, window)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, t, nq * hd)
     out = _mm(attn, layer["wo"]).astype(x.dtype)
     if "post_attn_norm" in layer:
@@ -493,6 +507,7 @@ def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
     return x + out
 
 
+@jax.named_scope("mlp")
 def _mlp_block(x, layer, config: LlamaConfig, mesh=None, rules=None,
                lora=None, adapter_ids=None):
     """Dense or MoE FFN; returns (out, aux_loss). lora/adapter_ids:
@@ -546,11 +561,12 @@ def _backbone(
     # output inherits a feature-dim sharding forces SPMD into an involuntary
     # full rematerialization when the result is then batch-sharded; with the
     # embed dim unsharded the output reshards by a cheap dynamic-slice.
-    tbl = constrain(params["embed"], "vocab", None)
-    x = tbl[tokens].astype(config.dtype)
-    if config.embed_scale != 1.0:
-        x = x * jnp.asarray(config.embed_scale, config.dtype)
-    x = constrain(x, "batch", "seq", None)
+    with jax.named_scope("embed"):
+        tbl = constrain(params["embed"], "vocab", None)
+        x = tbl[tokens].astype(config.dtype)
+        if config.embed_scale != 1.0:
+            x = x * jnp.asarray(config.embed_scale, config.dtype)
+        x = constrain(x, "batch", "seq", None)
 
     def make_layer_fn(window):
         # window is trace-time static (it selects the attention mask
@@ -602,6 +618,7 @@ def _head_matrix(params, config: LlamaConfig):
     return head
 
 
+@jax.named_scope("head_loss")
 def _lm_head(x, params, config: LlamaConfig) -> jax.Array:
     """Final norm + (tied or separate) LM head -> f32 logits."""
     x = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
@@ -611,12 +628,14 @@ def _lm_head(x, params, config: LlamaConfig) -> jax.Array:
     return logits
 
 
+@jax.named_scope("head_loss")
 def _next_token_ce(logits, targets):
     logp = jax.nn.log_softmax(logits, axis=-1)
     ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     return -jnp.mean(ll)
 
 
+@jax.named_scope("head_loss")
 def _next_token_ce_chunked(x, params, config: LlamaConfig, targets, n_chunks: int):
     """CE without materializing [b, t, V] f32 logits.
 
@@ -790,7 +809,8 @@ def forward_pipelined_and_aux(
     rules = rules or ShardingRules()
     layer_fn = pipeline_layer_fn(config, tokens.shape[1], rules)
 
-    x = params["embed"][tokens].astype(config.dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(config.dtype)
     x = pipeline.microbatch(x, n_microbatches)
     if schedule == "1f1b":
         y, aux = pipeline.pipeline_apply_1f1b(

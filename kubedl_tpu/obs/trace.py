@@ -13,6 +13,14 @@ wall clock, which is the shared axis that lets the operator process and
 its workload pods — separate OS processes on the local executor — merge
 into one timeline.
 
+A span used as a context manager is also a ``jax.profiler.TraceAnnotation``
+of the same name while it is open, in a process that has imported ``jax``
+already (this module never imports it: the operator stays off JAX). With
+no profiler session an annotation is a no-op; under one (the trainers'
+``--profile-dir`` window) the span lies on the profiler's clock beside the
+device's operations, so a gap on the device has a name. ``record()`` and
+``span().end()`` without ``with`` have no open interval to mirror.
+
 Correlation works the way ``KUBEDL_CONTROL_DIR`` already travels: the
 executor derives a deterministic gang-level trace id from the job key and
 injects ``KUBEDL_TRACE_ID`` + a per-job ``KUBEDL_TRACE_DIR`` into every
@@ -26,6 +34,7 @@ import hashlib
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -54,13 +63,17 @@ def job_trace_dir(root: str, namespace: str, name: str) -> str:
 
 class Span:
     """One open span; finishes on end() or context-manager exit (an
-    exception stamps an ``error`` attribute before closing)."""
+    exception stamps an ``error`` attribute before closing). ``dur`` is
+    None until then. ``export=False`` keeps the record in the ring and
+    out of the JSONL (sub-step spans that the profiler shows and whose
+    durations ride on another record)."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "service",
-                 "ts", "attrs", "_tracer", "_t0", "_done")
+                 "ts", "attrs", "dur", "export", "_tracer", "_t0", "_done",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
-                 parent_id: str, attrs: Dict) -> None:
+                 parent_id: str, attrs: Dict, export: bool = True) -> None:
         self._tracer = tracer
         self.name = name
         self.trace_id = trace_id
@@ -70,7 +83,10 @@ class Span:
         self.ts = time.time()
         self._t0 = time.perf_counter()
         self.attrs = dict(attrs)
+        self.dur: Optional[float] = None
+        self.export = export
         self._done = False
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -80,14 +96,21 @@ class Span:
         if self._done:
             return {}
         self._done = True
-        dur = time.perf_counter() - self._t0
-        return self._tracer._finish(self, dur)
+        self.dur = time.perf_counter() - self._t0
+        return self._tracer._finish(self, self.dur)
 
     def __enter__(self) -> "Span":
         self._tracer._push(self)
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         self._tracer._pop(self)
         if exc is not None:
             self.attrs.setdefault("error", f"{type(exc).__name__}: {exc}"[:200])
@@ -162,11 +185,14 @@ class Tracer:
         st = self._stack()
         return st[-1] if st else None
 
-    def span(self, name: str, trace_id: Optional[str] = None, **attrs) -> Span:
+    def span(self, name: str, trace_id: Optional[str] = None,
+             export: bool = True, **attrs) -> Span:
         """Open a span (use as a context manager for nesting: the parent
         is whatever span the calling thread currently has open). Children
         inherit the parent's trace id and job/namespace routing attrs, so
-        a nested span lands in the same per-job file."""
+        a nested span lands in the same per-job file. Under ``with`` the
+        span is mirrored into an open JAX profiler session (module
+        docstring); ``export=False`` keeps it out of the JSONL."""
         parent = self.current()
         if parent is not None:
             for key in ("job", "namespace"):
@@ -176,7 +202,7 @@ class Tracer:
             self, name,
             trace_id=trace_id or (parent.trace_id if parent else "") or self.trace_id,
             parent_id=parent.span_id if parent else "",
-            attrs=attrs,
+            attrs=attrs, export=export,
         )
 
     def record(
@@ -189,7 +215,9 @@ class Tracer:
     ) -> Dict:
         """Retroactively record a finished interval (e.g. a queue wait
         measured from monotonic timestamps): ``ts`` is back-dated so the
-        span COVERS the interval that just ended."""
+        span COVERS the interval that just ended. An interval that is
+        already over cannot be mirrored into a profiler session: what
+        the profiler should show is opened with ``span()``."""
         end_ts = time.time() if end_ts is None else end_ts
         rec = {
             "name": name,
@@ -215,15 +243,15 @@ class Tracer:
             "dur": dur,
             "attrs": span.attrs,
         }
-        self._commit(rec)
+        self._commit(rec, export=span.export)
         return rec
 
     # -- sinks -----------------------------------------------------------
 
-    def _commit(self, rec: Dict) -> None:
+    def _commit(self, rec: Dict, export: bool = True) -> None:
         with self._lock:
             self._ring.append(rec)
-            path = self._path_for(rec)
+            path = self._path_for(rec) if export else None
             if path is None:
                 return
             if self._exported.get(path, 0) >= self.max_export_spans:

@@ -10,6 +10,10 @@ ring attention builds on — ops/ring_attention.py).
 Layout: [batch*heads, seq, head_dim]. The public entry handles GQA by
 broadcasting KV heads, pads ragged sequence lengths to block multiples, and
 installs a custom VJP wiring the two kernels together.
+
+Each `pallas_call` carries a fixed `name=`, which the device trace shows as
+the event's name: `flash_fwd`, `flash_fwd_streamed`, `flash_bwd_dq`,
+`flash_bwd_dkv`. The benchmark's by-name metrics read them (PERF.md section 3).
 """
 from __future__ import annotations
 
@@ -157,6 +161,7 @@ def _fwd(q, k, v, sm_scale, causal, window, block_q, block_k, true_len,
             transcendentals=bh * seq * seq,
         ),
         interpret=interpret(),
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -252,6 +257,7 @@ def _fwd_streamed(q, k, v, sm_scale, causal, window, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret(),
+        name="flash_fwd_streamed",
     )(q, k, v)
     return out, lse
 
@@ -387,6 +393,7 @@ def _bwd(sm_scale, causal, window, block_q, block_k, true_len, res, dout,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
         interpret=interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, dout, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -409,6 +416,7 @@ def _bwd(sm_scale, causal, window, block_q, block_k, true_len, res, dout,
             jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
         ],
         interpret=interpret(),
+        name="flash_bwd_dkv",
     )(q, k, v, dout, lse, delta)
     return dq, dk, dv
 
@@ -611,8 +619,13 @@ def flash_attention(
     qf = _pad_seq_to(q.reshape(b * hq, sq, dk), target)
     kf = _pad_seq_to(k.reshape(b * hq, sq, dk), target)
     vf = _pad_seq_to(v.reshape(b * hq, sq, dk), target)
-    out = _flash(qf, kf, vf, sm_scale, causal, window, block_q, block_k,
-                 sq, d, softcap)
+    # A kernel's HLO instruction takes the innermost name on the stack.
+    # Under this scope that is always the kernel's own name= (%flash_fwd.N,
+    # %flash_bwd_dq.N); without one a bare jax.grad of this function would
+    # wrap it (jvp(flash_fwd) reads %jvp_flash_fwd_.N).
+    with jax.named_scope("flash_attention"):
+        out = _flash(qf, kf, vf, sm_scale, causal, window, block_q, block_k,
+                     sq, d, softcap)
     return out[:, :sq, :d].reshape(b, hq, sq, d)
 
 

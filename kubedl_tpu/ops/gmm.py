@@ -46,7 +46,14 @@ Shared mechanics:
     accumulating row-tiles into the owning expert's [K, N] block
     (zeroed on the group's first tile). `gmm_swiglu` recomputes the
     two pre-activation products in backward (flash-attention-style
-    rematerialization) rather than saving them.
+    rematerialization) rather than saving them;
+  * each `pallas_call` carries a fixed `name=` that the device trace
+    shows as the event's name: `gmm`, `gmm_scaled`, `gmm_swiglu` and
+    `gmm_drhs` (the `tgmm` weight gradient). dlhs is the `gmm` kernel
+    again; its `op_name` holds `transpose(jvp(` and the forward's does
+    not. The public entries open a `grouped_matmul` scope, so that the
+    innermost name on the stack, which the HLO instruction takes, is the
+    kernel's own also under a bare `jax.grad` (flash_attention.py).
 
 Like ops/flash_attention.py, kernels run in interpret mode on the CPU
 backend only, so CPU tests exercise the real kernel logic and any other
@@ -234,6 +241,7 @@ def _gmm_raw(lhs, rhs, tile_expert, out_scale=None):
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, bytes_accessed=0, transcendentals=0),
         interpret=interpret(),
+        name="gmm" if out_scale is None else "gmm_scaled",
     )(*operands)
 
 
@@ -272,6 +280,7 @@ def _gmm_swiglu_raw(lhs, w1, w3, tile_expert, scale1, scale3):
         cost_estimate=pl.CostEstimate(
             flops=4 * m * k * n, bytes_accessed=0, transcendentals=m * n),
         interpret=interpret(),
+        name="gmm_swiglu",
     )(tile_expert, lhs, w1, w3, scale1[:, None, :], scale3[:, None, :])
 
 
@@ -320,6 +329,7 @@ def _tgmm_raw(lhs, dout, tile_expert, first_tile, n_experts):
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, bytes_accessed=0, transcendentals=0),
         interpret=interpret(),
+        name="gmm_drhs",
     )(tile_expert, first_tile, lhs, dout)
 
 
@@ -416,6 +426,7 @@ def _check_row_tile(m: int, tile_expert, row_tile: int, name: str) -> None:
             "expert's weights")
 
 
+@jax.named_scope("grouped_matmul")
 def gmm(lhs, rhs, tile_expert, *, row_tile: int = TILE_M):
     """[M, K] x [E, K, N] -> [M, N], weight chosen per row-tile.
 
@@ -460,6 +471,7 @@ def _gmm_scaled_bwd(res, dout):
 _gmm_scaled_vjp.defvjp(_gmm_scaled_fwd, _gmm_scaled_bwd)
 
 
+@jax.named_scope("grouped_matmul")
 def gmm_scaled(lhs, rhs, tile_expert, out_scale, *, row_tile: int = TILE_M):
     """gmm with a per-expert output scale: out[i] = (lhs[i] @
     rhs[te[i]]) * out_scale[te[i]], the scale ([E, N], per output
@@ -517,6 +529,7 @@ def _gmm_swiglu_bwd(res, dout):
 _gmm_swiglu_vjp.defvjp(_gmm_swiglu_fwd, _gmm_swiglu_bwd)
 
 
+@jax.named_scope("grouped_matmul")
 def gmm_swiglu(lhs, w1, w3, tile_expert, scale1, scale3, *,
                row_tile: int = TILE_M):
     """Fused grouped SwiGLU front half:

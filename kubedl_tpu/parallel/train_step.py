@@ -77,7 +77,10 @@ def make_train_step(
     def _like_params(node) -> bool:
         return jax.tree_util.tree_structure(node) == params_treedef
 
-    def _init(params):
+    # the inner functions' names are what a profile shows: the device's
+    # "XLA Modules" line reads jit_train_step / jit_init_state, the host's
+    # PjitFunction(train_step) (PERF.md section 3)
+    def init_state(params):
         # Optimizer moments have the params' shapes but no data
         # dependence on them (zeros), so nothing propagates the params'
         # shardings onto them: left alone they start replicated on every
@@ -89,30 +92,32 @@ def make_train_step(
             tx.init(params), is_leaf=_like_params)
         return TrainState(params=params, opt_state=opt_state, step=jnp.zeros((), jnp.int32))
 
-    init_jit = jax.jit(_init, in_shardings=(param_sharding,))
+    init_jit = jax.jit(init_state, in_shardings=(param_sharding,))
 
-    def _step(state: TrainState, batch):
+    def train_step(state: TrainState, batch):
         if has_aux:
             (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 state.params, batch)
         else:
             loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
             aux = {}
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("grad_norm"):
+            gnorm = optax.global_norm(grads)
         return (
             TrainState(params=new_params, opt_state=new_opt, step=state.step + 1),
             {"loss": loss, "grad_norm": gnorm, **aux},
         )
 
     step_jit = jax.jit(
-        _step,
+        train_step,
         in_shardings=(None, batch_sharding),
         donate_argnums=(0,),
     )
 
-    def init_state(params):
+    def init_from_host(params):
         params = jax.device_put(params, param_sharding)
         return init_jit(params)
 
@@ -121,6 +126,6 @@ def make_train_step(
     # full TrainState sharding tree — eval_shape alone drops shardings,
     # so an AOT lower of step_jit with plain ShapeDtypeStructs would
     # silently measure a REPLICATED state (tests/test_aot_fit.py)
-    init_state.jit = init_jit
+    init_from_host.jit = init_jit
 
-    return init_state, step_jit
+    return init_from_host, step_jit

@@ -149,16 +149,15 @@ def main(argv=None) -> int:
         restore = _common_restore_step(args.checkpoint_path, n_stages)
         if restore is not None and os.environ.get(
                 "KUBEDL_CHECKPOINT_RESTORE", "1") == "1":
-            t_restore0 = time.perf_counter()
-            target = {"params": rt.params, "opt_state": rt.opt_state}
-            abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, target)
-            restored = mngr.restore(
-                restore, args=ocp.args.StandardRestore(abstract))
-            rt.params, rt.opt_state = restored["params"], restored["opt_state"]
+            with tracer.span("ckpt.restore", step=restore, stage=stage):
+                target = {"params": rt.params, "opt_state": rt.opt_state}
+                abstract = jax.tree.map(
+                    ocp.utils.to_shape_dtype_struct, target)
+                restored = mngr.restore(
+                    restore, args=ocp.args.StandardRestore(abstract))
+                rt.params, rt.opt_state = (
+                    restored["params"], restored["opt_state"])
             start_step = restore
-            tracer.record("ckpt.restore",
-                          duration_s=time.perf_counter() - t_restore0,
-                          step=restore, stage=stage)
             own = mngr.latest_step()
             note = f" (own latest {own})" if own != restore else ""
             print(f"stage {stage}: restored gang-common checkpoint at "
@@ -171,17 +170,15 @@ def main(argv=None) -> int:
             return
         import orbax.checkpoint as ocp
 
-        t_save0 = time.perf_counter()
-        mngr.save(step, args=ocp.args.StandardSave(
-            {"params": rt.params, "opt_state": rt.opt_state}))
-        if final:
-            mngr.wait_until_finished()
-            print(f"stage {stage}: saved final checkpoint at step {step}",
-                  flush=True)
-        stall = time.perf_counter() - t_save0
-        ckpt_stall["v"] += stall
-        tracer.record("ckpt.save", duration_s=stall, step=step, stage=stage,
-                      final=final)
+        with tracer.span("ckpt.save", step=step, stage=stage,
+                         final=final) as save_span:
+            mngr.save(step, args=ocp.args.StandardSave(
+                {"params": rt.params, "opt_state": rt.opt_state}))
+            if final:
+                mngr.wait_until_finished()
+                print(f"stage {stage}: saved final checkpoint at step {step}",
+                      flush=True)
+        ckpt_stall["v"] += save_span.dur
 
     preempted = {"flag": False}
     signal.signal(signal.SIGTERM, lambda *_: preempted.update(flag=True))
@@ -208,20 +205,22 @@ def main(argv=None) -> int:
                 tokens = rng.integers(
                     0, config.vocab_size,
                     (args.batch, args.seq_len), dtype=np.int32)
-            out = rt.run_step(tokens)
-            if tracer.exporting or step_stream is not None:
-                tracer.record(
+            # the step's span is open while it runs (run_step ends in a
+            # wait), so a --profile-dir window shows it on its own clock;
+            # it reaches the JSONL only under the injected trace env
+            with tracer.span(
                     "train.compile" if step == start_step else "pipeline.step",
-                    duration_s=out["step_s"], step=step + 1, stage=stage,
-                    wait_s=round(out["wait_s"], 6),
-                    **({"loss": out["loss"]} if out["loss"] is not None
-                       else {}))
-                if step_stream is not None:
-                    step_stream.record(
-                        step + 1, out["step_s"], data_s=out["wait_s"],
-                        loss=out["loss"], compile=step == start_step,
-                        ckpt_s=ckpt_stall["v"])
-                    ckpt_stall["v"] = 0.0
+                    step=step + 1, stage=stage) as step_span:
+                out = rt.run_step(tokens)
+                step_span.set(wait_s=round(out["wait_s"], 6))
+                if out["loss"] is not None:
+                    step_span.set(loss=out["loss"])
+            if step_stream is not None:
+                step_stream.record(
+                    step + 1, out["step_s"], data_s=out["wait_s"],
+                    loss=out["loss"], compile=step == start_step,
+                    ckpt_s=ckpt_stall["v"])
+                ckpt_stall["v"] = 0.0
             if prof is not None and prof.should_stop(step):
                 prof.stop()
             if out["loss"] is not None and (

@@ -21,6 +21,62 @@ import os
 import signal
 import sys
 import time
+from collections import deque
+
+# steps the recorder leaves on the device while it waits for an older one
+# (benchmarks/runners/train.py keeps the same two in its window)
+IN_FLIGHT = 2
+
+
+class StepRecorder:
+    """The flight recorder's side of the step loop: one ``train.step``
+    span record and one step-stream record a step, without draining the
+    device.
+
+    A step's loss is read only once ``IN_FLIGHT`` later steps have been
+    dispatched, so the device always has work queued. ``step_s`` is the
+    time between two consecutive awaited completions (from the step's own
+    start where nothing was in flight before it), and the loss written
+    for a step is that step's own. Whatever waits anyway (save,
+    preemption, resize, eval, the log line) calls ``flush()`` first."""
+
+    def __init__(self, tracer, step_stream) -> None:
+        self.tracer = tracer
+        self.step_stream = step_stream
+        self.pending: deque = deque()
+        self.last_done = 0.0  # perf_counter at the last awaited completion
+        # checkpoint stall the loop felt since the last step record (the
+        # async save's device->host copy + any final wait), folded into
+        # the next heartbeat's ckpt_s
+        self.ckpt_stall = 0.0
+
+    def dispatched(self, step: int, loss, t_start: float, data_s: float,
+                   dispatch_s: float, compiled: bool) -> None:
+        self.pending.append((step, loss, t_start, data_s, dispatch_s, compiled))
+        while len(self.pending) > IN_FLIGHT:
+            self._await_oldest()
+
+    def flush(self) -> None:
+        while self.pending:
+            self._await_oldest()
+
+    def _await_oldest(self) -> None:
+        step, loss, t_start, data_s, dispatch_s, compiled = self.pending.popleft()
+        with self.tracer.span("train.wait", export=False, step=step) as wait:
+            loss_v = float(loss)
+        now = time.perf_counter()
+        step_s = now - max(self.last_done, t_start)
+        self.last_done = now
+        self.tracer.record(
+            "train.compile" if compiled else "train.step",
+            duration_s=step_s, step=step, loss=loss_v,
+            data_wait_s=round(data_s, 6), dispatch_s=round(dispatch_s, 6),
+            wait_s=round(wait.dur, 6))
+        if self.step_stream is not None:
+            self.step_stream.record(
+                step, step_s, data_s=data_s, loss=loss_v, compile=compiled,
+                ckpt_s=self.ckpt_stall)
+            self.ckpt_stall = 0.0
 
 
 def parse_args(argv=None):
@@ -118,7 +174,8 @@ def main(argv=None) -> int:
     # the injected KUBEDL_TRACE_DIR + a bounded per-step telemetry stream
     # with a control-dir heartbeat the operator aggregates for straggler
     # detection. Without the env both stay inert (ring-only / None) and
-    # the step loop keeps its plain async-dispatch behavior.
+    # the step loop keeps its plain async-dispatch behavior. Spans opened
+    # with `with` also show in a --profile-dir window, on its clock.
     from kubedl_tpu.obs import StepStream, tracer_from_env
 
     tracer = tracer_from_env()
@@ -436,13 +493,12 @@ def main(argv=None) -> int:
             # as the abstract target, so each leaf comes back with its
             # param_specs sharding instead of landing replicated on one
             # device (mandatory for models that only fit sharded).
-            t_restore0 = time.perf_counter()
-            abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, state)
-            state = mngr.restore(latest, args=ocp.args.StandardRestore(abstract))
-            start_step = int(state.step)
-            tracer.record("ckpt.restore",
-                          duration_s=time.perf_counter() - t_restore0,
-                          step=start_step)
+            with tracer.span("ckpt.restore") as restore_span:
+                abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, state)
+                state = mngr.restore(
+                    latest, args=ocp.args.StandardRestore(abstract))
+                start_step = int(state.step)
+                restore_span.set(step=start_step)
             print(f"restored checkpoint at step {start_step}", flush=True)
 
     # interval saves are ASYNC: orbax's save() blocks only for the
@@ -452,29 +508,28 @@ def main(argv=None) -> int:
     # durability. last-saved is tracked here, not via latest_step(),
     # which lags while a save is in flight.
     saved_step = {"v": mngr.latest_step() if mngr else None}
-    # checkpoint stall the step loop actually felt since the last step
-    # record (the async save's device->host copy + any final wait);
-    # folded into the next heartbeat's ckpt_s
-    ckpt_stall = {"v": 0.0}
+    # with the injected trace env the loop records every step, two steps
+    # behind the dispatch (StepRecorder); without it there is none
+    recorder = (StepRecorder(tracer, step_stream)
+                if tracer.exporting or step_stream is not None else None)
 
     def save(step, final=False):
         if mngr is None:
             return
-        t_save0 = time.perf_counter()
-        did_save = saved_step["v"] != step
-        if did_save:  # else: interval hook already saved it
-            import orbax.checkpoint as ocp
+        did_save = saved_step["v"] != step  # else: interval hook saved it
+        if not (did_save or final):
+            return
+        with tracer.span("ckpt.save", step=step, final=final) as save_span:
+            if did_save:
+                import orbax.checkpoint as ocp
 
-            mngr.save(step, args=ocp.args.StandardSave(state))
-            saved_step["v"] = step
-        if final:
-            mngr.wait_until_finished()
-            print(f"saved final checkpoint at step {step}", flush=True)
-        if did_save or final:
-            stall = time.perf_counter() - t_save0
-            ckpt_stall["v"] += stall
-            tracer.record("ckpt.save", duration_s=stall, step=step,
-                          final=final)
+                mngr.save(step, args=ocp.args.StandardSave(state))
+                saved_step["v"] = step
+            if final:
+                mngr.wait_until_finished()
+                print(f"saved final checkpoint at step {step}", flush=True)
+        if recorder is not None:
+            recorder.ckpt_stall += save_span.dur
 
     # -- live resize protocol (train/reshard_runtime.py ladder) ----------
 
@@ -486,12 +541,12 @@ def main(argv=None) -> int:
         saved and never trained on."""
         print(f"live reshard failed ({reason}); falling back to "
               f"checkpoint restore", file=sys.stderr)
-        try:
-            save(at_step, final=True)
-        except Exception:  # noqa: BLE001 — last interval save still holds
-            pass
-        tracer.record("reshard.fallback", step=at_step,
-                      reason=str(reason)[:200])
+        with tracer.span("reshard.fallback", step=at_step,
+                         reason=str(reason)[:200]):
+            try:
+                save(at_step, final=True)
+            except Exception:  # noqa: BLE001 — last interval save still holds
+                pass
         if ctl is not None:
             ctl.reply(msg, outcome="fallback", step=at_step,
                       error=str(reason)[:300])
@@ -505,34 +560,32 @@ def main(argv=None) -> int:
         and restarts onto the new topology (reassembly at startup). The
         manifest publishes only when every pod staged with a matching
         plan digest; any gap falls back closed."""
-        t_stage0 = time.perf_counter()
         try:
-            if not reshard_dir:
-                raise reshard_runtime.ReshardError("no KUBEDL_RESHARD_DIR")
-            leaves = reshard_runtime.leaves_from_state(state)
-            new_axes = reshard_runtime.refit_axes(dict(mesh.shape), new_chips)
-            plan = reshard_runtime.plan_reshard(
-                leaves, dict(mesh.shape), new_axes,
-                info.num_processes, info.num_processes)
-            blocks = reshard_runtime.addressable_blocks(state)
-            reshard_runtime.stage_shards(
-                reshard_dir, plan, info.process_id,
-                reshard_runtime.provider_from_blocks(blocks), at_step)
-            # the job's own quiesce budget (spec.elastic.quiesceTimeoutS,
-            # injected by the controller) outranks the scheduler default
-            quiesce = float(os.environ.get(
-                "KUBEDL_RESHARD_QUIESCE_S",
-                msg.get("quiesce_timeout_s", 30.0)))
-            if info.process_id == 0 and not reshard_runtime.write_manifest(
-                reshard_dir, plan, at_step, info.num_processes,
-                timeout=quiesce,
-            ):
-                raise reshard_runtime.ReshardError("manifest aborted")
+            with tracer.span("reshard.staged", step=at_step, chips=new_chips):
+                if not reshard_dir:
+                    raise reshard_runtime.ReshardError("no KUBEDL_RESHARD_DIR")
+                leaves = reshard_runtime.leaves_from_state(state)
+                new_axes = reshard_runtime.refit_axes(
+                    dict(mesh.shape), new_chips)
+                plan = reshard_runtime.plan_reshard(
+                    leaves, dict(mesh.shape), new_axes,
+                    info.num_processes, info.num_processes)
+                blocks = reshard_runtime.addressable_blocks(state)
+                reshard_runtime.stage_shards(
+                    reshard_dir, plan, info.process_id,
+                    reshard_runtime.provider_from_blocks(blocks), at_step)
+                # the job's own quiesce budget (spec.elastic.quiesceTimeoutS,
+                # injected by the controller) outranks the scheduler default
+                quiesce = float(os.environ.get(
+                    "KUBEDL_RESHARD_QUIESCE_S",
+                    msg.get("quiesce_timeout_s", 30.0)))
+                if info.process_id == 0 and not reshard_runtime.write_manifest(
+                    reshard_dir, plan, at_step, info.num_processes,
+                    timeout=quiesce,
+                ):
+                    raise reshard_runtime.ReshardError("manifest aborted")
         except Exception as e:  # noqa: BLE001 — fallback closed
             _resize_fallback(msg, at_step, f"staged lane: {e}")
-        tracer.record("reshard.staged",
-                      duration_s=time.perf_counter() - t_stage0,
-                      step=at_step, chips=new_chips)
         ctl.reply(msg, outcome="staged", step=at_step)
         print(f"staged reshard at step {at_step}: restarting onto the new "
               f"topology", flush=True)
@@ -551,18 +604,22 @@ def main(argv=None) -> int:
             _resize_fallback(msg, at_step, "lora runs restart via checkpoint")
         if info.num_processes > 1:
             _resize_staged(msg, at_step, new_chips)  # does not return
-        try:
-            new_mesh, new_state, plan = reshard_runtime.live_resize(
-                state, mesh, new_chips)
-        except reshard_runtime.ReshardError as e:
-            _resize_fallback(msg, at_step, str(e))  # does not return
-        mesh, state = new_mesh, new_state
-        loss = loss_on(mesh)
-        init_state, train_step = build_step(mesh)
-        batch_sharding = rules.sharding(mesh, "batch", None)
-        if eval_fn is not None:
-            eval_fn = jax.jit(loss)
-        jax.block_until_ready(jax.tree_util.tree_leaves(state.params))
+        with tracer.span("reshard.live", step=at_step,
+                         chips=new_chips) as live_span:
+            try:
+                new_mesh, new_state, plan = reshard_runtime.live_resize(
+                    state, mesh, new_chips)
+            except reshard_runtime.ReshardError as e:
+                _resize_fallback(msg, at_step, str(e))  # does not return
+            mesh, state = new_mesh, new_state
+            loss = loss_on(mesh)
+            init_state, train_step = build_step(mesh)
+            batch_sharding = rules.sharding(mesh, "batch", None)
+            if eval_fn is not None:
+                eval_fn = jax.jit(loss)
+            jax.block_until_ready(jax.tree_util.tree_leaves(state.params))
+            live_span.set(outcome="ok",
+                          moved_mb=round(plan.moved_bytes / 2**20, 3))
         # reply NOW — downtime = quiesce -> full state resident on the new
         # mesh, a step dispatchable (the bench's definition). The first
         # post-reshard step's compile is ordinary training the scheduler
@@ -570,9 +627,6 @@ def main(argv=None) -> int:
         # deferred past it would blow reshard_reply_timeout and turn every
         # successful reshard into a spurious pod teardown.
         downtime = time.perf_counter() - t0
-        tracer.record("reshard.live", duration_s=downtime, step=at_step,
-                      chips=new_chips, outcome="ok",
-                      moved_mb=round(plan.moved_bytes / 2**20, 3))
         ctl.reply(msg, outcome="ok", step=at_step,
                   downtime_s=round(downtime, 4), chips=new_chips,
                   moved_mb=round(plan.moved_bytes / 2**20, 3))
@@ -686,15 +740,23 @@ def main(argv=None) -> int:
 
     prof = window_from_args(args, start_step)
 
-    # flight-recorder step loop: with the injected trace env the loss is
-    # synced EVERY step so step/data-wait times are wall-true — the
-    # documented overhead of the recorder. KUBEDL_TRACE_STEP_SYNC=0 keeps
-    # the async-dispatch loop on real accelerators: steps still record,
-    # but durations are DISPATCH times (synced=False attr) and the loss
-    # only materializes at log boundaries.
-    recording = tracer.exporting or step_stream is not None
-    sync_steps = os.environ.get("KUBEDL_TRACE_STEP_SYNC", "1") == "1"
+    # The step loop has two bodies. Plain: next_batch, train_step, nothing
+    # else, no object made per step. Instrumented, with the injected trace
+    # env (recorder) or inside an open --profile-dir window: the same two
+    # calls under train.data / train.dispatch spans and one
+    # StepTraceAnnotation, which a profile shows beside the device's
+    # operations; the recorder reads a step's loss two steps later
+    # (StepRecorder), so recording does not drain the device.
     compile_pending = {"v": True}  # first step after (re)build compiles
+
+    def settle(loss_arr) -> None:
+        """Every step dispatched so far has ended, and the recorder has
+        written them: what save, preemption, resize, eval, the log line
+        and the end of the profile window do before they go on."""
+        if recorder is not None:
+            recorder.flush()
+        with tracer.span("train.wait", export=False):
+            jax.block_until_ready(loss_arr)
 
     tracer.record("trainer.init",
                   duration_s=time.perf_counter() - t_main0,
@@ -707,33 +769,26 @@ def main(argv=None) -> int:
         for step in range(start_step, args.steps):
             if prof is not None:
                 prof.maybe_start(step)
-            t_step0 = time.perf_counter()
-            batch = next_batch(step)
-            data_s = time.perf_counter() - t_step0
-            state, metrics = train_step(state, batch)
-            loss_v = None
-            if recording:
-                if sync_steps:
-                    loss_v = float(metrics["loss"])  # sync: true step time
-                step_s = time.perf_counter() - t_step0
-                was_compile = compile_pending["v"]
-                compile_pending["v"] = False
-                tracer.record(
-                    "train.compile" if was_compile else "train.step",
-                    duration_s=step_s, step=step + 1,
-                    data_wait_s=round(data_s, 6),
-                    **({"loss": loss_v} if loss_v is not None
-                       else {"synced": False}))
-                if step_stream is not None:
-                    step_stream.record(
-                        step + 1, step_s, data_s=data_s, loss=loss_v,
-                        compile=was_compile, ckpt_s=ckpt_stall["v"])
-                    ckpt_stall["v"] = 0.0
+            if recorder is None and not (prof is not None and prof.tracing):
+                state, metrics = train_step(state, next_batch(step))
+            else:
+                with jax.profiler.StepTraceAnnotation("train", step_num=step + 1):
+                    t_step0 = time.perf_counter()
+                    with tracer.span("train.data", export=False) as data_span:
+                        batch = next_batch(step)
+                    with tracer.span("train.dispatch",
+                                     export=False) as dispatch_span:
+                        state, metrics = train_step(state, batch)
+                    if recorder is not None:
+                        recorder.dispatched(
+                            step + 1, metrics["loss"], t_step0, data_span.dur,
+                            dispatch_span.dur, compile_pending["v"])
+                        compile_pending["v"] = False
             if prof is not None and prof.should_stop(step):
-                jax.block_until_ready(metrics["loss"])
+                settle(metrics["loss"])
                 prof.stop()
             if preempted["flag"]:
-                jax.block_until_ready(metrics["loss"])
+                settle(metrics["loss"])
                 if prof is not None:
                     prof.stop()
                 save(step + 1, final=True)
@@ -750,6 +805,7 @@ def main(argv=None) -> int:
                 cmsg = ctl.poll()
                 if cmsg is not None:
                     if cmsg.get("type") == "RESIZE":
+                        settle(metrics["loss"])
                         handle_resize(cmsg, step + 1)
                         # the rebuilt step compiles on the next dispatch
                         compile_pending["v"] = True
@@ -758,11 +814,13 @@ def main(argv=None) -> int:
                                   error=f"unknown control message "
                                         f"{cmsg.get('type')!r}")
             if args.checkpoint_interval and (step + 1) % args.checkpoint_interval == 0:
-                jax.block_until_ready(metrics["loss"])
+                settle(metrics["loss"])
                 save(step + 1)
             if args.eval_every and (step + 1) % args.eval_every == 0:
+                settle(metrics["loss"])
                 eval_pass(step + 1)
             if (step + 1) % args.log_every == 0:
+                settle(metrics["loss"])
                 loss_v = float(metrics["loss"])
                 now = time.perf_counter()
                 sps = args.log_every / (now - last_log)
@@ -775,6 +833,8 @@ def main(argv=None) -> int:
         if prof is not None:
             prof.stop()
 
+    if recorder is not None:
+        recorder.flush()
     jax.block_until_ready(state.step)
     total = time.perf_counter() - t_start
     steps_done = args.steps - start_step

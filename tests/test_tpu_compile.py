@@ -66,6 +66,12 @@ def _kernels(text: str) -> int:
     return text.count('custom_call_target="tpu_custom_call"')
 
 
+# a pallas_call's name= becomes its HLO instruction's name, which the
+# profiler's event name starts with and the benchmark's by-name metrics
+# match (benchmarks/metrics/flash_{fwd,bwd}_roofline.train.json)
+FLASH_INSTRUCTIONS = ("%flash_fwd.", "%flash_bwd_dq.", "%flash_bwd_dkv.")
+
+
 @pytest.mark.parametrize("shape,window", [
     ((4, 16, 2048, 128), None),
     ((4, 8, 1024, 128), None),
@@ -81,6 +87,8 @@ def test_flash_fwd_bwd_compiles(one_chip, on_tpu, shape, window):
     text = _compile(jax.value_and_grad(f, argnums=(0, 1, 2)), x, x, x)
     # forward, dq and dk/dv
     assert _kernels(text) >= 3, "flash fell back to attention_reference"
+    for name in FLASH_INSTRUCTIONS:
+        assert name in text
 
 
 def test_flash_unaligned_blocks_raise(one_chip, on_tpu):
@@ -118,6 +126,7 @@ def test_gmm_fwd_bwd_compiles(one_chip, on_tpu, rows, d, ffn, e):
 
     text = _compile(jax.value_and_grad(f, argnums=(0, 1)), a["x"], a["w"], a["te"])
     assert _kernels(text) >= 3  # gmm, dlhs gmm, tgmm
+    assert "%gmm." in text and "%gmm_drhs." in text
 
 
 @pytest.mark.parametrize("rows,d,ffn,e", GMM_SHAPES)
@@ -131,6 +140,7 @@ def test_gmm_swiglu_fwd_bwd_compiles(one_chip, on_tpu, rows, d, ffn, e):
     text = _compile(jax.value_and_grad(f, argnums=(0, 1, 2)),
                     a["x"], a["w"], a["w"], a["te"], a["s"], a["s"])
     assert _kernels(text) >= 7  # fused fwd + 2 remat + 2 dlhs + 2 tgmm
+    assert "%gmm_swiglu." in text and "%gmm_drhs." in text
 
 
 @pytest.mark.parametrize("rows,d,ffn,e", GMM_SHAPES)
@@ -145,6 +155,7 @@ def test_gmm_scaled_int8_fwd_bwd_compiles(one_chip, on_tpu, rows, d, ffn, e):
     text = _compile(jax.value_and_grad(f, argnums=(0, 3)),
                     a["x"], a["q"], a["te"], a["s"])
     assert _kernels(text) >= 2  # scaled fwd + dlhs gmm
+    assert "%gmm_scaled." in text
 
 
 # loss_fn trains on tokens[:, :-1]: 1,025 gives the model 1,024, the
@@ -203,6 +214,10 @@ def test_sharded_train_step_with_flash_compiles(topo, on_tpu):
     compiled = train_step.lower(state, tokens).compile()
     text = compiled.as_text()
     assert _kernels(text) >= 3, "flash is not in the sharded step"
+    assert text.startswith("HloModule jit_train_step")
+    for name in FLASH_INSTRUCTIONS:  # by its own name inside the shard_map too
+        assert name in text
+    assert "%shard_map." not in text
     assert "all-gather" in text or "all-reduce" in text
     # the parameters are spread: one device holds about a quarter
     ma = compiled.memory_analysis()
