@@ -30,7 +30,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from kubedl_tpu.models.moe import moe_init, moe_mlp, moe_param_specs
 from kubedl_tpu.models.quant import matmul as _mm
-from kubedl_tpu.ops.flash_attention import flash_attention
+from kubedl_tpu.ops.flash_attention import (FLASH_LSE, FLASH_OUT,
+                                            flash_attention)
 from kubedl_tpu.ops.ring_attention import ring_attention
 from kubedl_tpu.parallel import pipeline
 from kubedl_tpu.parallel.mesh import ShardingRules
@@ -69,7 +70,8 @@ class LlamaConfig:
     remat: bool = True
     # None = full recompute; "dots" saves matmul outputs and recomputes
     # only elementwise ops (jax dots_with_no_batch_dims_saveable) — most
-    # of remat's HBM win at a fraction of its ~15-35% step-time cost
+    # of remat's HBM win at a fraction of its ~15-35% step-time cost.
+    # Both keep the flash kernel's out and lse (_remat_policy).
     remat_policy: Optional[str] = None
     use_flash: bool = True
     # context-parallel attention strategy when the mesh's "context" axis
@@ -331,10 +333,19 @@ def param_count(params) -> int:
 
 
 def _remat_policy(name: Optional[str]):
+    """What a rematerialised layer keeps besides its input. Both policies
+    keep the flash kernel's output and log-sum-exp, the residuals its
+    backward needs, so the backward does not run the forward kernel a
+    second time: one [tokens, d_model] activation and one f32 per head
+    and token a layer. A layer that holds no flash kernel holds neither
+    name and keeps nothing more."""
+    policies = jax.checkpoint_policies
+    flash = policies.save_only_these_names(FLASH_OUT, FLASH_LSE)
     if name is None:
-        return None  # save nothing: full recompute
+        return flash  # everything else: full recompute
     if name == "dots":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        return policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, flash)
     raise ValueError(f"unknown remat_policy {name!r} (None | 'dots')")
 
 

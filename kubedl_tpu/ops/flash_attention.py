@@ -24,6 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -46,6 +47,12 @@ FLASH_MIN_SEQ = 1024
 # parallelism — the streamed path serves long-context inference prefill.
 STREAM_MIN_SEQ = 8192
 NEG_INF = -1e30
+# `jax.ad_checkpoint.checkpoint_name`s on the forward kernel's two outputs
+# under differentiation. A `jax.checkpoint` whose policy saves these names
+# (models/llama.py:_remat_policy) keeps them and its backward does not run
+# the forward kernel again; anywhere else the tag is the identity.
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
 
 # ---------------------------------------------------------------------------
 # forward kernel
@@ -445,6 +452,11 @@ def _flash_fwd(q, k, v, sm_scale, causal, window, block_q, block_k, true_len,
                true_d, softcap):
     out, lse = _fwd(q, k, v, sm_scale, causal, window, block_q, block_k,
                     true_len, softcap=softcap)
+    # Tagged before the slice below: the primal output and the residual
+    # both derive from the saved value, so a d=64 model's backward does
+    # not re-run the kernel for either.
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     # Residuals store only the true head dim: padded columns are zeros by
     # construction, so slicing here and re-padding in backward is exact —
     # and halves attention residual HBM for d=64 models.

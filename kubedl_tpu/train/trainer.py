@@ -140,7 +140,10 @@ def parse_args(argv=None):
     p.add_argument("--remat", choices=["full", "dots", "none"],
                    default=os.environ.get("KUBEDL_REMAT", ""),
                    help="override the model's remat: full recompute, "
-                        "matmul-saving 'dots' policy, or none")
+                        "matmul-saving 'dots' policy, or none. full and "
+                        "dots both keep the flash kernel's output and "
+                        "log-sum-exp, 2*tokens*d_model + 4*tokens*n_heads "
+                        "bytes a layer, and do not run it twice")
     p.add_argument("--ce-chunks", type=int,
                    default=int(os.environ.get("KUBEDL_CE_CHUNKS", 0)),
                    help=">1: chunked cross-entropy (no [b,t,V] logits)")

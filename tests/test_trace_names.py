@@ -152,9 +152,13 @@ def test_kernel_names_reach_the_lowered_text(lowered_text):
     # is what its HLO instruction is called after
     for name in ("attn_core/flash_attention/flash_fwd/",
                  "attn_core/flash_attention/flash_bwd_dq/",
-                 "attn_core/flash_attention/flash_bwd_dkv/",
-                 "rematted_computation/attn/attn_core/flash_attention/flash_fwd/"):
+                 "attn_core/flash_attention/flash_bwd_dkv/"):
         assert name in text
+    # remat keeps the forward kernel's out and lse (llama._remat_policy):
+    # the backward recomputes the projections around it, not the kernel
+    assert "rematted_computation/attn/" in text
+    assert ("rematted_computation/attn/attn_core/flash_attention/flash_fwd/"
+            not in text)
     assert "flash_fwd" not in lowered_text[(False, True)]
 
 
