@@ -2200,7 +2200,6 @@ def _tpu_child(results_path: str) -> int:
         params = moe_mod.moe_init(jax.random.PRNGKey(0), d, ff, e, dtype=dtype)
         hf = jax.random.normal(jax.random.PRNGKey(1), (s, d), dtype)
         ks = k * s
-        src_rows = jnp.tile(jnp.arange(s, dtype=jnp.int32), k)
 
         def timed(fn, n1=10, n2=40, reps=3):
             """Median per-call seconds of fn(carry)->f32 scalar via an
@@ -2244,16 +2243,16 @@ def _tpu_child(results_path: str) -> int:
         # rolling ef per iteration keeps the plan inside the loop
         def permute_fn(c):
             ef_i = jnp.roll(ef, c.astype(jnp.int32) % ks)
-            order, dest, _, _, m_pad = moe_mod._dispatch_plan(ef_i, e)
-            x = moe_mod._permute(hf, src_rows, order, dest, m_pad)
+            order, dest, pos, _, m_pad = moe_mod._dispatch_plan(ef_i, e)
+            x, _ = moe_mod._permute(hf, order, dest, pos, m_pad)
             return jnp.sum(x.astype(jnp.float32))
 
         tile = moe_mod._row_tile(ks, e)
         m_pad = (ks + tile - 1) // tile * tile + e * tile
         order, dest, pos_of_entry, tile_expert, _ = jax.jit(
             lambda ef: moe_mod._dispatch_plan(ef, e))(ef)
-        x_pad = jax.jit(lambda: moe_mod._permute(
-            hf, src_rows, order, dest, m_pad))()
+        x_pad, _ = jax.jit(lambda: moe_mod._permute(
+            hf, order, dest, pos_of_entry, m_pad))()
 
         # gmm: the fused expert FFN on the padded rows
         def gmm_fn(c):

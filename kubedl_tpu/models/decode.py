@@ -77,6 +77,7 @@ def init_kv_cache(
     wrapped-position attention (a pytree-STRUCTURE property: ring and
     flat caches compile separately, like uniform/ragged). Single-token
     decode only — block verify would need window+T-1 rows."""
+    config.require_kv_state_only("the cached decode path (init_kv_cache)")
     if kv_dtype not in (None, "int8"):
         raise ValueError(f"kv_dtype must be None or 'int8', got {kv_dtype!r}")
     if ring:
@@ -318,7 +319,7 @@ def decode_step(
             attn_out = rms_norm(attn_out, layer["post_attn_norm"],
                                 c.rms_eps, c.norm_offset)
         x = x + attn_out
-        x, _ = _mlp_block(x, layer, c, lora=llayer, adapter_ids=adapter_ids)
+        x, _, _ = _mlp_block(x, layer, c, lora=llayer, adapter_ids=adapter_ids)
 
     out_cache = {
         "k": new_k,
@@ -451,7 +452,7 @@ def decode_block_step(
             attn_out = rms_norm(attn_out, layer["post_attn_norm"],
                                 c.rms_eps, c.norm_offset)
         x = x + attn_out
-        x, _ = _mlp_block(x, layer, c, lora=llayer, adapter_ids=adapter_ids)
+        x, _, _ = _mlp_block(x, layer, c, lora=llayer, adapter_ids=adapter_ids)
 
     out_cache = {"k": new_k, "v": new_v, "lengths": pos + T}
     if int8_kv:
@@ -593,7 +594,7 @@ def prefill(
             attn_out = rms_norm(attn_out, layer["post_attn_norm"],
                                 c.rms_eps, c.norm_offset)
         x = x + attn_out
-        x, _ = _mlp_block(x, layer, c, lora=llayer, adapter_ids=adapter_ids)
+        x, _, _ = _mlp_block(x, layer, c, lora=llayer, adapter_ids=adapter_ids)
 
     int8_kv = "ks" in cache
     if int8_kv:

@@ -28,8 +28,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kubedl_tpu.models.moe import moe_init, moe_mlp, moe_param_specs
+from kubedl_tpu.models.moe import moe_init, moe_layer, moe_param_specs
 from kubedl_tpu.models.quant import matmul as _mm
+from kubedl_tpu.models.short_conv import (short_conv, short_conv_init,
+                                          short_conv_param_specs)
 from kubedl_tpu.ops.flash_attention import (FLASH_LSE, FLASH_OUT,
                                             flash_attention)
 from kubedl_tpu.ops.ring_attention import ring_attention
@@ -141,6 +143,31 @@ class LlamaConfig:
     # into this many chunks so ICI transfer overlaps the local grouped
     # matmuls (models/moe.py _dropless_shard_fn); 1 = no chunking
     moe_a2a_chunks: int = 1
+    # What an MoE model's layers are, beyond "every FFN routed":
+    # the first n_dense_layers keep a dense FFN of width d_ff, the
+    # others route to experts of width d_ff_expert (None = d_ff)
+    n_dense_layers: int = 0
+    d_ff_expert: Optional[int] = None
+    # the router's score: "softmax" over all outputs with the GShard
+    # auxiliary loss, or "sigmoid": each output's own sigmoid, a
+    # router_bias leaf that only the top-k selection sees, weights
+    # normalised over the k chosen, no auxiliary loss (models/moe.py
+    # _sigmoid_gating)
+    moe_router: str = "softmax"
+    # the chip's share of an expert-parallel deployment: the router keeps
+    # all n_experts outputs, the layer holds experts first_expert ..
+    # first_expert + n_experts_held - 1 and computes their part of the
+    # result (None = all held; models/moe.py moe_layer)
+    n_experts_held: Optional[int] = None
+    first_expert: int = 0
+    # Token mixer per layer: None = attention in every layer, else a tuple
+    # of n_layers entries, "attention" or "conv" (a gated short
+    # convolution over conv_kernel tokens, models/short_conv.py)
+    layer_types: Optional[tuple] = None
+    conv_kernel: int = 3
+    # RMSNorm over each head's entries of q and of k before RoPE
+    # (q_norm / k_norm leaves of size head_dim)
+    qk_norm: bool = False
 
     def __post_init__(self):
         if self.sliding_window is not None and self.sliding_window < 1:
@@ -157,6 +184,37 @@ class LlamaConfig:
                 if w is not None and w < 1:
                     raise ValueError(
                         f"layer_windows[{i}] must be >= 1 or None, got {w}")
+        if self.layer_types is not None:
+            if len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types has {len(self.layer_types)} entries "
+                    f"for {self.n_layers} layers")
+            bad = set(self.layer_types) - {"attention", "conv"}
+            if bad:
+                raise ValueError(
+                    f"layer_types holds {sorted(bad)} (attention, conv)")
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown moe_router {self.moe_router!r} (softmax, sigmoid)")
+
+    def mixer_for(self, i: int) -> str:
+        """Layer i's token mixer: "attention" or "conv"."""
+        return "attention" if self.layer_types is None else self.layer_types[i]
+
+    def routed(self, i: int) -> bool:
+        """Whether layer i's FFN is a routed expert layer."""
+        return self.n_experts > 0 and i >= self.n_dense_layers
+
+    def require_kv_state_only(self, what: str) -> None:
+        """Refusal of the paths that carry state from token to token and
+        know keys and values alone."""
+        if self.layer_types is not None and "conv" in self.layer_types:
+            raise NotImplementedError(
+                f"{what} has no state for a short-convolution layer: the "
+                f"last conv_kernel - 1 = {self.conv_kernel - 1} gated "
+                f"inputs a layer would carry from step to step have no "
+                f"cache beside the keys and values (layer_types holds "
+                f"{self.layer_types.count('conv')} conv layers)")
 
     def window_for(self, i: int) -> Optional[int]:
         """Layer i's attention window: layer_windows wins, else the
@@ -205,12 +263,34 @@ class LlamaConfig:
             "bench-150m": LlamaConfig.bench_150m,
             "bench-1b": LlamaConfig.bench_1b,
             "llama-7b": LlamaConfig.llama_7b,
+            "lfm2-8b-a1b": LlamaConfig.lfm2_8b_a1b,
         }
         if name not in factories:
             raise ValueError(
                 f"unknown model {name!r} (choose from {sorted(factories)})"
             )
         return factories[name]()
+
+    @staticmethod
+    def lfm2_8b_a1b(**kw) -> "LlamaConfig":
+        """LFM2-8B-A1B at its published sizes (LiquidAI/LFM2-8B-A1B
+        config.json): 24 layers of hidden 2,048, 18 gated short
+        convolutions and 6 GQA layers with q/k head norms, two leading
+        dense FFNs of 7,168, then 4 of 32 experts of 1,792 by a sigmoid
+        router with a selection bias. 8.34B parameters, 1.5B active."""
+        kinds = ["conv", "conv", "attention"] + ["conv", "conv", "conv",
+                                                "attention"] * 4
+        kinds += ["conv", "conv", "attention", "conv", "conv"]
+        defaults = dict(
+            vocab_size=65536, d_model=2048, n_layers=24, n_heads=32,
+            n_kv_heads=8, d_ff=7168, max_seq_len=128000,
+            rope_theta=1000000.0, rms_eps=1e-5, tie_embeddings=True,
+            layer_types=tuple(kinds), conv_kernel=3, qk_norm=True,
+            n_experts=32, expert_top_k=4, n_dense_layers=2,
+            d_ff_expert=1792, moe_router="sigmoid",
+        )
+        defaults.update(kw)
+        return LlamaConfig(**defaults)
 
     @staticmethod
     def bench_150m(**kw) -> "LlamaConfig":
@@ -241,32 +321,42 @@ class LlamaConfig:
 def param_specs(config: LlamaConfig, rules: Optional[ShardingRules] = None) -> Dict:
     """PartitionSpec pytree matching init() — the sharding contract."""
     r = rules or ShardingRules()
-    layer = {
-        "attn_norm": r.spec("embed"),
-        "wq": r.spec("embed", "heads"),
-        "wk": r.spec("embed", "heads"),
-        "wv": r.spec("embed", "heads"),
-        "wo": r.spec("heads", "embed"),
-        "mlp_norm": r.spec("embed"),
-    }
-    if config.attn_qkv_bias:
-        # biases follow their projection's OUTPUT axis sharding
-        layer.update({"bq": r.spec("heads"), "bk": r.spec("heads"),
-                      "bv": r.spec("heads")})
-    if config.post_block_norms:
-        layer.update({"post_attn_norm": r.spec("embed"),
-                      "post_mlp_norm": r.spec("embed")})
-    if config.n_experts > 0:
-        layer["moe"] = moe_param_specs(r)
-    else:
-        layer.update({
-            "w1": r.spec("embed", "mlp"),
-            "w3": r.spec("embed", "mlp"),
-            "w2": r.spec("mlp", "embed"),
-        })
+
+    def layer_specs(i: int) -> Dict:
+        if config.mixer_for(i) == "conv":
+            layer = {"conv_norm": r.spec("embed"), **short_conv_param_specs(r)}
+        else:
+            layer = {
+                "attn_norm": r.spec("embed"),
+                "wq": r.spec("embed", "heads"),
+                "wk": r.spec("embed", "heads"),
+                "wv": r.spec("embed", "heads"),
+                "wo": r.spec("heads", "embed"),
+            }
+            if config.attn_qkv_bias:
+                # biases follow their projection's OUTPUT axis sharding
+                layer.update({"bq": r.spec("heads"), "bk": r.spec("heads"),
+                              "bv": r.spec("heads")})
+            if config.qk_norm:
+                layer.update({"q_norm": r.spec(None), "k_norm": r.spec(None)})
+        layer["mlp_norm"] = r.spec("embed")
+        if config.post_block_norms:
+            layer.update({"post_attn_norm": r.spec("embed"),
+                          "post_mlp_norm": r.spec("embed")})
+        if config.routed(i):
+            layer["moe"] = moe_param_specs(
+                r, router_bias=config.moe_router == "sigmoid")
+        else:
+            layer.update({
+                "w1": r.spec("embed", "mlp"),
+                "w3": r.spec("embed", "mlp"),
+                "w2": r.spec("mlp", "embed"),
+            })
+        return layer
+
     specs = {
         "embed": r.spec("vocab", "embed"),
-        "layers": [dict(layer) for _ in range(config.n_layers)],
+        "layers": [layer_specs(i) for i in range(config.n_layers)],
         "final_norm": r.spec("embed"),
     }
     if not config.tie_embeddings:
@@ -289,23 +379,35 @@ def init(config: LlamaConfig, key: jax.Array) -> Dict:
     for i in range(config.n_layers):
         ks = jax.random.split(keys[i], 7)
         norm_init = jnp.full((d,), 1.0 - config.norm_offset, jnp.float32)
-        layer = {
-            "attn_norm": norm_init,
-            "wq": dense(ks[0], (d, nq * hd), d),
-            "wk": dense(ks[1], (d, nkv * hd), d),
-            "wv": dense(ks[2], (d, nkv * hd), d),
-            "wo": dense(ks[3], (nq * hd, d), nq * hd),
-            "mlp_norm": norm_init,
-        }
-        if config.attn_qkv_bias:
-            layer["bq"] = jnp.zeros((nq * hd,), jnp.float32)
-            layer["bk"] = jnp.zeros((nkv * hd,), jnp.float32)
-            layer["bv"] = jnp.zeros((nkv * hd,), jnp.float32)
+        if config.mixer_for(i) == "conv":
+            layer = {"conv_norm": norm_init, **short_conv_init(
+                ks[0], d, config.conv_kernel, dtype=dt)}
+        else:
+            layer = {
+                "attn_norm": norm_init,
+                "wq": dense(ks[0], (d, nq * hd), d),
+                "wk": dense(ks[1], (d, nkv * hd), d),
+                "wv": dense(ks[2], (d, nkv * hd), d),
+                "wo": dense(ks[3], (nq * hd, d), nq * hd),
+            }
+            if config.attn_qkv_bias:
+                layer["bq"] = jnp.zeros((nq * hd,), jnp.float32)
+                layer["bk"] = jnp.zeros((nkv * hd,), jnp.float32)
+                layer["bv"] = jnp.zeros((nkv * hd,), jnp.float32)
+            if config.qk_norm:
+                head_norm = jnp.full((hd,), 1.0 - config.norm_offset,
+                                     jnp.float32)
+                layer["q_norm"] = head_norm
+                layer["k_norm"] = head_norm
+        layer["mlp_norm"] = norm_init
         if config.post_block_norms:
             layer["post_attn_norm"] = norm_init
             layer["post_mlp_norm"] = norm_init
-        if config.n_experts > 0:
-            layer["moe"] = moe_init(ks[4], d, dff, config.n_experts, dtype=dt)
+        if config.routed(i):
+            layer["moe"] = moe_init(
+                ks[4], d, config.d_ff_expert or dff, config.n_experts,
+                dtype=dt, n_held=config.n_experts_held,
+                router_bias=config.moe_router == "sigmoid")
         else:
             layer.update({
                 "w1": dense(ks[4], (d, dff), d),
@@ -501,6 +603,9 @@ def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
     q = _proj(h, layer, "q").reshape(b, t, nq, hd).transpose(0, 2, 1, 3)
     k = _proj(h, layer, "k").reshape(b, t, nkv, hd).transpose(0, 2, 1, 3)
     v = _proj(h, layer, "v").reshape(b, t, nkv, hd).transpose(0, 2, 1, 3)
+    if "q_norm" in layer:
+        q = rms_norm(q, layer["q_norm"], config.rms_eps, config.norm_offset)
+        k = rms_norm(k, layer["k_norm"], config.rms_eps, config.norm_offset)
     q = _rope(q, positions, config.rope_theta, config.rope_scaling)
     k = _rope(k, positions, config.rope_theta, config.rope_scaling)
     if config.q_prescale != 1.0:
@@ -518,19 +623,40 @@ def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
     return x + out
 
 
+@jax.named_scope("short_conv")
+def _short_conv_block(x, layer, config: LlamaConfig):
+    """A convolution layer's mixer with its norm and residual, as
+    _attention_block is an attention layer's."""
+    h = rms_norm(x, layer["conv_norm"], config.rms_eps, config.norm_offset)
+    return x + short_conv(h, layer).astype(x.dtype)
+
+
+def _mixer_block(x, layer, config: LlamaConfig, positions, mesh, rules,
+                 context_size, window=None):
+    """The layer's token mixer, by what the layer holds."""
+    if "conv_in" in layer:
+        return _short_conv_block(x, layer, config)
+    return _attention_block(x, layer, config, positions, mesh, rules,
+                            context_size, window=window)
+
+
 @jax.named_scope("mlp")
 def _mlp_block(x, layer, config: LlamaConfig, mesh=None, rules=None,
                lora=None, adapter_ids=None):
-    """Dense or MoE FFN; returns (out, aux_loss). lora/adapter_ids:
-    per-row serving adapters on w1/w3/w2 (see _proj); MoE layers carry
-    no dense projections for adapters to target."""
+    """Dense or MoE FFN; returns (out, aux_loss, counters): the
+    counters are an expert layer's (moe.py _dispatch_stats), {} for a
+    dense one. lora/adapter_ids: per-row serving adapters on w1/w3/w2
+    (see _proj); MoE layers carry no dense projections for adapters to
+    target."""
     h = rms_norm(x, layer["mlp_norm"], config.rms_eps, config.norm_offset)
+    stats = {}
     if "moe" in layer:
-        y, aux = moe_mlp(
+        y, aux, stats = moe_layer(
             h, layer["moe"], top_k=config.expert_top_k,
             capacity_factor=config.expert_capacity_factor, mesh=mesh, rules=rules,
             dropless=config.moe_dropless, fused=config.moe_fused,
             a2a_chunks=config.moe_a2a_chunks,
+            first_expert=config.first_expert,
         )
         y = y.astype(x.dtype)
     else:
@@ -542,7 +668,7 @@ def _mlp_block(x, layer, config: LlamaConfig, mesh=None, rules=None,
     if "post_mlp_norm" in layer:
         y = rms_norm(y, layer["post_mlp_norm"], config.rms_eps,
                      config.norm_offset)
-    return x + y, aux
+    return x + y, aux, stats
 
 
 def _constrainer(mesh, rules):
@@ -559,8 +685,9 @@ def _backbone(
     config: LlamaConfig,
     mesh: Optional[Mesh],
     rules: ShardingRules,
-) -> Tuple[jax.Array, jax.Array]:
-    """(pre-final-norm activations [batch, seq, d], summed MoE aux loss)."""
+) -> Tuple[jax.Array, jax.Array, Dict]:
+    """(pre-final-norm activations [batch, seq, d], summed MoE aux loss,
+    the expert layers' counters summed over layers: {} for a dense model)."""
     context_size = 1
     if mesh is not None:
         context_size = mesh.shape.get("context", 1)
@@ -584,11 +711,11 @@ def _backbone(
         # program), so it rides a closure, not a traced argument
         def layer_fn(carry, layer):
             x, aux = carry
-            x = _attention_block(x, layer, config, positions, mesh, rules,
-                                 context_size, window=window)
+            x = _mixer_block(x, layer, config, positions, mesh, rules,
+                             context_size, window=window)
             x = constrain(x, "batch", "seq", None)
-            x, a = _mlp_block(x, layer, config, mesh, rules)
-            return constrain(x, "batch", "seq", None), aux + a
+            x, a, counters = _mlp_block(x, layer, config, mesh, rules)
+            return (constrain(x, "batch", "seq", None), aux + a), counters
 
         if config.remat:
             return jax.checkpoint(
@@ -596,9 +723,12 @@ def _backbone(
         return layer_fn
 
     aux = jnp.zeros((), jnp.float32)
+    stats: Dict = {}
     for i, layer in enumerate(params["layers"]):
-        x, aux = make_layer_fn(config.window_for(i))((x, aux), layer)
-    return x, aux
+        (x, aux), counters = make_layer_fn(config.window_for(i))((x, aux), layer)
+        for k, v in counters.items():
+            stats[k] = stats[k] + v if k in stats else v
+    return x, aux, stats
 
 
 def forward_and_aux(
@@ -610,7 +740,7 @@ def forward_and_aux(
 ) -> Tuple[jax.Array, jax.Array]:
     """(logits [batch, seq, vocab] f32, summed MoE aux loss — 0 when dense)."""
     rules = rules or ShardingRules()
-    x, aux = _backbone(params, tokens, config, mesh, rules)
+    x, aux, _ = _backbone(params, tokens, config, mesh, rules)
     logits = _lm_head(x, params, config)
     return _constrainer(mesh, rules)(logits, "batch", "seq", "vocab"), aux
 
@@ -704,16 +834,36 @@ def loss_fn(params, tokens, config: LlamaConfig, mesh=None, rules=None):
     With config.ce_chunks > 1 (and no vocab/tensor sharding to respect)
     the loss runs chunked — the full logits tensor never exists.
     """
+    return loss_and_stats(params, tokens, config, mesh=mesh, rules=rules)[0]
+
+
+def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
+    """(loss_fn's loss, the step's expert-layer counters): what
+    make_train_step(has_aux=True) returns as metrics beside the loss.
+    {} for a dense model; for a model with expert layers on the
+    single-device dropless route, summed over its layers:
+    moe_rows_routed, moe_rows_held, gmm_live_tiles, gmm_grid_tiles, and
+    moe_load_max_over_mean over the held experts (docs/observability.md)."""
     rules = rules or ShardingRules()
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    if config.ce_chunks > 1:
-        if mesh is None or mesh.shape.get("tensor", 1) == 1:
-            x, aux = _backbone(params, inputs, config, mesh, rules)
-            ce = _next_token_ce_chunked(x, params, config, targets, config.ce_chunks)
-            return ce + config.moe_aux_coef * aux
+    chunked = config.ce_chunks > 1
+    if chunked and mesh is not None and mesh.shape.get("tensor", 1) != 1:
         _warn_ce_chunks_ignored(mesh.shape.get("tensor", 1))
-    logits, aux = forward_and_aux(params, inputs, config, mesh=mesh, rules=rules)
-    return _next_token_ce(logits, targets) + config.moe_aux_coef * aux
+        chunked = False
+    x, aux, stats = _backbone(params, inputs, config, mesh, rules)
+    if chunked:
+        ce = _next_token_ce_chunked(x, params, config, targets, config.ce_chunks)
+    else:
+        logits = _constrainer(mesh, rules)(
+            _lm_head(x, params, config), "batch", "seq", "vocab")
+        ce = _next_token_ce(logits, targets)
+    if stats:
+        stats = dict(stats)
+        held = config.n_experts_held or config.n_experts
+        stats["moe_load_max_over_mean"] = (
+            stats.pop("moe_rows_fullest") * held
+            / jnp.maximum(stats["moe_rows_held"], 1.0))
+    return ce + config.moe_aux_coef * aux, stats
 
 
 _warned_ce_chunks = False
@@ -773,7 +923,7 @@ def pipeline_layer_fn(config: LlamaConfig, t: int,
         pos = jnp.broadcast_to(positions1, (a.shape[0], t))
         a = _attention_block(a, layer, config, pos, None, rules, 1,
                              window=config.sliding_window)
-        a, aux = _mlp_block(a, layer, config)
+        a, aux, _ = _mlp_block(a, layer, config)
         return a, aux
 
     return layer_fn
@@ -804,6 +954,14 @@ def forward_pipelined_and_aux(
         # params; a per-layer static mask can't vary inside the scan
         raise ValueError("pipelined path requires a uniform window "
                          "(layer_windows unsupported)")
+    if config.layer_types is not None or config.n_dense_layers:
+        # the same scan: every layer must hold the same leaves
+        raise NotImplementedError(
+            "the pipelined forward scans one layer program over stacked "
+            "layers and has no per-stage layer kinds: layer_types "
+            f"{config.layer_types} / n_dense_layers {config.n_dense_layers} "
+            "need layers of unlike leaves (a short convolution beside "
+            "attention, a dense FFN before routed ones)")
     for ax in ("tensor", "context", "expert"):
         if mesh.shape.get(ax, 1) != 1:
             raise ValueError(f"pipelined mesh must have {ax}=1, got {mesh.shape[ax]}")

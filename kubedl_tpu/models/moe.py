@@ -37,6 +37,19 @@ spec's `expert` axis:
 
 Tokens overflowing an expert's capacity are dropped (contribute zero) and
 their residual path passes through — standard Switch behavior.
+
+Two things a layer reads off its own arrays (docs/moe_performance.md):
+  * which experts it HOLDS: the router keeps its published width
+    (`router [d, n_out]`) while `w1/w3/w2` carry `e <= n_out` experts,
+    `first_expert .. first_expert + e - 1`. The layer routes over all
+    `n_out` outputs, turns choices of absent experts into the sentinel
+    `_gmm_ffn` understands and returns the part of the result its own
+    experts give — one chip's share of an expert-parallel deployment,
+    without the exchange and without any stand-in for it. Dropless only;
+  * the router's score function: softmax over all outputs with the
+    GShard auxiliary loss (`_top_k_gating`), or, where the layer carries
+    a `router_bias`, a sigmoid score whose top-k selection alone sees
+    the bias and which has no auxiliary loss (`_sigmoid_gating`).
 """
 from __future__ import annotations
 
@@ -51,21 +64,30 @@ from jax.sharding import Mesh
 from kubedl_tpu.parallel.mesh import ShardingRules
 
 
-def moe_param_specs(rules: Optional[ShardingRules] = None) -> Dict:
+def moe_param_specs(rules: Optional[ShardingRules] = None,
+                    router_bias: bool = False) -> Dict:
     """PartitionSpec pytree matching moe_init() for one MoE FFN layer."""
     r = rules or ShardingRules()
-    return {
+    specs = {
         "router": r.spec("embed", "expert"),
         "w1": r.spec("expert", "embed", "mlp"),
         "w3": r.spec("expert", "embed", "mlp"),
         "w2": r.spec("expert", "mlp", "embed"),
     }
+    if router_bias:
+        specs["router_bias"] = r.spec(None)
+    return specs
 
 
 def moe_init(
-    key: jax.Array, d_model: int, d_ff: int, n_experts: int, dtype=jnp.bfloat16
+    key: jax.Array, d_model: int, d_ff: int, n_experts: int, dtype=jnp.bfloat16,
+    n_held: Optional[int] = None, router_bias: bool = False,
 ) -> Dict:
+    """One expert layer: a router over `n_experts` outputs and the
+    `n_held` experts this layer holds (None = all of them). With
+    `router_bias`, the sigmoid router's selection bias (zeros)."""
     ks = jax.random.split(key, 4)
+    n_held = n_held or n_experts
 
     def dense(k, shape, fan_in):
         return (
@@ -73,16 +95,19 @@ def moe_init(
             * (1.0 / np.sqrt(fan_in))
         ).astype(dtype)
 
-    return {
+    layer = {
         # router stays f32: tiny, and gating is precision-sensitive
         "router": (
             jax.random.truncated_normal(ks[0], -2, 2, (d_model, n_experts), jnp.float32)
             * (1.0 / np.sqrt(d_model))
         ),
-        "w1": dense(ks[1], (n_experts, d_model, d_ff), d_model),
-        "w3": dense(ks[2], (n_experts, d_model, d_ff), d_model),
-        "w2": dense(ks[3], (n_experts, d_ff, d_model), d_ff),
+        "w1": dense(ks[1], (n_held, d_model, d_ff), d_model),
+        "w3": dense(ks[2], (n_held, d_model, d_ff), d_model),
+        "w2": dense(ks[3], (n_held, d_ff, d_model), d_ff),
     }
+    if router_bias:
+        layer["router_bias"] = jnp.zeros((n_experts,), jnp.float32)
+    return layer
 
 
 def expert_capacity(
@@ -96,6 +121,7 @@ def _top_k_gating(
     top_k: int,
     capacity: int,
     need_slots: bool = True,
+    bias: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array,
            Tuple[jax.Array, jax.Array]]:
     """Routing as INDICES instead of one-hot planes.
@@ -118,20 +144,29 @@ def _top_k_gating(
     `need_slots=False` skips the sort entirely for callers that run
     their own dispatch ordering (the dropless paths): slots come back
     zero, keeps all-true, and `capacity` is ignored.
+
+    With a `bias` the score is the sigmoid router's (`_sigmoid_gating`):
+    weights arrive normalised over all k choices and stay so, a choice
+    that loses its slot simply adds nothing, and the load-balance
+    factors are zero (that router trains without the auxiliary loss).
     """
     s, e = gate_logits.shape
-    probs = jax.nn.softmax(gate_logits, axis=-1)
+    if bias is not None:
+        experts, gates = _sigmoid_gating(gate_logits, bias, top_k)
+        me = ce = jnp.zeros((e,), jnp.float32)
+    else:
+        probs = jax.nn.softmax(gate_logits, axis=-1)
 
-    topv, topi = jax.lax.top_k(probs, top_k)  # [S, k] each
-    experts = topi.T.astype(jnp.int32)  # [k, S], choice-major
-    gates = topv.T.astype(jnp.float32)  # [k, S]
+        topv, topi = jax.lax.top_k(probs, top_k)  # [S, k] each
+        experts = topi.T.astype(jnp.int32)  # [k, S], choice-major
+        gates = topv.T.astype(jnp.float32)  # [k, S]
 
-    # load-balance aux factors: mean(prob), mean(top-1 assignment)
-    me = jnp.mean(probs, axis=0)
-    ce = jnp.zeros((e,), jnp.float32).at[experts[0]].add(1.0 / s)
+        # load-balance aux factors: mean(prob), mean(top-1 assignment)
+        me = jnp.mean(probs, axis=0)
+        ce = jnp.zeros((e,), jnp.float32).at[experts[0]].add(1.0 / s)
 
     if not need_slots:
-        weights = gates / jnp.maximum(
+        weights = gates if bias is not None else gates / jnp.maximum(
             jnp.sum(gates, axis=0, keepdims=True), 1e-9)
         return (
             experts,
@@ -158,9 +193,10 @@ def _top_k_gating(
     keeps = slots < capacity
 
     weights = gates * keeps  # [k, S]
-    # renormalize over the choices that actually kept the token
-    weights = weights / jnp.maximum(
-        jnp.sum(weights, axis=0, keepdims=True), 1e-9)
+    if bias is None:
+        # renormalize over the choices that actually kept the token
+        weights = weights / jnp.maximum(
+            jnp.sum(weights, axis=0, keepdims=True), 1e-9)
     return experts, slots, weights, keeps, (me, ce)
 
 
@@ -211,6 +247,50 @@ def _top_k_gating_reference(
     )
 
 
+# the family's modelling code adds this to the sum that normalises the k
+# selected scores (it is not a key of any config.json)
+SIGMOID_NORM_EPS = 1e-6
+
+
+def _sigmoid_gating(
+    gate_logits: jax.Array,  # [S, n_out] f32
+    bias: jax.Array,  # [n_out] f32, moves the selection and nothing else
+    top_k: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid router with a selection bias: (experts [k, S] i32,
+    weights [k, S] f32). The score of every output is its own sigmoid;
+    the k outputs with the largest `score + bias` are chosen, and each
+    weighs its score (without the bias) over the sum of all k chosen
+    scores, held here or not. The bias takes no gradient: it reaches the
+    result through the indices alone."""
+    scores = jax.nn.sigmoid(gate_logits)
+    _, topi = jax.lax.top_k(scores + bias.astype(scores.dtype), top_k)
+    chosen = jnp.take_along_axis(scores, topi, axis=-1)  # [S, k]
+    weights = chosen / (
+        jnp.sum(chosen, axis=-1, keepdims=True) + SIGMOID_NORM_EPS)
+    return topi.T.astype(jnp.int32), weights.T
+
+
+def _dispatch_stats(eid: jax.Array, e: int) -> Dict:
+    """What one dropless dispatch did, as counters (f32 scalars, so that
+    layers add up and a step can return them as metrics): rows routed
+    over all of the router's outputs, rows computed by the experts held
+    here, the fullest held expert's rows (with `moe_rows_held` and the
+    held count it gives the load's max over mean), and the grouped
+    matmuls' live row tiles beside the static grid's."""
+    m = eid.shape[0]
+    tile = _row_tile(m, e)
+    counts = jnp.zeros((e,), jnp.int32).at[eid].add(1, mode="drop")
+    f32 = lambda v: jnp.asarray(v, jnp.float32)
+    return {
+        "moe_rows_routed": f32(m),
+        "moe_rows_held": f32(jnp.sum(counts)),
+        "moe_rows_fullest": f32(jnp.max(counts)),
+        "gmm_live_tiles": f32(jnp.sum((counts + tile - 1) // tile)),
+        "gmm_grid_tiles": f32(((m + tile - 1) // tile * tile + e * tile) // tile),
+    }
+
+
 # ---------------------------------------------------------------------------
 # dropless dispatch stages. _gmm_ffn composes plan -> permute -> ffn ->
 # gather; they are split so bench.py can time each stage (the
@@ -244,8 +324,9 @@ def _dispatch_plan(eid: jax.Array, e: int):
       * tile_expert [m_pad // tile]: owning expert per row-tile, where
         `tile = _row_tile(M, e)` (512 for large dispatches, TILE_M for
         small — the gmm kernels derive the tile size from this array's
-        length). Tiles past the real rows clamp to the last expert and
-        multiply zeros — bounded, harmless;
+        length). Tiles past the real rows carry the id `e`: dead, and
+        the kernels' grids stop before them (ops/gmm.py), so device
+        time follows the rows routed here and not `m_pad`;
       * m_pad: static worst case, rounded to whole row-tiles — the
         per-group padded runs sum to <= round_up(M) + e*tile and the
         gmm grid must cover every row (a ragged tail would silently
@@ -268,26 +349,64 @@ def _dispatch_plan(eid: jax.Array, e: int):
     dest = jnp.where(sorted_eid < e,
                      pad_offsets[real_eid] + pos_in_group, m_pad)  # [M]
     tile_starts = jnp.arange(m_pad // tile, dtype=jnp.int32) * tile
-    tile_expert = jnp.clip(
-        jnp.searchsorted(jnp.cumsum(pad_sizes), tile_starts, side="right"),
-        0, e - 1).astype(jnp.int32)
+    tile_expert = jnp.searchsorted(
+        jnp.cumsum(pad_sizes), tile_starts, side="right").astype(jnp.int32)
     pos_of_entry = jnp.zeros((m,), jnp.int32).at[order].set(dest)
     return order, dest, pos_of_entry, tile_expert, m_pad
 
 
+def _take(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """x[idx] by rows; an index of len(x) (or beyond) reads a zero row."""
+    return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+
+
+@jax.custom_vjp
+def _take_rows(x: jax.Array, idx: jax.Array, back: jax.Array) -> jax.Array:
+    """y[i] = x[idx[i]], a zero row where idx[i] == len(x).
+
+    `back` [c, len(x)] names, for each row of x, the rows of y that took
+    it (len(y) = none). The transpose is then c gathers and a sum, where
+    autodiff would scatter-add [rows, d] into x: a TPU scatters rows
+    several times slower than it gathers them, and the dispatch moves
+    k*S rows of d_model four times a layer and step."""
+    return _take(x, idx)
+
+
+def _take_rows_fwd(x, idx, back):
+    return _take(x, idx), (idx, back)
+
+
+def _take_rows_bwd(res, dy):
+    idx, back = res
+    dx = _take(dy, back[0])
+    for c in range(1, back.shape[0]):
+        dx = dx + _take(dy, back[c])
+    zero = lambda a: np.zeros(a.shape, jax.dtypes.float0)
+    return dx, zero(idx), zero(back)
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
 def _permute(
     src: jax.Array,  # [n_src, d]
-    src_rows: jax.Array,  # [M] i32
     order: jax.Array,
     dest: jax.Array,
+    pos_of_entry: jax.Array,
     m_pad: int,
-) -> jax.Array:
-    """Gather the routed rows into the padded expert-sorted layout.
-    Sentinel entries target the out-of-range row m_pad and are dropped
-    by the scatter (gathered back later as the zero row)."""
-    d = src.shape[1]
-    return jnp.zeros((m_pad, d), src.dtype).at[dest].set(
-        src[src_rows[order]], mode="drop")
+) -> Tuple[jax.Array, jax.Array]:
+    """Gather the routed rows into the padded expert-sorted layout: which
+    entry each padded row holds (a scatter of M integers; sentinel
+    entries target the out-of-range row m_pad and are dropped), then one
+    gather of rows (entry f is row `f % n_src` of `src`). Padding rows
+    read the zero row. Returns (rows [m_pad, d], entry_of_row [m_pad],
+    M where a row holds none)."""
+    n_src, m = src.shape[0], order.shape[0]
+    entry_of_row = jnp.full((m_pad,), m, jnp.int32).at[dest].set(
+        order, mode="drop")
+    row_src = jnp.where(entry_of_row < m, entry_of_row % n_src, n_src)
+    x = _take_rows(src, row_src, pos_of_entry.reshape(m // n_src, n_src))
+    return x, entry_of_row
 
 
 def _ffn_rows(
@@ -344,28 +463,38 @@ def _ffn_rows(
 
 
 def _gmm_ffn(
-    src: jax.Array,  # [n_src, d] source rows to gather from
-    src_rows: jax.Array,  # [M] i32 row of `src` backing each routed entry
+    src: jax.Array,  # [n_src, d] source rows
     eid: jax.Array,  # [M] i32 expert per entry, in [0, e]; e = empty sentinel
     params: Dict,
     e: int,
     fused: bool = True,
 ) -> jax.Array:
-    """Route M rows through their experts' SwiGLU FFN via the grouped
+    """Route M entries through their experts' SwiGLU FFN via the grouped
     matmul kernels (ops/gmm.py): sort entries by expert, pad each
-    expert's run to the row-tile, run the fused FFN. Returns [M, d]
-    outputs aligned to the input entries; sentinel entries (eid == e)
-    come back as zero rows."""
-    d = src.shape[1]
-    order, dest, pos_of_entry, tile_expert, m_pad = _dispatch_plan(eid, e)
-    x = _permute(src, src_rows, order, dest, m_pad)
-    rows = _ffn_rows(x, tile_expert, params, fused=fused)
-    # entry p's output sits at padded row dest[p]; sentinel dest == m_pad
-    # gathers the appended zero row
-    rows = jnp.concatenate([rows, jnp.zeros((1, d), rows.dtype)], axis=0)
-    return rows[pos_of_entry]
+    expert's run to the row-tile, run the fused FFN. Entry f is row
+    `f % n_src` of `src` (M a multiple of n_src: each row's k choices,
+    choice-major). Returns [M, d] outputs aligned to the entries;
+    sentinel entries (eid == e) come back as zero rows.
+
+    Rows move by gathers in both directions and in both passes
+    (`_take_rows`): into the padded layout by the entry each padded row
+    holds, back out by the padded row of each entry."""
+    n_src, m = src.shape[0], eid.shape[0]
+    if m % n_src:
+        raise ValueError(f"{m} entries do not tile {n_src} source rows")
+    with jax.named_scope("moe_route"):
+        order, dest, pos_of_entry, tile_expert, m_pad = _dispatch_plan(eid, e)
+    with jax.named_scope("moe_permute"):
+        x, entry_of_row = _permute(src, order, dest, pos_of_entry, m_pad)
+    with jax.named_scope("moe_experts"):
+        rows = _ffn_rows(x, tile_expert, params, fused=fused)
+    # entry f's output sits at padded row pos_of_entry[f]; a sentinel's
+    # m_pad reads the zero row
+    with jax.named_scope("moe_combine"):
+        return _take_rows(rows, pos_of_entry, entry_of_row[None])
 
 
+@jax.named_scope("moe_combine")
 def _combine(
     rows: jax.Array,  # [k*S, d] FFN outputs, entry f = choice*S + token
     weights: jax.Array,  # [k, S] f32 combine weights
@@ -395,8 +524,7 @@ def _dropless_mlp(
     k = experts.shape[0]
     ks = k * s
     ef = experts.reshape(ks)  # flat id f = choice*S + token
-    src_rows = jnp.tile(jnp.arange(s, dtype=jnp.int32), k)
-    rows = _gmm_ffn(hf, src_rows, ef, params, e, fused=fused)  # [ks, d]
+    rows = _gmm_ffn(hf, ef, params, e, fused=fused)  # [ks, d]
     return _combine(rows, weights, hf.dtype)
 
 
@@ -443,9 +571,10 @@ def _dropless_shard_fn(
     s_loc, d = hf_loc.shape
     k = top_k
     ks = k * s_loc
-    gate_logits = hf_loc.astype(jnp.float32) @ params["router"]
+    bias = params.get("router_bias")
+    gate_logits = _router_logits(hf_loc, params["router"])
     experts, _, gates, _, (me, ce) = _top_k_gating(
-        gate_logits, k, s_loc + 1, need_slots=False)
+        gate_logits, k, s_loc + 1, need_slots=False, bias=bias)
     # load-balance loss over GLOBAL means: every token axis partitions
     # the token set, so pmean over all of them is the global mean
     me = jax.lax.pmean(me, token_axes)
@@ -499,9 +628,7 @@ def _dropless_shard_fn(
         flat_eid = re.reshape(n_e * qc)
         local_eid = jnp.where(flat_eid < e, flat_eid - ei * e_loc, e_loc)
         rows = rx.reshape(n_e * qc, d)
-        y_rows = _gmm_ffn(
-            rows, jnp.arange(n_e * qc, dtype=jnp.int32), local_eid,
-            params, e_loc, fused=fused)
+        y_rows = _gmm_ffn(rows, local_eid, params, e_loc, fused=fused)
         if tensor_axes:
             # tensor-parallel experts: w1/w3 are column-blocked and w2
             # row-blocked over the tensor axis (classic TP MLP), so each
@@ -530,8 +657,9 @@ def _dropless_shard_fn(
     slot_of_entry = jnp.zeros((ks,), jnp.int32).at[order].set(slot)
     kept = jnp.zeros((ks,), bool).at[order].set(kept_sorted).reshape(k, s_loc)
     weights = gates * kept
-    weights = weights / jnp.maximum(
-        jnp.sum(weights, axis=0, keepdims=True), 1e-9)
+    if bias is None:
+        weights = weights / jnp.maximum(
+            jnp.sum(weights, axis=0, keepdims=True), 1e-9)
     back_flat = jnp.concatenate(
         [back.reshape(n_e * quota, d), jnp.zeros((1, d), back.dtype)], axis=0)
     y = jnp.zeros((s_loc, d), hf_loc.dtype)
@@ -616,28 +744,41 @@ def _dropless_mlp_sharded(
                     "s": P(expert_axis, eout)}
         return P(expert_axis, ein, eout)
 
-    in_specs = (
-        P(token_axes, None),
-        {
-            "router": P(None, None),
-            "w1": wspec(params["w1"]),
-            "w3": wspec(params["w3"]),
-            "w2": wspec(params["w2"], transpose=True),
-        },
-    )
+    layer_specs = {
+        "router": P(None, None),
+        "w1": wspec(params["w1"]),
+        "w3": wspec(params["w3"]),
+        "w2": wspec(params["w2"], transpose=True),
+    }
+    if "router_bias" in params:
+        layer_specs["router_bias"] = P(None)
     fn = functools.partial(
         _dropless_shard_fn, top_k=top_k, e=e, e_loc=e_loc, n_e=n_e,
         quota=quota, expert_axis=expert_axis, token_axes=token_axes,
         tensor_axes=mlp_axes, fused=fused, a2a_chunks=a2a_chunks)
     return jax.shard_map(
         fn, mesh=mesh,
-        in_specs=in_specs,
+        in_specs=(P(token_axes, None), layer_specs),
         out_specs=(P(token_axes, None), P()),
         check_vma=False,
-    )(hf, {k: params[k] for k in ("router", "w1", "w3", "w2")})
+    )(hf, {k: params[k] for k in layer_specs})
 
 
-def moe_mlp(
+def _router_logits(hf: jax.Array, router: jax.Array) -> jax.Array:
+    """[S, n_out] float32 router logits. A top-k choice hinges on the gap
+    between two scores, so the product keeps float32's mantissa on a TPU
+    too (its default for a float32 matmul is one bf16 pass)."""
+    return jnp.dot(hf.astype(jnp.float32), router.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def moe_mlp(h: jax.Array, params: Dict, **kw) -> Tuple[jax.Array, jax.Array]:
+    """`moe_layer` without its counters: (output [b,t,d], aux loss)."""
+    y, aux, _ = moe_layer(h, params, **kw)
+    return y, aux
+
+
+def moe_layer(
     h: jax.Array,  # [b, t, d] normed hidden states
     params: Dict,
     *,
@@ -648,8 +789,20 @@ def moe_mlp(
     dropless: Optional[bool] = None,
     fused: Optional[bool] = None,
     a2a_chunks: int = 1,
-) -> Tuple[jax.Array, jax.Array]:
-    """Returns (output [b,t,d], aux_load_balance_loss scalar).
+    first_expert: int = 0,
+) -> Tuple[jax.Array, jax.Array, Dict]:
+    """Returns (output [b,t,d], aux_load_balance_loss scalar, counters).
+
+    The counters (`_dispatch_stats`) are filled on the single-device
+    dropless route and empty elsewhere.
+
+    The router's width is `params["router"].shape[1]`; the experts held
+    are the `w1.shape[0]` from `first_expert` on. Where that is fewer
+    than the router's outputs the layer computes its own experts' part
+    of the result (module docstring): single-device dropless route only,
+    since the rest of the result lives on chips this program does not
+    exchange with. A `router_bias` in `params` selects the sigmoid
+    router (`_sigmoid_gating`).
 
     dropless=None (auto): use the grouped-matmul kernel only when there
     is no multi-device mesh — it processes exactly the routed tokens (no
@@ -678,6 +831,25 @@ def moe_mlp(
     s = b * t
     w1 = params["w1"]
     e = (w1["q"] if isinstance(w1, dict) else w1).shape[0]
+    n_out = params["router"].shape[1]
+    bias = params.get("router_bias")
+    multi_device = mesh is not None and mesh.size > 1
+    if e != n_out:
+        if first_expert < 0 or first_expert + e > n_out:
+            raise ValueError(
+                f"experts {first_expert}..{first_expert + e - 1} are not "
+                f"among the router's {n_out} outputs")
+        if multi_device:
+            raise NotImplementedError(
+                f"a layer that holds {e} of its router's {n_out} experts "
+                f"runs on one device: the token exchange (all-to-all over "
+                f"the `expert` mesh axis) that would bring it the other "
+                f"chips' rows is not implemented, mesh {dict(mesh.shape)}")
+        if dropless is False:
+            raise ValueError(
+                "a layer that holds part of its experts is dropless only: "
+                "capacity slots have no sentinel for an absent expert")
+        dropless = True
     c = expert_capacity(s, e, top_k, capacity_factor)
     if dropless is None:
         # auto only where the gmm path is validated: no mesh (or a
@@ -686,7 +858,7 @@ def moe_mlp(
         # would force full replication of activations — so multi-device
         # meshes default to the capacity/scatter path; dropless=True
         # forces the gmm route regardless.
-        dropless = mesh is None or mesh.size <= 1
+        dropless = not multi_device
     if fused is None:
         fused = True
 
@@ -696,23 +868,29 @@ def moe_mlp(
         return jax.lax.with_sharding_constraint(x, rules.sharding(mesh, *dims))
 
     hf = h.reshape(s, d)
-    if dropless and mesh is not None and mesh.size > 1:
+    if dropless and multi_device:
         # expert-parallel dropless: shard_map + all_to_all dispatch; the
         # router runs per-device inside the shard body
         y, aux = _dropless_mlp_sharded(
             hf, params, top_k=top_k, quota_factor=capacity_factor,
             mesh=mesh, rules=rules, e=e, fused=fused, a2a_chunks=a2a_chunks)
-        return y.reshape(b, t, d), aux
-    gate_logits = hf.astype(jnp.float32) @ params["router"]
+        return y.reshape(b, t, d), aux, {}
+    with jax.named_scope("moe_route"):
+        gate_logits = _router_logits(hf, params["router"])
+        experts, slots, weights, keeps, (me, ce) = _top_k_gating(
+            gate_logits, top_k, c, need_slots=not dropless, bias=bias)
+        aux = n_out * jnp.sum(me * ce)
+        if dropless and e != n_out:
+            # a choice of an expert held elsewhere becomes the sentinel e:
+            # no row is computed for it and it adds nothing here
+            local = experts - first_expert
+            experts = jnp.where((local >= 0) & (local < e), local, e)
     if dropless:
-        experts, _, gates, _, (me, ce) = _top_k_gating(
-            gate_logits, top_k, s + 1, need_slots=False)
-        # unlimited capacity: every choice keeps, so `gates` arrives
-        # renormalized over all k choices — true dropless
-        y = _dropless_mlp(hf, params, experts, gates, e, fused=fused)
-        return y.reshape(b, t, d), e * jnp.sum(me * ce)
-    experts, slots, weights, keeps, (me, ce) = _top_k_gating(gate_logits, top_k, c)
-    aux = e * jnp.sum(me * ce)
+        # unlimited capacity: every choice keeps, so `weights` arrives
+        # normalized over all k choices — true dropless
+        y = _dropless_mlp(hf, params, experts, weights, e, fused=fused)
+        stats = _dispatch_stats(experts.reshape(-1), e)
+        return y.reshape(b, t, d), aux, stats
 
     def emm(x, w, eq):
         """Batched expert matmul; int8 stacks ({q, s}, models/quant.py)
@@ -747,4 +925,4 @@ def moe_mlp(
     y = jnp.zeros((s, d), h.dtype)
     for k in range(flat.shape[0]):
         y = y + weights[k][:, None].astype(h.dtype) * out_pad[flat[k]]
-    return y.reshape(b, t, d), aux
+    return y.reshape(b, t, d), aux, {}

@@ -257,6 +257,7 @@ class ServingEngine:
         draft_config: Optional[LlamaConfig] = None,
         spec_k: int = 4,
     ) -> None:
+        config.require_kv_state_only("ServingEngine")
         self.params = params
         self.config = config
         self.slots = slots

@@ -55,6 +55,16 @@ Shared mechanics:
     innermost name on the stack, which the HLO instruction takes, is the
     kernel's own also under a bare `jax.grad` (flash_attention.py).
 
+Live row tiles: a row-tile whose `tile_expert` entry is E (one past the
+last expert of `rhs`) holds no routed row. Such tiles are a suffix of
+the layout (moe.py `_dispatch_plan` sorts the rows it holds first), and
+every kernel's grid stops before them: the row-tile grid dimension is
+the traced count of live tiles, so device time follows the rows routed
+here and not the static worst case `k*S + E*tile` in which every entry
+lands here. Rows of dead tiles are never written and never read back
+(the dispatch gathers only routed rows); the reductions over tiles
+(`_owned_mask`, `_tile_segsum`) drop them by their out-of-range id.
+
 Like ops/flash_attention.py, kernels run in interpret mode on the CPU
 backend only, so CPU tests exercise the real kernel logic and any other
 backend compiles them or raises.
@@ -108,6 +118,22 @@ def _row_tile_of(m: int, tile_expert, name: str) -> int:
             "whole tiles and a ragged tail would silently never be "
             "computed")
     return tm
+
+
+def _live_tiles(tile_expert, n_experts: int):
+    """Row-tiles the grid visits: those owned by a real expert (dead ones
+    carry the id `n_experts` and come last). At least one, so that a
+    layout with no routed row still launches a defined grid; that tile
+    multiplies zeros, as every padding row does."""
+    return jnp.maximum(jnp.sum(tile_expert < n_experts, dtype=jnp.int32), 1)
+
+
+def _visited_ids(tile_expert, n_experts: int):
+    """The per-tile ids the kernels' index maps read. Only live tiles are
+    visited, but for the one tile `_live_tiles` launches where nothing is
+    live: its id is the dead mark, one past the last expert, and must not
+    reach a BlockSpec (the chip halts on the out-of-range block)."""
+    return jnp.minimum(tile_expert, n_experts - 1)
 
 
 def _pick(dim: int, pref: int) -> int:
@@ -209,14 +235,14 @@ def _gmm_raw(lhs, rhs, tile_expert, out_scale=None):
     tm = _row_tile_of(m, tile_expert, "gmm")
     tk, tn = _pick_tiles(k, n, lhs.dtype)
     nk = k // tk
-    grid = (m // tm, n // tn, nk)
+    grid = (_live_tiles(tile_expert, rhs.shape[0]), n // tn, nk)
     if out_scale is None:
         kernel = functools.partial(_gmm_kernel, nk=nk)
         in_specs = [
             pl.BlockSpec((tm, tk), lambda i, j, kk, te: (i, kk)),
             pl.BlockSpec((1, tk, tn), lambda i, j, kk, te: (te[i], kk, j)),
         ]
-        operands = (tile_expert, lhs, rhs)
+        operands = (_visited_ids(tile_expert, rhs.shape[0]), lhs, rhs)
     else:
         kernel = functools.partial(_gmm_scaled_kernel, nk=nk)
         in_specs = [
@@ -224,7 +250,8 @@ def _gmm_raw(lhs, rhs, tile_expert, out_scale=None):
             pl.BlockSpec((1, tk, tn), lambda i, j, kk, te: (te[i], kk, j)),
             _scale_spec(tn),
         ]
-        operands = (tile_expert, lhs, rhs, out_scale[:, None, :])
+        operands = (_visited_ids(tile_expert, rhs.shape[0]), lhs, rhs,
+                    out_scale[:, None, :])
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -254,7 +281,7 @@ def _gmm_swiglu_raw(lhs, w1, w3, tile_expert, scale1, scale3):
     tm = _row_tile_of(m, tile_expert, "gmm_swiglu")
     tk, tn = _pick_tiles(k, n, lhs.dtype)
     nk = k // tk
-    grid = (m // tm, n // tn, nk)
+    grid = (_live_tiles(tile_expert, w1.shape[0]), n // tn, nk)
     return pl.pallas_call(
         functools.partial(_gmm_swiglu_kernel, nk=nk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -281,7 +308,8 @@ def _gmm_swiglu_raw(lhs, w1, w3, tile_expert, scale1, scale3):
             flops=4 * m * k * n, bytes_accessed=0, transcendentals=m * n),
         interpret=interpret(),
         name="gmm_swiglu",
-    )(tile_expert, lhs, w1, w3, scale1[:, None, :], scale3[:, None, :])
+    )(_visited_ids(tile_expert, w1.shape[0]), lhs, w1, w3,
+      scale1[:, None, :], scale3[:, None, :])
 
 
 # -- transposed (weight-gradient) --------------------------------------------
@@ -302,13 +330,13 @@ def _tgmm_kernel(te_ref, first_ref, lhs_ref, dout_ref, out_ref):
 
 def _tgmm_raw(lhs, dout, tile_expert, first_tile, n_experts):
     """drhs[e] = sum over e's row-tiles of lhs_tile^T @ dout_tile.
-    Experts with no tiles keep whatever was in their block — callers
+    Experts with no live tile keep whatever was in their block — callers
     mask them to zero (cheap jnp.where on group counts)."""
     m, k = lhs.shape
     _, n = dout.shape
     tm = _row_tile_of(m, tile_expert, "tgmm")
     tk, tn = _pick_tiles(k, n, lhs.dtype)
-    grid = (k // tk, n // tn, m // tm)
+    grid = (k // tk, n // tn, _live_tiles(tile_expert, n_experts))
     return pl.pallas_call(
         _tgmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -330,7 +358,7 @@ def _tgmm_raw(lhs, dout, tile_expert, first_tile, n_experts):
             flops=2 * m * k * n, bytes_accessed=0, transcendentals=0),
         interpret=interpret(),
         name="gmm_drhs",
-    )(tile_expert, first_tile, lhs, dout)
+    )(_visited_ids(tile_expert, n_experts), first_tile, lhs, dout)
 
 
 # -- shared backward helpers -------------------------------------------------
@@ -338,7 +366,8 @@ def _tgmm_raw(lhs, dout, tile_expert, first_tile, n_experts):
 
 def _owned_mask(tile_expert, n_experts):
     """[E] int32 count of row-tiles each expert owns (0 = never written
-    by tgmm — its block is garbage and must be masked)."""
+    by tgmm — its block is garbage and must be masked). Dead tiles carry
+    the id E and are dropped."""
     return jnp.zeros((n_experts,), jnp.int32).at[tile_expert].add(
         1, mode="drop")
 
@@ -346,7 +375,8 @@ def _owned_mask(tile_expert, n_experts):
 def _bcast_tile_scale(x, scale, tile_expert):
     """x[m, n] * scale[tile_expert][...] without materializing a [m, n]
     repeat array: the per-tile [n] vectors broadcast over a reshaped
-    [tiles, row_tile, n] view (XLA fuses the whole thing)."""
+    [tiles, row_tile, n] view (XLA fuses the whole thing). A dead tile's
+    out-of-range id clamps to the last expert; its rows are never read."""
     m, n = x.shape
     nt = tile_expert.shape[0]
     return (
@@ -358,10 +388,12 @@ def _bcast_tile_scale(x, scale, tile_expert):
 def _tile_segsum(x, tile_expert, n_experts):
     """[E, N] per-expert sum of x's rows (x [m, n]) — the dscale
     reduction: each tile's rows collapse, then tiles scatter-add into
-    their owning expert's row."""
+    their owning expert's row. Dead tiles (id E) hold unwritten rows:
+    zeroed before the sum, since the scatter's drop comes after it."""
     m, n = x.shape
     nt = tile_expert.shape[0]
-    per_tile = x.reshape(nt, m // nt, n).sum(axis=1)
+    live = (tile_expert < n_experts)[:, None, None]
+    per_tile = jnp.where(live, x.reshape(nt, m // nt, n), 0).sum(axis=1)
     return jnp.zeros((n_experts, n), x.dtype).at[tile_expert].add(
         per_tile, mode="drop")
 
@@ -434,7 +466,8 @@ def gmm(lhs, rhs, tile_expert, *, row_tile: int = TILE_M):
     per-group padded to `row_tile` by the caller — see moe.py's
     dropless dispatch, which uses wider tiles for large dispatches to
     amortize the per-tile weight stream). Padding rows are zeros; they
-    multiply into zeros and are never gathered back."""
+    multiply into zeros and are never gathered back. Tiles named E (a
+    suffix) are dead: not computed, their output rows left unwritten."""
     _check_row_tile(lhs.shape[0], tile_expert, row_tile, "gmm")
     return _gmm_vjp(lhs, rhs, tile_expert)
 

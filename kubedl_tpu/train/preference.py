@@ -118,8 +118,8 @@ def sequence_logprobs(
     returns ([n, T-1] logprobs, [n, T-1] f32 continuation mask) instead —
     the shape GRPO's per-token importance ratios need (train/rl.py)."""
     rules_ = rules
-    x, aux = llama._backbone(params, tokens, config, mesh, rules_ or
-                             llama.ShardingRules())
+    x, aux, _ = llama._backbone(params, tokens, config, mesh, rules_ or
+                                llama.ShardingRules())
     targets = tokens[:, 1:]
     head_is_plain = isinstance(
         llama._head_matrix(params, config), jax.Array)

@@ -51,8 +51,12 @@ class StepRecorder:
         self.ckpt_stall = 0.0
 
     def dispatched(self, step: int, loss, t_start: float, data_s: float,
-                   dispatch_s: float, compiled: bool) -> None:
-        self.pending.append((step, loss, t_start, data_s, dispatch_s, compiled))
+                   dispatch_s: float, compiled: bool, counters=None) -> None:
+        """`counters`: what the step returned beside its loss and gradient
+        norm (an expert model's `moe_*` / `gmm_*`, llama.loss_and_stats);
+        they ride the step's record, read when its loss is."""
+        self.pending.append((step, loss, t_start, data_s, dispatch_s, compiled,
+                             counters or {}))
         while len(self.pending) > IN_FLIGHT:
             self._await_oldest()
 
@@ -61,9 +65,11 @@ class StepRecorder:
             self._await_oldest()
 
     def _await_oldest(self) -> None:
-        step, loss, t_start, data_s, dispatch_s, compiled = self.pending.popleft()
+        (step, loss, t_start, data_s, dispatch_s, compiled,
+         counters) = self.pending.popleft()
         with self.tracer.span("train.wait", export=False, step=step) as wait:
             loss_v = float(loss)
+        counters = {k: float(v) for k, v in counters.items()}
         now = time.perf_counter()
         step_s = now - max(self.last_done, t_start)
         self.last_done = now
@@ -71,7 +77,7 @@ class StepRecorder:
             "train.compile" if compiled else "train.step",
             duration_s=step_s, step=step, loss=loss_v,
             data_wait_s=round(data_s, 6), dispatch_s=round(dispatch_s, 6),
-            wait_s=round(wait.dur, 6))
+            wait_s=round(wait.dur, 6), **counters)
         if self.step_stream is not None:
             self.step_stream.record(
                 step, step_s, data_s=data_s, loss=loss_v, compile=compiled,
@@ -82,7 +88,7 @@ class StepRecorder:
 def parse_args(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--model", default=os.environ.get("KUBEDL_MODEL", "tiny"),
-                   choices=["tiny", "bench-1b", "llama-7b"])
+                   choices=["tiny", "bench-1b", "llama-7b", "lfm2-8b-a1b"])
     p.add_argument("--steps", type=int, default=int(os.environ.get("KUBEDL_STEPS", 100)))
     p.add_argument("--batch", type=int, default=int(os.environ.get("KUBEDL_BATCH", 8)))
     p.add_argument("--seq-len", type=int, default=int(os.environ.get("KUBEDL_SEQ_LEN", 512)))
@@ -425,10 +431,18 @@ def main(argv=None) -> int:
                 """Mesh-dependent compute, rebuilt after a live reshard."""
                 spec_tree = (llama.param_specs_pp(config, rules) if pipelined
                              else llama.param_specs(config, rules))
+                if pipelined:
+                    step_loss = loss_on(a_mesh)
+                else:
+                    def step_loss(params, batch):
+                        # (loss, an expert model's counters, which ride the
+                        # train.step record: {} for a dense model)
+                        return llama.loss_and_stats(
+                            params, batch, config, mesh=a_mesh, rules=rules)
                 return make_train_step(
-                    loss_on(a_mesh), tx, a_mesh, spec_tree,
+                    step_loss, tx, a_mesh, spec_tree,
                     rules.spec("batch", None), rules,
-                    accum_steps=args.accum_steps,
+                    accum_steps=args.accum_steps, has_aux=not pipelined,
                 )
 
             init_state, train_step = build_step(mesh)
@@ -785,7 +799,9 @@ def main(argv=None) -> int:
                     if recorder is not None:
                         recorder.dispatched(
                             step + 1, metrics["loss"], t_step0, data_span.dur,
-                            dispatch_span.dur, compile_pending["v"])
+                            dispatch_span.dur, compile_pending["v"],
+                            {k: v for k, v in metrics.items()
+                             if k.startswith(("moe_", "gmm_"))})
                         compile_pending["v"] = False
             if prof is not None and prof.should_stop(step):
                 settle(metrics["loss"])
