@@ -11,6 +11,12 @@ Layout: [batch*heads, seq, head_dim]. The public entry handles GQA by
 broadcasting KV heads, pads ragged sequence lengths to block multiples, and
 installs a custom VJP wiring the two kernels together.
 
+Each kernel reads a block's fate off its position (`_segments`): a block
+with no pair inside the causal diagonal, the window and the true length
+is never visited. In `flash_bwd_dq`, the one kernel the vector unit binds
+on a v5e, a block whose every pair is inside them runs a body with no
+mask; `block_plan` counts both kinds for a shape.
+
 Each `pallas_call` carries a fixed `name=`, which the device trace shows as
 the event's name: `flash_fwd`, `flash_fwd_streamed`, `flash_bwd_dq`,
 `flash_bwd_dkv`. The benchmark's by-name metrics read them (PERF.md section 3).
@@ -53,6 +59,83 @@ NEG_INF = -1e30
 # the forward kernel again; anywhere else the tag is the identity.
 FLASH_OUT = "flash_out"
 FLASH_LSE = "flash_lse"
+
+
+# ---------------------------------------------------------------------------
+# which blocks a kernel visits, and which of them need a mask
+# ---------------------------------------------------------------------------
+
+# The two inner loops: forward and dq walk K blocks under one Q block,
+# dk/dv walks Q blocks under one K block.
+SIDES = ("fwd_dq", "dkv")
+
+
+def _segments(outer, side, *, seq_len, window, block_q, block_k, causal,
+              xp=jnp):
+    """The inner loop's block ranges under outer block `outer`, as
+    `(start, lo, hi, stop)`: `[start, stop)` holds exactly the blocks with
+    at least one live pair, `[lo, hi)` those whose every pair is live
+    (inside the diagonal, the window and `seq_len`), and `[start, lo)` /
+    `[hi, stop)` the blocks an edge crosses. Traced inside the kernels
+    (`outer` a program id) and evaluated by `block_plan` on the host
+    (`outer` an index array, `xp` numpy): one arithmetic for both."""
+    zero, top = xp.zeros_like(outer), seq_len - 1
+    if side == "fwd_dq":
+        # rows first..last of Q: the keys live for some row and for every
+        # row. The window's edge is the low edge, the diagonal the high one.
+        first, last, inner = outer * block_q, (outer + 1) * block_q - 1, block_k
+        some = (zero, last if causal else top)
+        every = (zero, first if causal else top)
+        if window is not None:
+            some = (xp.maximum(first - window + 1, 0), some[1])
+            every = (xp.maximum(last - window + 1, 0), every[1])
+    elif side == "dkv":
+        # columns first..last of K: the queries live for some column and
+        # for every column. The diagonal is the low edge, the window's the
+        # high one.
+        first, last, inner = outer * block_k, (outer + 1) * block_k - 1, block_q
+        some = (first if causal else zero, top)
+        every = (last if causal else zero, top)
+        if window is not None:
+            some = (some[0], xp.minimum(last, top) + window - 1)
+            every = (every[0], first + window - 1)
+    else:
+        raise ValueError(f"side must be one of {SIDES}, got {side!r}")
+    start = some[0] // inner
+    stop = xp.minimum(some[1], top) // inner + 1
+    lo = (every[0] + inner - 1) // inner
+    hi = (xp.minimum(every[1], top) + 1) // inner
+    # an outer block wholly in the padded tail has no live pair, one that
+    # reaches into it no interior block
+    stop = xp.where(first <= top, xp.maximum(stop, start), start)
+    lo = xp.clip(lo, start, stop)
+    hi = xp.where(last <= top, xp.clip(hi, lo, stop), lo)
+    return start, lo, hi, stop
+
+
+def block_plan(seq_len, window, block_q, block_k, causal, side):
+    """`(visited, interior)`: the block pairs one head's call of a kernel
+    visits, and how many of them hold live pairs alone. `side` is "fwd_dq"
+    (`flash_fwd`, `flash_bwd_dq`) or "dkv" (`flash_bwd_dkv`). `flash_bwd_dq`
+    runs its interior blocks with no mask; the other two mask every block
+    they visit. A function of the shape alone, from the kernels' own
+    `_segments` in numpy: no device is touched."""
+    outer = block_q if side == "fwd_dq" else block_k
+    start, lo, hi, stop = _segments(
+        np.arange(pl.cdiv(seq_len, outer)), side, seq_len=seq_len,
+        window=window, block_q=block_q, block_k=block_k, causal=causal, xp=np)
+    return int(np.sum(stop - start)), int(np.sum(hi - lo))
+
+
+def _pair_mask(q_pos, k_pos, seq_len, causal, window):
+    """Which (query, key) pairs of a block are live."""
+    mask = (k_pos < seq_len) & (q_pos < seq_len)
+    if causal:
+        mask = mask & (k_pos <= q_pos)
+    if window is not None:
+        mask = mask & (k_pos > q_pos - window)
+    return mask
+
 
 # ---------------------------------------------------------------------------
 # forward kernel
@@ -103,28 +186,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
 
     q_pos = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
 
-    num_kb = pl.cdiv(seq_len, block_k)
-    if causal:
-        # K blocks strictly above the diagonal contribute nothing.
-        num_kb = jnp.minimum(num_kb, (qb + 1) * block_q // block_k + 1)
-    start_kb = jnp.int32(0)
-    if window is not None:
-        # K blocks entirely below every query's window contribute nothing.
-        start_kb = jnp.maximum(0, (qb * block_q - window + 1) // block_k)
-
     def body(kb, carry):
         m, l, acc = carry
         k = k_ref[0, pl.ds(kb * block_k, block_k), :]
         v = v_ref[0, pl.ds(kb * block_k, block_k), :]
         k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = (k_pos < seq_len) & (q_pos < seq_len)
-        if causal:
-            mask = mask & (k_pos <= q_pos)
-        if window is not None:
-            mask = mask & (k_pos > q_pos - window)
+        mask = _pair_mask(q_pos, k_pos, seq_len, causal, window)
         return _online_softmax_step(q, k, v, m, l, acc, sm_scale, mask,
                                     softcap)
 
+    start_kb, _, _, num_kb = _segments(
+        qb, "fwd_dq", seq_len=seq_len, window=window, block_q=block_q,
+        block_k=block_k, causal=causal)
     m, l, acc = jax.lax.fori_loop(start_kb, num_kb, body, (m0, l0, acc0))
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
@@ -284,38 +357,41 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     delta = delta_ref[0, 0][:, None]
     q_pos = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
 
-    num_kb = pl.cdiv(seq_len, block_k)
-    if causal:
-        num_kb = jnp.minimum(num_kb, (qb + 1) * block_q // block_k + 1)
-    start_kb = jnp.int32(0)
-    if window is not None:
-        start_kb = jnp.maximum(0, (qb * block_q - window + 1) // block_k)
+    def body(masked):
+        def step(kb, dq):
+            k = k_ref[0, pl.ds(kb * block_k, block_k), :]
+            v = v_ref[0, pl.ds(kb * block_k, block_k), :]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * sm_scale
+            if softcap is not None:
+                s = _softcap_scores(s, softcap)
+            p = jnp.exp(s - lse)
+            if masked:
+                k_pos = kb * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                p = jnp.where(
+                    _pair_mask(q_pos, k_pos, seq_len, causal, window), p, 0.0)
+            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - delta)
+            if softcap is not None:
+                # d/dx[cap*tanh(x/cap)] = 1 - tanh(x/cap)^2 = 1 - (s/cap)^2
+                ds = ds * (1.0 - (s / softcap) ** 2)
+            return dq + jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return step
 
-    def body(kb, dq):
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if softcap is not None:
-            s = _softcap_scores(s, softcap)
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = (k_pos < seq_len) & (q_pos < seq_len)
-        if causal:
-            mask = mask & (k_pos <= q_pos)
-        if window is not None:
-            mask = mask & (k_pos > q_pos - window)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        if softcap is not None:
-            # d/dx[cap*tanh(x/cap)] = 1 - tanh(x/cap)^2 = 1 - (s/cap)^2
-            ds = ds * (1.0 - (s / softcap) ** 2)
-        return dq + jax.lax.dot_general(ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-
-    dq0 = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-    dq = jax.lax.fori_loop(start_kb, num_kb, body, dq0)
+    # ascending as one loop would run: the blocks the window's edge crosses,
+    # the interior ones with no mask, the blocks the diagonal or the padded
+    # tail crosses
+    start_kb, lo, hi, num_kb = _segments(
+        qb, "fwd_dq", seq_len=seq_len, window=window, block_q=block_q,
+        block_k=block_k, causal=causal)
+    dq = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
+    dq = jax.lax.fori_loop(start_kb, lo, body(True), dq)
+    dq = jax.lax.fori_loop(lo, hi, body(False), dq)
+    dq = jax.lax.fori_loop(hi, num_kb, body(True), dq)
     dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
@@ -326,16 +402,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
     k = k_ref[0]  # bf16 into the MXU; f32 accumulation
     v = v_ref[0]
     k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-
-    num_qb = pl.cdiv(seq_len, block_q)
-    start_qb = jnp.int32(0)
-    if causal:
-        # Q blocks strictly before this K block see none of it.
-        start_qb = kb * block_k // block_q
-    if window is not None:
-        # Q blocks whose every query is past this K block's window.
-        num_qb = jnp.minimum(
-            num_qb, ((kb + 1) * block_k - 1 + window) // block_q + 1)
 
     def body(qb, carry):
         dk, dv = carry
@@ -348,11 +414,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         if softcap is not None:
             s = _softcap_scores(s, softcap)
         q_pos = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        mask = (k_pos < seq_len) & (q_pos < seq_len)
-        if causal:
-            mask = mask & (k_pos <= q_pos)
-        if window is not None:
-            mask = mask & (k_pos > q_pos - window)
+        mask = _pair_mask(q_pos, k_pos, seq_len, causal, window)
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         pb = p.astype(do.dtype)
         dv = dv + jax.lax.dot_general(pb, do, (((0,), (0,)), ((), ())),
@@ -367,6 +429,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
                                       preferred_element_type=jnp.float32)
         return dk, dv
 
+    start_qb, _, _, num_qb = _segments(
+        kb, "dkv", seq_len=seq_len, window=window, block_q=block_q,
+        block_k=block_k, causal=causal)
     dk0 = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
     dv0 = jnp.zeros((block_k, v.shape[-1]), jnp.float32)
     dk, dv = jax.lax.fori_loop(start_qb, num_qb, body, (dk0, dv0))

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from kubedl_tpu.ops import flash_attention as fa
 from kubedl_tpu.ops.flash_attention import attention_reference, flash_attention
 
 
@@ -336,3 +337,161 @@ def test_softcap_streamed_path():
         fa.STREAM_MIN_SEQ = orig
     ref = attention_reference(q, k, v, causal=True, softcap=15.0)
     np.testing.assert_allclose(out, ref, atol=2e-3, rtol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# Blocks by position: dead ones skipped in every kernel, interior ones
+# mask-free in dq (ops/flash_attention.py:_segments)
+# ---------------------------------------------------------------------------
+
+
+def _loss(attention, **kw):
+    return lambda q, k, v: jnp.sum(attention(q, k, v, **kw) ** 2)
+
+
+# (seq, window, block_q, block_k, causal, softcap)
+BLOCK_KINDS = {
+    "window_of_whole_blocks": (512, 256, 128, 128, True, None),
+    "window_inside_a_block": (512, 300, 128, 128, True, None),
+    "window_under_one_block": (512, 50, 128, 128, True, None),
+    "wide_q_blocks": (512, 256, 256, 128, True, None),
+    "wide_k_blocks": (512, 256, 128, 256, True, None),
+    "ragged_window": (450, 192, 128, 128, True, None),
+    "ragged_wide_k_blocks": (600, None, 128, 256, True, None),
+    "full_causal": (512, None, 128, 128, True, None),
+    "not_causal": (512, None, 128, 128, False, None),
+    # the tail's edge lies in the last column of blocks, interior otherwise
+    "not_causal_ragged": (450, None, 128, 128, False, None),
+    "softcap": (512, 300, 128, 128, True, 20.0),
+}
+
+
+@pytest.mark.parametrize("shape", BLOCK_KINDS.values(), ids=BLOCK_KINDS.keys())
+def test_every_kind_of_block_matches_reference(shape):
+    seq, window, block_q, block_k, causal, softcap = shape
+    # the shape holds what its name says: edge blocks, and interior ones
+    # wherever the window is wide enough to hold a whole block
+    for side in fa.SIDES:
+        visited, interior = fa.block_plan(
+            seq, window, block_q, block_k, causal, side)
+        assert interior <= visited, (side, visited, interior)
+        assert (interior > 0) == (
+            window is None or window >= block_q + block_k - 1), side
+    q, k, v = rand_qkv(b=1, hq=2, hkv=2, s=seq, d=64)
+    kw = dict(causal=causal, window=window, softcap=softcap)
+    blocks = dict(block_q=block_q, block_k=block_k)
+    out = flash_attention(q, k, v, **kw, **blocks)
+    ref = attention_reference(q, k, v, **kw)
+    np.testing.assert_allclose(out, ref, atol=2e-3, rtol=2e-3)
+    gf = jax.grad(_loss(flash_attention, **kw, **blocks), (0, 1, 2))(q, k, v)
+    gr = jax.grad(_loss(attention_reference, **kw), (0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(a, b, atol=5e-3, rtol=5e-3,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("shape", [
+    (512, 300, 128, 128, True, None),
+    (450, None, 128, 256, True, None),
+    (450, None, 256, 128, False, None),
+    (512, 300, 128, 128, True, 20.0),
+], ids=["window", "ragged_wide_k_blocks", "not_causal_ragged", "softcap"])
+def test_the_mask_free_body_changes_no_bit(shape, monkeypatch):
+    """Every block under the masked body (an empty interior range) against
+    the kernels as they are: dq's `where(mask, p, 0)` with the mask all
+    true is `p`, so `out` and the three gradients are equal bit for bit."""
+    seq, window, block_q, block_k, causal, softcap = shape
+    q, k, v = rand_qkv(b=1, hq=2, hkv=2, s=seq, d=64)
+    kw = dict(causal=causal, window=window, softcap=softcap,
+              block_q=block_q, block_k=block_k)
+
+    def run():
+        out = flash_attention(q, k, v, **kw)
+        return (out,) + jax.grad(_loss(flash_attention, **kw), (0, 1, 2))(q, k, v)
+
+    real = run()
+    segments = fa._segments
+    interior = []
+
+    def all_masked(*a, **kwargs):
+        start, lo, hi, stop = segments(*a, **kwargs)
+        interior.append((lo, hi))
+        return start, stop, stop, stop
+
+    monkeypatch.setattr(fa, "_segments", all_masked)
+    masked = run()
+    assert len(interior) >= 3  # forward, dq and dk/dv all asked
+    for a, b, name in zip(real, masked, ("out", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+
+
+def _live_pairs(seq, window, block_q, block_k, causal):
+    """The mask itself, pair by pair over the padded square, cut into
+    blocks: [q blocks, k blocks, block_q, block_k]."""
+    import math
+
+    lcm = math.lcm(block_q, block_k)
+    padded = -(-seq // lcm) * lcm
+    q = np.arange(padded)[:, None]
+    k = np.arange(padded)[None, :]
+    live = (q < seq) & (k < seq)
+    if causal:
+        live &= k <= q
+    if window is not None:
+        live &= k > q - window
+    return live.reshape(
+        padded // block_q, block_q, padded // block_k, block_k
+    ).transpose(0, 2, 1, 3)
+
+
+# (seq, window, block_q, block_k, causal): blocks of a few pairs, so that
+# every edge falls on, beside and inside a block boundary
+PLANS = [
+    (64, None, 8, 8, True), (64, 16, 8, 8, True), (64, 13, 8, 8, True),
+    (64, 3, 8, 8, True), (64, 1, 8, 8, True), (61, 24, 8, 8, True),
+    (64, 24, 16, 8, True), (64, 24, 8, 16, True), (50, 20, 4, 16, True),
+    (50, 7, 16, 4, True), (37, None, 4, 8, True), (64, 200, 8, 8, True),
+    (64, None, 8, 8, False), (53, None, 8, 16, False), (53, None, 16, 8, False),
+]
+
+
+@pytest.mark.parametrize("side", ["fwd_dq", "dkv"])
+@pytest.mark.parametrize("seq,window,block_q,block_k,causal", PLANS)
+def test_block_plan_against_brute_force(seq, window, block_q, block_k, causal,
+                                        side):
+    live = _live_pairs(seq, window, block_q, block_k, causal)
+    if side == "dkv":  # outer K blocks, inner Q blocks
+        live = live.transpose(1, 0, 2, 3)
+    n_outer, n_inner = live.shape[:2]
+    start, lo, hi, stop = fa._segments(
+        np.arange(n_outer), side, seq_len=seq, window=window,
+        block_q=block_q, block_k=block_k, causal=causal, xp=np)
+    assert np.all((0 <= start) & (start <= lo) & (lo <= hi) & (hi <= stop)
+                  & (stop <= n_inner))
+    inner = np.arange(n_inner)[None, :]
+    visited = (start[:, None] <= inner) & (inner < stop[:, None])
+    interior = (lo[:, None] <= inner) & (inner < hi[:, None])
+    # visited = live; interior = every pair live
+    np.testing.assert_array_equal(visited, live.any(axis=(2, 3)))
+    np.testing.assert_array_equal(interior, live.all(axis=(2, 3)))
+    assert fa.block_plan(seq, window, block_q, block_k, causal, side) == (
+        int(live.any(axis=(2, 3)).sum()), int(live.all(axis=(2, 3)).sum()))
+
+
+@pytest.mark.parametrize("side", ["fwd_dq", "dkv"])
+@pytest.mark.parametrize("window,plan", [(4096, (108, 84)), (None, (136, 120))])
+def test_block_plan_of_the_benchmark_shapes(window, plan, side):
+    """Sequence 8,192 in blocks of 512: under the old bound the forward
+    and dq visited 123 (window 4,096) and 151 (full causal) pairs a head."""
+    assert fa.block_plan(8192, window, 512, 512, True, side) == plan
+
+
+def test_block_plan_is_counted_on_the_host(monkeypatch):
+    """numpy alone: a tool on a machine with no chip starts no backend."""
+    monkeypatch.setattr(fa, "jnp", None)
+    assert fa.block_plan(8192, 4096, 512, 512, True, "fwd_dq") == (108, 84)
+
+
+def test_block_plan_refuses_an_unknown_side():
+    with pytest.raises(ValueError, match="side"):
+        fa.block_plan(64, None, 8, 8, True, "fwd")

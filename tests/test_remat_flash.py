@@ -133,6 +133,15 @@ def test_the_tag_is_the_identity_outside_a_policy():
     assert calls == dict.fromkeys(KERNELS, config.n_layers)
 
 
+def test_a_window_adds_no_kernel():
+    """Edge and interior blocks are loops inside `flash_bwd_dq`, not
+    kernels of their own: the benchmark's by-name metrics count calls."""
+    config = llama.LlamaConfig.tiny(sliding_window=8)
+    params, tokens = _inputs(config)
+    calls = _kernel_calls(_gradient_equations(config, params, tokens))
+    assert calls == dict.fromkeys(KERNELS, config.n_layers)
+
+
 def test_unknown_policy_is_refused():
     config = llama.LlamaConfig.tiny(remat_policy="all")
     params, tokens = _inputs(config)

@@ -11,6 +11,8 @@ import: only one process may load libtpu, and every xdist worker imports
 this file. For the same reason all of these live in this one file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import optax
@@ -72,10 +74,23 @@ def _kernels(text: str) -> int:
 FLASH_INSTRUCTIONS = ("%flash_fwd.", "%flash_bwd_dq.", "%flash_bwd_dkv.")
 
 
+def _flash_calls(text: str) -> dict:
+    """How many instructions each flash kernel's name defines: what
+    benchmarks/reducers/flash_roofline.py counts as calls, so a kernel
+    split in two under one name would double the least time it credits."""
+    return {name: len(re.findall(re.escape(name) + r"\d+ = ", text))
+            for name in FLASH_INSTRUCTIONS}
+
+
 @pytest.mark.parametrize("shape,window", [
     ((4, 16, 2048, 128), None),
     ((4, 8, 1024, 128), None),
     ((4, 8, 1024, 128), 256),
+    # the benchmark's: Mistral's window under its sequence (window-edge,
+    # interior and diagonal blocks in dq's three loops) and LFM2's padded
+    # head size
+    ((2, 32, 8192, 128), 4096),
+    ((2, 32, 8192, 64), None),
 ])
 def test_flash_fwd_bwd_compiles(one_chip, on_tpu, shape, window):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
@@ -87,8 +102,18 @@ def test_flash_fwd_bwd_compiles(one_chip, on_tpu, shape, window):
     text = _compile(jax.value_and_grad(f, argnums=(0, 1, 2)), x, x, x)
     # forward, dq and dk/dv
     assert _kernels(text) >= 3, "flash fell back to attention_reference"
-    for name in FLASH_INSTRUCTIONS:
-        assert name in text
+    assert _flash_calls(text) == dict.fromkeys(FLASH_INSTRUCTIONS, 1)
+
+
+def test_flash_streamed_fwd_compiles(one_chip, on_tpu):
+    """Past STREAM_MIN_SEQ the forward is the K-streaming kernel (serving
+    prefill), one block a grid step under `pl.when(live)`."""
+    x = jax.ShapeDtypeStruct((1, 8, 16384, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    text = _compile(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, window=4096),
+        x, x, x)
+    assert _kernels(text) == 1 and "%flash_fwd_streamed." in text
 
 
 def test_flash_unaligned_blocks_raise(one_chip, on_tpu):
@@ -215,8 +240,9 @@ def test_sharded_train_step_with_flash_compiles(topo, on_tpu):
     text = compiled.as_text()
     assert _kernels(text) >= 3, "flash is not in the sharded step"
     assert text.startswith("HloModule jit_train_step")
-    for name in FLASH_INSTRUCTIONS:  # by its own name inside the shard_map too
-        assert name in text
+    # by its own name inside the shard_map too, one of each a layer
+    assert _flash_calls(text) == dict.fromkeys(
+        FLASH_INSTRUCTIONS, config.n_layers)
     assert "%shard_map." not in text
     assert "all-gather" in text or "all-reduce" in text
     # the parameters are spread: one device holds about a quarter
