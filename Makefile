@@ -61,79 +61,47 @@ presubmit:
 	  --total tests/test_weights.py=90
 	$(PY) -m pytest tests/ -q -m slow
 
+# Host-only timings of the control plane: the launch-delay headline,
+# then the four lanes below. No accelerator, no JAX, never a device
+# metric: the chip's yardstick is benchmarks/run.py (BENCHMARK.json,
+# PERF.md). Records go to .bench_extras.json, KUBEDL_BENCH_SMALL=1
+# cuts the lanes to smoke sizes.
 .PHONY: bench
 bench:
 	$(PY) bench.py
 
-# MoE-only fast loop: just the llama_moe milestone + the dispatch
-# overhead breakdown (gating/permute/gmm/combine/a2a), printed as JSON.
-.PHONY: bench-moe
-bench-moe:
-	$(PY) bench.py --moe-only
-
-# Serving-only fast loop: the serving throughput milestone + the
-# disaggregated plane's latency/capacity record (paged-KV admission
-# ratio, prefix-share hit-rate, TTFT/per-token p50/p99 mono vs disagg).
-.PHONY: bench-serving
-bench-serving:
-	$(PY) bench.py --serving-only
-
-# Resize-only fast loop: the resize_downtime record — live reshard vs
-# checkpoint-restore downtime for the same shrink/grow on the same model
-# (merges ONLY the resize key into .bench_extras.json).
-.PHONY: bench-resize
-bench-resize:
-	$(PY) bench.py --resize-only
-
-# Pipeline-only fast loop: the pipeline_schedule record — GPipe vs
-# interleaved 1F1B bubble fraction + step time at the bench shape
-# (M=8, S=4, v=2), plus the 2-stage MPMD lane vs the single-program
-# oracle (merges ONLY the pipeline_schedule key into .bench_extras.json).
-.PHONY: bench-pp
-bench-pp:
-	$(PY) bench.py --pipeline-only
-
-# Transport-only fast loop: the transport_roundtrip record — socket
-# plane vs DirChannel msg/s + MB/s at control-sized and boundary-sized
-# (8MB) payloads (merges ONLY the transport_roundtrip key into
+# Host-only: the transport_roundtrip record — socket plane vs
+# DirChannel msg/s + MB/s at control-sized and boundary-sized (8MB)
+# payloads (merges ONLY the transport_roundtrip key into
 # .bench_extras.json; span file at .bench_trace/transport.jsonl).
 .PHONY: bench-transport
 bench-transport:
 	$(PY) bench.py --transport-only
 
-# RL-only fast loop: the rl_throughput record — actor/learner fleet
-# rollout tok/s, learner step/s, weight-sync latency, and the
-# actor-starved vs learner-starved queue-wait split (merges ONLY the
-# rl_throughput key into .bench_extras.json; fleet span timeline at
-# .bench_trace/rl_fleet.jsonl).
-.PHONY: bench-rl
-bench-rl:
-	$(PY) bench.py --rl-only
-
-# Weights-only fast loop: the weight_distribution record — serial
-# hub-and-spoke dial vs the O(log n) broadcast tree at N in {4,16,64}
-# pods over paced loopback planes, per-pod commit p50/p99, relay
-# amplification, and the byte-identity/0.25x gates, under the lock
-# witness (merges ONLY the weight_distribution key into
-# .bench_extras.json; span file at .bench_trace/weights.jsonl).
+# Host-only: the weight_distribution record — serial hub-and-spoke
+# dial vs the O(log n) broadcast tree at N in {4,16,64} pods over
+# paced loopback planes, per-pod commit p50/p99, relay amplification,
+# and the byte-identity/0.25x gates, under the lock witness (merges
+# ONLY the weight_distribution key into .bench_extras.json; span file
+# at .bench_trace/weights.jsonl).
 .PHONY: bench-weights
 bench-weights:
 	$(PY) bench.py --weights-only
 
-# Journal-only fast loop: the journal_wal record — grant-path latency
-# with the write-ahead journal off vs on, raw fsync'd append
-# throughput, and a 1k-gang crash replay (merges ONLY the journal_wal
-# key into .bench_extras.json; span file at .bench_trace/journal.jsonl).
+# Host-only: the journal_wal record — grant-path latency with the
+# write-ahead journal off vs on, raw fsync'd append throughput, and a
+# 1k-gang crash replay (merges ONLY the journal_wal key into
+# .bench_extras.json; span file at .bench_trace/journal.jsonl).
 .PHONY: bench-journal
 bench-journal:
 	$(PY) bench.py --journal-only
 
-# Fleet-scale control-plane loop: the fleet_scale record — 10k-job /
-# 100k-pod closed-loop launch latency through the real operator,
-# sharded-reconcile throughput (1 vs 8 workers), incremental
-# demand-view tick cost, and concurrent group-commit grant cost, all
-# under the lock witness (merges ONLY the fleet_scale key into
-# .bench_extras.json; span file at .bench_trace/fleet.jsonl).
+# Host-only: the fleet_scale record — 10k-job / 100k-pod closed-loop
+# launch latency through the real operator, sharded-reconcile
+# throughput (1 vs 8 workers), incremental demand-view tick cost, and
+# concurrent group-commit grant cost, all under the lock witness
+# (merges ONLY the fleet_scale key into .bench_extras.json; span file
+# at .bench_trace/fleet.jsonl).
 .PHONY: bench-fleet
 bench-fleet:
 	$(PY) bench.py --fleet-only

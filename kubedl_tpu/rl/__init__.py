@@ -19,7 +19,7 @@ repo's own parts:
   * learner.py     — LearnerRuntime: staleness-bounded GRPO updates,
                      weight publishing, checkpointing hooks
   * fleet.py       — in-process harness (threads + QueueChannels) for
-                     tests and `make bench-rl`
+                     tests
   * metrics.py     — kubedl_rl_* families (module singleton, the
                      pipeline_metrics pattern)
 

@@ -2,10 +2,10 @@
 QueueChannels.
 
 The single-process lane of the RL plane, the way MPMDPipeline is the
-single-process lane of the MPMD pipeline: tests and ``make bench-rl``
-drive the REAL ActorRuntime/LearnerRuntime against in-memory channels,
-so the trajectory/broadcast protocol, the staleness bound, and the
-starvation accounting are exercised without pods. The pod-world
+single-process lane of the MPMD pipeline: tests drive the REAL
+ActorRuntime/LearnerRuntime against in-memory channels, so the
+trajectory/broadcast protocol, the staleness bound, and the starvation
+accounting are exercised without pods. The pod-world
 difference is only the transport (DirChannel/SocketChannel) and the
 process boundary — both pinned separately (tests/test_rl.py two-process
 e2e, transport byte-identity pins).
