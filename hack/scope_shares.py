@@ -17,9 +17,11 @@ protobuf wire reader of a few lines and the field numbers of
 this is how the per-scope shares of PERF.md section 5 are read.
 
 Per device plane it prints the device time of each scope as a share of
-the traced window (first operation's start to the last one's end), the
-share that is remat recompute (`rematted_computation` in the `op_name`),
-what no scope covers, each named kernel's calls and time, the runs of
+the traced window (first operation's start to the last one's end; an
+instant under a `while` or `conditional` counts once, for the innermost
+operation: `own_times`), the share that is remat recompute
+(`rematted_computation` in the `op_name`), what no scope covers, each
+named kernel's calls and time (`flash*`, `gmm*`, `moe_gather`), the runs of
 each program on the modules line, and the host's `train.*` / `bench.*`
 spans with the device gaps that fall under each.
 """
@@ -53,6 +55,30 @@ def scope_of(op_name: str) -> str:
         if re.search(rf"(?:^|[/(]){scope}(?:[/)]|$)", op_name):
             return scope
     return "unscoped"
+
+
+def own_times(events):
+    """(scope, is remat recompute, ns) of each (op_name, start, duration),
+    every instant counted once. A `while` or a `conditional` is an event
+    that spans the operations of its body (the dispatch's bounded loop,
+    the branch a row move takes): time goes to the innermost event, and
+    an operation whose own `op_name` names no scope counts under the
+    enclosing event's."""
+    open_events = []  # [end, scope, is_remat, ns not given to a child]
+
+    def closed(until):
+        while open_events and open_events[-1][0] <= until:
+            yield tuple(open_events.pop()[1:])
+
+    for op_name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        yield from closed(start)
+        scope, is_remat = scope_of(op_name), "rematted_computation" in op_name
+        if open_events:
+            open_events[-1][3] -= dur
+            if scope == "unscoped":
+                scope, is_remat = open_events[-1][1:3]
+        open_events.append([start + dur, scope, is_remat, dur])
+    yield from closed(float("inf"))
 
 
 # -- the protobuf wire format, as far as xplane.proto needs it ---------------
@@ -170,21 +196,20 @@ def summarize(path: str) -> dict:
                 continue
             by_scope, kernels, spans = {}, {}, []
             remat = named = 0
-            for name, stats, start, dur in line_events(plane, line):
-                if dur <= 0:
-                    continue
+            events = [e for e in line_events(plane, line) if e[3] > 0]
+            for name, stats, start, dur in events:
                 spans.append((start, start + dur))
-                op_name = stats.get(OP_NAME_STAT, "")
-                named += bool(op_name)
-                scope = scope_of(op_name)
-                by_scope[scope] = by_scope.get(scope, 0) + dur
-                if "rematted_computation" in op_name:
-                    remat += dur
-                m = re.match(r"^%((?:flash|gmm)\w*?)\.\d+ = ", name)
+                named += bool(stats.get(OP_NAME_STAT))
+                m = re.match(r"^%((?:flash|gmm|moe_gather)\w*?)\.\d+ = ", name)
                 if m:
                     k = kernels.setdefault(m[1], [0, 0])
                     k[0] += 1
                     k[1] += dur
+            for scope, is_remat, own in own_times(
+                    (stats.get(OP_NAME_STAT, ""), start, dur)
+                    for _, stats, start, dur in events):
+                by_scope[scope] = by_scope.get(scope, 0) + own
+                remat += own if is_remat else 0
             if not spans:
                 continue
             busy = tr.union(spans)

@@ -842,8 +842,9 @@ def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
     make_train_step(has_aux=True) returns as metrics beside the loss.
     {} for a dense model; for a model with expert layers on the
     single-device dropless route, summed over its layers:
-    moe_rows_routed, moe_rows_held, gmm_live_tiles, gmm_grid_tiles, and
-    moe_load_max_over_mean over the held experts (docs/observability.md)."""
+    moe_rows_routed, moe_rows_held, gmm_live_tiles, gmm_grid_tiles,
+    moe_rows_moved, moe_rows_spanned, and moe_load_max_over_mean over
+    the held experts (docs/observability.md)."""
     rules = rules or ShardingRules()
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     chunked = config.ce_chunks > 1

@@ -54,6 +54,7 @@ Two things a layer reads off its own arrays (docs/moe_performance.md):
 from __future__ import annotations
 
 import functools
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -276,25 +277,32 @@ def _dispatch_stats(eid: jax.Array, e: int) -> Dict:
     layers add up and a step can return them as metrics): rows routed
     over all of the router's outputs, rows computed by the experts held
     here, the fullest held expert's rows (with `moe_rows_held` and the
-    held count it gives the load's max over mean), and the grouped
-    matmuls' live row tiles beside the static grid's."""
+    held count it gives the load's max over mean), the grouped matmuls'
+    live row tiles beside the static grid's, and the rows the forward's
+    two row moves copy (the live tiles' rows into the padded layout, the
+    held entries' rows back out) beside the rows their outputs span."""
     m = eid.shape[0]
     tile = _row_tile(m, e)
     counts = jnp.zeros((e,), jnp.int32).at[eid].add(1, mode="drop")
+    live_tiles = jnp.sum((counts + tile - 1) // tile)
+    grid_tiles = ((m + tile - 1) // tile * tile + e * tile) // tile
     f32 = lambda v: jnp.asarray(v, jnp.float32)
     return {
         "moe_rows_routed": f32(m),
         "moe_rows_held": f32(jnp.sum(counts)),
         "moe_rows_fullest": f32(jnp.max(counts)),
-        "gmm_live_tiles": f32(jnp.sum((counts + tile - 1) // tile)),
-        "gmm_grid_tiles": f32(((m + tile - 1) // tile * tile + e * tile) // tile),
+        "gmm_live_tiles": f32(live_tiles),
+        "gmm_grid_tiles": f32(grid_tiles),
+        "moe_rows_moved": f32(live_tiles * tile + jnp.sum(counts)),
+        "moe_rows_spanned": f32(grid_tiles * tile + m),
     }
 
 
 # ---------------------------------------------------------------------------
 # dropless dispatch stages. _gmm_ffn composes plan -> permute -> ffn ->
-# gather; they are split so bench.py can time each stage (the
-# gating/permute/gmm/combine attribution in .bench_extras.json).
+# gather, each under a named scope of its own (moe_route, moe_permute,
+# moe_experts, moe_combine), which is how a device trace is read by stage
+# (hack/scope_shares.py).
 # ---------------------------------------------------------------------------
 
 
@@ -356,33 +364,106 @@ def _dispatch_plan(eid: jax.Array, e: int):
 
 
 def _take(x: jax.Array, idx: jax.Array) -> jax.Array:
-    """x[idx] by rows; an index of len(x) (or beyond) reads a zero row."""
+    """x[idx] by rows; an index of len(x) (or beyond) reads a zero row.
+    XLA's gather: what `_move_rows` is defined by and the tests compare
+    it with."""
     return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
 
 
+def _take_sum(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """[n, d], [c, r] -> [r, d]: `_take(x, idx[j])` over j, added in the
+    order of j in float32 and rounded to x's dtype once: what XLA's fused
+    sum of c bfloat16 gathers gives on the chip, said outright."""
+    y = _take(x, idx[0])
+    if idx.shape[0] == 1:
+        return y
+    y = y.astype(jnp.float32)
+    for j in range(1, idx.shape[0]):
+        y = y + _take(x, idx[j]).astype(jnp.float32)
+    return y.astype(x.dtype)
+
+
+def _live_rows(tile_expert: jax.Array, e: int, m_pad: int) -> jax.Array:
+    """Leading rows of the padded layout that live tiles cover, read off
+    `tile_expert` as the `gmm*` grids read it (ops/gmm.py `_live_tiles`:
+    at least one tile)."""
+    from kubedl_tpu.ops.gmm import _live_tiles
+
+    return _live_tiles(tile_expert, e) * (m_pad // tile_expert.shape[0])
+
+
+# The kernel costs a live row 1.6 times XLA's gather and a dead one
+# nothing (PERF.md section 6, PR 29): a move without a bound takes it
+# where at most this share of its indices name a row
+SPARSE_SHARE = 0.5
+# rows a step of the bounded loop gathers (a power of two), or the most
+# of them that divide the layout
+LOOP_ROWS = 1024
+
+
+@jax.jit  # a step traces and lowers each of its four moves once
+def _move_rows(x: jax.Array, idx: jax.Array,
+               live_rows: Optional[jax.Array] = None) -> jax.Array:
+    """[n, d], [c, r] -> [r, d]: `y[i] = sum_j x[idx[j, i]]`, an index of
+    len(x) reading a zero row: `_take_sum(x, idx)`.
+
+    Only the rows that hold something move, where the caller or the
+    indices say which. With `live_rows` (a traced scalar; c = 1) they are
+    the leading `live_rows` (the padded layout's live tiles): a loop
+    gathers those, `LOOP_ROWS` a step, and the rows past them are left
+    zero, which no reader may count on. Without it they lie anywhere
+    among the sentinels, and each call chooses by their count: the
+    row-gather kernel (ops/row_gather.py), which copies no row for a
+    sentinel, where at most `SPARSE_SHARE` of the indices name a row
+    (a layer that holds a quarter of its router's experts), else one XLA
+    gather over all r rows (a layer that holds them all: what every move
+    was until PR 29)."""
+    from kubedl_tpu.ops.row_gather import gather_rows
+
+    c, r = idx.shape
+    if live_rows is not None:
+        step = math.gcd(r, LOOP_ROWS)
+
+        def one(t, y):
+            rows = _take(x, jax.lax.dynamic_slice(idx[0], (t * step,), (step,)))
+            return jax.lax.dynamic_update_slice(y, rows, (t * step, 0))
+        return jax.lax.fori_loop(
+            0, (live_rows + step - 1) // step, one,
+            jnp.zeros((r, x.shape[1]), x.dtype))
+    named = jnp.sum(idx < x.shape[0], dtype=jnp.int32)
+    return jax.lax.cond(
+        named <= int(SPARSE_SHARE * c * r), gather_rows, _take_sum, x, idx)
+
+
 @jax.custom_vjp
-def _take_rows(x: jax.Array, idx: jax.Array, back: jax.Array) -> jax.Array:
-    """y[i] = x[idx[i]], a zero row where idx[i] == len(x).
+def _take_rows(x: jax.Array, idx: jax.Array, back: jax.Array,
+               live_out: Optional[jax.Array] = None,
+               live_in: Optional[jax.Array] = None) -> jax.Array:
+    """y[i] = x[idx[i]], a zero row where idx[i] == len(x): `_take(x,
+    idx)`, moved by `_move_rows`.
 
     `back` [c, len(x)] names, for each row of x, the rows of y that took
-    it (len(y) = none). The transpose is then c gathers and a sum, where
-    autodiff would scatter-add [rows, d] into x: a TPU scatters rows
-    several times slower than it gathers them, and the dispatch moves
-    k*S rows of d_model four times a layer and step."""
-    return _take(x, idx)
+    it (len(y) = none). The transpose is then one move that adds its c
+    rows in the order of c, where autodiff would scatter-add [rows, d]
+    into x: a TPU scatters rows several times slower than it gathers
+    them, and the dispatch moves k*S rows of d_model four times a layer
+    and step.
+
+    The bounds (traced scalars, or None for all rows) say how many
+    leading rows of a padded layout are live: `live_out` of y, `live_in`
+    of x and so of x's cotangent. No index may name a row past them."""
+    return _move_rows(x, idx[None], live_out)
 
 
-def _take_rows_fwd(x, idx, back):
-    return _take(x, idx), (idx, back)
+def _take_rows_fwd(x, idx, back, live_out, live_in):
+    return _move_rows(x, idx[None], live_out), (idx, back, live_out, live_in)
 
 
 def _take_rows_bwd(res, dy):
-    idx, back = res
-    dx = _take(dy, back[0])
-    for c in range(1, back.shape[0]):
-        dx = dx + _take(dy, back[c])
-    zero = lambda a: np.zeros(a.shape, jax.dtypes.float0)
-    return dx, zero(idx), zero(back)
+    idx, back, live_out, live_in = res
+    dx = _move_rows(dy, back, live_in)
+    zero = lambda a: None if a is None else np.zeros(a.shape, jax.dtypes.float0)
+    return dx, zero(idx), zero(back), zero(live_out), zero(live_in)
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
@@ -394,18 +475,21 @@ def _permute(
     dest: jax.Array,
     pos_of_entry: jax.Array,
     m_pad: int,
+    live_rows: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Gather the routed rows into the padded expert-sorted layout: which
     entry each padded row holds (a scatter of M integers; sentinel
     entries target the out-of-range row m_pad and are dropped), then one
     gather of rows (entry f is row `f % n_src` of `src`). Padding rows
-    read the zero row. Returns (rows [m_pad, d], entry_of_row [m_pad],
-    M where a row holds none)."""
+    read zero; no reader may count on that past `live_rows` (dead
+    tiles). Returns (rows [m_pad, d], entry_of_row [m_pad], M where a
+    row holds none)."""
     n_src, m = src.shape[0], order.shape[0]
     entry_of_row = jnp.full((m_pad,), m, jnp.int32).at[dest].set(
         order, mode="drop")
     row_src = jnp.where(entry_of_row < m, entry_of_row % n_src, n_src)
-    x = _take_rows(src, row_src, pos_of_entry.reshape(m // n_src, n_src))
+    x = _take_rows(src, row_src, pos_of_entry.reshape(m // n_src, n_src),
+                   live_rows, None)
     return x, entry_of_row
 
 
@@ -478,20 +562,29 @@ def _gmm_ffn(
 
     Rows move by gathers in both directions and in both passes
     (`_take_rows`): into the padded layout by the entry each padded row
-    holds, back out by the padded row of each entry."""
+    holds, back out by the padded row of each entry. A layer that holds
+    part of its router's experts moves only the rows that hold something
+    (`_move_rows`): the padded layout is filled, and its cotangent with
+    it, only as far as the last live row tile, where the `gmm*` grids
+    stop too, and a sentinel entry costs no copy on the way out. Rows of
+    dead tiles are then whatever that move left there (zeros today); the
+    grouped matmuls leave theirs unwritten; nothing reads either."""
     n_src, m = src.shape[0], eid.shape[0]
     if m % n_src:
         raise ValueError(f"{m} entries do not tile {n_src} source rows")
     with jax.named_scope("moe_route"):
         order, dest, pos_of_entry, tile_expert, m_pad = _dispatch_plan(eid, e)
+        live_rows = _live_rows(tile_expert, e, m_pad)
     with jax.named_scope("moe_permute"):
-        x, entry_of_row = _permute(src, order, dest, pos_of_entry, m_pad)
+        x, entry_of_row = _permute(
+            src, order, dest, pos_of_entry, m_pad, live_rows)
     with jax.named_scope("moe_experts"):
         rows = _ffn_rows(x, tile_expert, params, fused=fused)
     # entry f's output sits at padded row pos_of_entry[f]; a sentinel's
     # m_pad reads the zero row
     with jax.named_scope("moe_combine"):
-        return _take_rows(rows, pos_of_entry, entry_of_row[None])
+        return _take_rows(rows, pos_of_entry, entry_of_row[None],
+                          None, live_rows)
 
 
 @jax.named_scope("moe_combine")

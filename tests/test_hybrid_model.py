@@ -274,6 +274,36 @@ def test_rows_move_by_gathers_and_the_transpose_is_exact():
     assert not np.any(np.asarray(moe._take_rows(x, idx, back))[[1, 5]])
 
 
+def take_for_every_move(x, idx, live_rows=None):
+    """`moe._move_rows` by XLA's gather alone, over all rows: the oracle."""
+    return moe._take_sum(x, idx)
+
+
+@pytest.mark.parametrize("held_of", [4, 1], ids=["quarter_held", "every_expert_held"])
+def test_the_dispatch_moves_the_rows_xlas_gather_moves(held_of, monkeypatch):
+    """_gmm_ffn's output and its gradients to src and to the three
+    weights, element for element, against the same function with `_take`
+    over all rows in the place of every move: one entry in four held (the
+    bounded loop and the kernel) and every entry held (the loop and one
+    XLA gather)."""
+    src, _, params, e = sentinel_dispatch(8)
+    eid = jax.random.randint(jax.random.PRNGKey(8), (4 * src.shape[0],), 0, held_of * e)
+    eid = jnp.where(eid < e, eid, e)
+    w = jax.random.normal(jax.random.PRNGKey(9), (eid.shape[0], src.shape[1]))
+
+    def run():
+        # a function of its own each time: a jit would keep the first trace
+        loss = lambda src, params: jnp.sum(moe._gmm_ffn(src, eid, params, e) * w)
+        return moe._gmm_ffn(src, eid, params, e), jax.grad(loss, argnums=(0, 1))(src, params)
+
+    got = run()
+    monkeypatch.setattr(moe, "_move_rows", take_for_every_move)
+    want = run()
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert np.any(np.asarray(got[0])) and np.any(np.asarray(got[1][0]))
+
+
 def test_no_matrix_product_for_a_dead_tile():
     """Rows of dead tiles hold NaN: a product over them would poison the
     weight gradient (a sum over rows) and, were it written, the output."""
@@ -320,6 +350,12 @@ def test_counters_follow_the_rows_routed_here():
     assert float(stats["gmm_live_tiles"]) == sum(-(-c // tile) for c in counts)
     assert float(stats["gmm_grid_tiles"]) == -(-4 * 96 // tile) + 2
     assert stats["gmm_live_tiles"] <= stats["gmm_grid_tiles"]
+    # the forward's two row moves: the live tiles' rows in, the held
+    # entries' rows out, of the rows the padded layout and the entries span
+    assert float(stats["moe_rows_moved"]) == (
+        sum(-(-c // tile) for c in counts) * tile + sum(counts))
+    assert float(stats["moe_rows_spanned"]) == (
+        (-(-4 * 96 // tile) + 2) * tile + 4 * 96)
 
 
 # -- the short convolution -----------------------------------------------------

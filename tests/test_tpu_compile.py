@@ -23,6 +23,7 @@ from kubedl_tpu.models import llama
 from kubedl_tpu.models.moe import _row_tile
 from kubedl_tpu.ops.flash_attention import flash_attention
 from kubedl_tpu.ops.gmm import gmm, gmm_scaled, gmm_swiglu
+from kubedl_tpu.ops.row_gather import gather_rows
 from kubedl_tpu.parallel.mesh import ShardingRules, build_mesh
 from kubedl_tpu.parallel.train_step import make_train_step
 
@@ -181,6 +182,22 @@ def test_gmm_scaled_int8_fwd_bwd_compiles(one_chip, on_tpu, rows, d, ffn, e):
                     a["x"], a["q"], a["te"], a["s"])
     assert _kernels(text) >= 2  # scaled fwd + dlhs gmm
     assert "%gmm_scaled." in text
+
+
+# the LFM2 cell's two moves without a bound (combine's forward, permute's
+# backward with its sum over k = 4), and float32 rows
+@pytest.mark.parametrize("n,c,r,dtype", [
+    (69632, 1, 65536, jnp.bfloat16), (69632, 4, 16384, jnp.bfloat16),
+    (69632, 4, 16384, jnp.float32)], ids=["combine_fwd", "permute_bwd", "f32"])
+def test_row_gather_compiles(one_chip, on_tpu, n, c, r, dtype):
+    """Mosaic takes what interpret mode cannot see: copies of whole
+    8-row groups, a row picked by a dynamic sublane index, 16-bit rows
+    read as words."""
+    text = _compile(
+        gather_rows,
+        jax.ShapeDtypeStruct((n, 2048), dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((c, r), jnp.int32, sharding=one_chip))
+    assert _kernels(text) == 1 and "%moe_gather." in text
 
 
 # loss_fn trains on tokens[:, :-1]: 1,025 gives the model 1,024, the
