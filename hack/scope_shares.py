@@ -5,7 +5,8 @@
 
 The model's and the step's `jax.named_scope`s (embed, attn > attn_core,
 short_conv, mlp > moe_route / moe_permute / moe_experts / moe_combine,
-head_loss, optimizer, grad_norm: PERF.md section 3) reach each
+head_loss, exit_gate, optimizer, grad_norm, and loop_pass around a looped
+stack's pass: PERF.md section 3) reach each
 device operation's `op_name`, not its name. On a TPU the profiler keeps
 the `op_name` as the stat `tf_op` of the operation's *event metadata*,
 which `jax.profiler.ProfileData` (jax 0.9) does not hand out: its
@@ -41,8 +42,11 @@ from benchmarks import trace as tr  # interval arithmetic only; no JAX
 
 # innermost first: an operation under attn/attn_core counts as attn_core
 SCOPES = ("attn_core", "attn", "short_conv", "moe_route", "moe_permute",
-          "moe_experts", "moe_combine", "mlp", "head_loss", "embed",
-          "optimizer", "grad_norm")
+          "moe_experts", "moe_combine", "mlp", "head_loss", "exit_gate",
+          "embed", "optimizer", "grad_norm",
+          # a looped stack's pass: what no scope inside it covers (its
+          # final norm, the loop's own copies)
+          "loop_pass")
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 HOST_SPAN = re.compile(r"^(train|bench|ckpt|reshard)\.|^train$")
 OP_NAME_STAT = "tf_op"

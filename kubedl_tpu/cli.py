@@ -144,6 +144,22 @@ def _grow_widths(widths, row) -> None:
             widths[i] = max(widths[i], len(str(cell)) + 2)
 
 
+def _span_detail(attrs) -> str:
+    """The DETAIL column of `trace`: the attributes that say what a span
+    was, and of a looped stack's step its passes and exit distribution
+    (the `loop_*` counters, docs/observability.md)."""
+    detail = [f"{k}={attrs[k]}" for k in
+              ("step", "stage", "cause", "outcome", "shape", "reason", "error")
+              if k in attrs]
+    if "loop_passes" in attrs:
+        passes = int(attrs["loop_passes"])
+        detail.append(f"passes={passes}")
+        detail.append("exit=" + "/".join(
+            f"{attrs.get(f'loop_exit_mass_{t}', 0.0):.2f}"
+            for t in range(1, passes + 1)))
+    return " ".join(detail)
+
+
 def _print_table(rows):
     """Print aligned rows; returns the column widths so continuation rows
     (watch mode) can keep the alignment."""
@@ -558,11 +574,7 @@ def cmd_trace(args) -> int:
           f"trace_id {' '.join(trace_ids) or '?'}")
     rows = [("T+S", "DUR_S", "SERVICE", "SPAN", "DETAIL")]
     for s in spans:
-        attrs = s.get("attrs") or {}
-        detail = " ".join(
-            f"{k}={attrs[k]}" for k in
-            ("step", "stage", "cause", "outcome", "shape", "reason", "error")
-            if k in attrs)
+        detail = _span_detail(s.get("attrs") or {})
         rows.append((
             f"{s.get('ts', 0.0) - t0:+.3f}",
             f"{s.get('dur', 0.0):.3f}",

@@ -33,6 +33,13 @@ def config_from_hf(hf_config, **overrides) -> LlamaConfig:
     import jax.numpy as jnp
 
     model_type = getattr(hf_config, "model_type", "llama")
+    passes = int(getattr(hf_config, "total_ut_steps", 1) or 1)
+    if passes > 1:
+        raise ValueError(
+            f"model_type {model_type!r} runs its stack total_ut_steps = "
+            f"{passes} times with four norms a layer and an exit gate: no "
+            "checkpoint mapping is written for it (LlamaConfig.ouro_2_6b "
+            "trains it from seeded weights)")
     if model_type not in ("llama", "mistral", "gemma", "gemma2", "qwen2"):
         raise ValueError(
             f"unsupported model_type {model_type!r} "

@@ -168,6 +168,16 @@ class LlamaConfig:
     # RMSNorm over each head's entries of q and of k before RoPE
     # (q_norm / k_norm leaves of size head_dim)
     qk_norm: bool = False
+    # A looped stack (Ouro's `total_ut_steps`): the n_layers layers are
+    # applied this many times over the same weights, the final norm after
+    # every pass (its output feeds the next pass, the head and an exit
+    # gate, a Linear(d_model, 1) with bias: the exit_gate leaves). 1 = a
+    # plain decoder, no gate. Training only (_looped_loss); the paths
+    # that carry state from token to token refuse it.
+    total_ut_steps: int = 1
+    # weight of the exit distribution's entropy in the looped objective
+    # (the paper's stage-I beta, a uniform prior over exit steps)
+    exit_entropy_beta: float = 0.05
 
     def __post_init__(self):
         if self.sliding_window is not None and self.sliding_window < 1:
@@ -196,6 +206,9 @@ class LlamaConfig:
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"unknown moe_router {self.moe_router!r} (softmax, sigmoid)")
+        if self.total_ut_steps < 1:
+            raise ValueError(
+                f"total_ut_steps must be >= 1, got {self.total_ut_steps}")
 
     def mixer_for(self, i: int) -> str:
         """Layer i's token mixer: "attention" or "conv"."""
@@ -205,9 +218,26 @@ class LlamaConfig:
         """Whether layer i's FFN is a routed expert layer."""
         return self.n_experts > 0 and i >= self.n_dense_layers
 
+    @property
+    def looped(self) -> bool:
+        """Whether the stack is applied more than once (total_ut_steps)."""
+        return self.total_ut_steps > 1
+
+    def require_single_pass(self, what: str) -> None:
+        """Refusal of the paths that visit each layer once a token."""
+        if self.looped:
+            raise NotImplementedError(
+                f"{what} visits each layer once a token: a stack run "
+                f"total_ut_steps = {self.total_ut_steps} times over "
+                f"{self.n_layers} layers needs {self.total_ut_steps} x "
+                f"{self.n_layers} key/value entries a token (or stages "
+                f"visited {self.total_ut_steps} times) and an exit by the "
+                f"gate; it trains (llama.loss_and_stats) and is not served")
+
     def require_kv_state_only(self, what: str) -> None:
         """Refusal of the paths that carry state from token to token and
-        know keys and values alone."""
+        know keys and values alone, one entry a layer."""
+        self.require_single_pass(what)
         if self.layer_types is not None and "conv" in self.layer_types:
             raise NotImplementedError(
                 f"{what} has no state for a short-convolution layer: the "
@@ -264,6 +294,7 @@ class LlamaConfig:
             "bench-1b": LlamaConfig.bench_1b,
             "llama-7b": LlamaConfig.llama_7b,
             "lfm2-8b-a1b": LlamaConfig.lfm2_8b_a1b,
+            "ouro-2.6b": LlamaConfig.ouro_2_6b,
         }
         if name not in factories:
             raise ValueError(
@@ -288,6 +319,23 @@ class LlamaConfig:
             layer_types=tuple(kinds), conv_kernel=3, qk_norm=True,
             n_experts=32, expert_top_k=4, n_dense_layers=2,
             d_ff_expert=1792, moe_router="sigmoid",
+        )
+        defaults.update(kw)
+        return LlamaConfig(**defaults)
+
+    @staticmethod
+    def ouro_2_6b(**kw) -> "LlamaConfig":
+        """Ouro-2.6B at its published sizes (ByteDance/Ouro-2.6B
+        config.json; arXiv:2510.25741): 48 layers of hidden 2,048 with four
+        norms each, 16 heads of 128 with as many key/value heads, SwiGLU
+        of 5,632, an untied head over 49,152, the whole stack run 4 times
+        over the same weights with a head and an exit gate after every
+        pass. 2.67B parameters."""
+        defaults = dict(
+            vocab_size=49152, d_model=2048, n_layers=48, n_heads=16,
+            n_kv_heads=16, d_ff=5632, max_seq_len=65536,
+            rope_theta=1000000.0, rms_eps=1e-6, post_block_norms=True,
+            total_ut_steps=4,
         )
         defaults.update(kw)
         return LlamaConfig(**defaults)
@@ -361,6 +409,8 @@ def param_specs(config: LlamaConfig, rules: Optional[ShardingRules] = None) -> D
     }
     if not config.tie_embeddings:
         specs["lm_head"] = r.spec("embed", "vocab")
+    if config.looped:
+        specs["exit_gate"] = {"w": r.spec("embed", None), "b": r.spec(None)}
     return specs
 
 
@@ -374,6 +424,8 @@ def init(config: LlamaConfig, key: jax.Array) -> Dict:
         return (jax.random.truncated_normal(key, -2, 2, shape, jnp.float32)
                 * (1.0 / np.sqrt(fan_in))).astype(dt)
 
+    # keys[-1] was never drawn from: the gate takes it, and a model with
+    # no gate keeps the weights it had
     keys = jax.random.split(key, config.n_layers + 3)
     layers = []
     for i in range(config.n_layers):
@@ -422,6 +474,9 @@ def init(config: LlamaConfig, key: jax.Array) -> Dict:
     }
     if not config.tie_embeddings:
         params["lm_head"] = dense(keys[-2], (d, config.vocab_size), d)
+    if config.looped:
+        params["exit_gate"] = {"w": dense(keys[-1], (d, 1), d),
+                               "b": jnp.zeros((1,), jnp.float32)}
     return params
 
 
@@ -686,8 +741,12 @@ def _backbone(
     mesh: Optional[Mesh],
     rules: ShardingRules,
 ) -> Tuple[jax.Array, jax.Array, Dict]:
-    """(pre-final-norm activations [batch, seq, d], summed MoE aux loss,
-    the expert layers' counters summed over layers: {} for a dense model)."""
+    """(what the head reads, summed MoE aux loss, the expert layers'
+    counters summed over layers: {} for a dense model). What the head
+    reads is the pre-final-norm activations [batch, seq, d] of a stack
+    run once, and of a looped one (total_ut_steps > 1) every pass's
+    state after the final norm, [passes, batch, seq, d]: the norm sits
+    inside the loop there, its output feeds the next pass."""
     context_size = 1
     if mesh is not None:
         context_size = mesh.shape.get("context", 1)
@@ -722,13 +781,46 @@ def _backbone(
                 layer_fn, policy=_remat_policy(config.remat_policy))
         return layer_fn
 
-    aux = jnp.zeros((), jnp.float32)
-    stats: Dict = {}
-    for i, layer in enumerate(params["layers"]):
-        (x, aux), counters = make_layer_fn(config.window_for(i))((x, aux), layer)
-        for k, v in counters.items():
-            stats[k] = stats[k] + v if k in stats else v
-    return x, aux, stats
+    def stack(x):
+        """Every layer once over x."""
+        aux = jnp.zeros((), jnp.float32)
+        stats: Dict = {}
+        for i, layer in enumerate(params["layers"]):
+            (x, aux), counters = make_layer_fn(config.window_for(i))((x, aux), layer)
+            for k, v in counters.items():
+                stats[k] = stats[k] + v if k in stats else v
+        return x, aux, stats
+
+    if not config.looped:
+        return stack(x)
+
+    # The passes are a loop in the program, the weights closed over: the
+    # step holds n_layers layer bodies whatever total_ut_steps is, the
+    # loop's own backward sums the passes' gradients of each weight, and
+    # what a pass saves for it (each layer's input, the flash kernel's out
+    # and lse) is stacked by pass.
+    def final_norm(u):
+        return rms_norm(u, params["final_norm"], config.rms_eps,
+                        config.norm_offset)
+
+    if config.remat:  # or the loop keeps each pass's float32 copy of u
+        final_norm = jax.checkpoint(final_norm)
+
+    def one_pass(h, _):
+        with jax.named_scope("loop_pass"):
+            u, aux, stats = stack(h)
+            h = final_norm(u)
+        return h, (h, aux, stats)
+
+    _, (states, aux, stats) = _scan_passes(
+        one_pass, x, None, length=config.total_ut_steps)
+    return states, jnp.sum(aux), jax.tree_util.tree_map(
+        lambda v: jnp.sum(v, axis=0), stats)
+
+
+# the loop over the passes of a looped stack (hack/probe_loop_shape.py
+# measures it against its unrolled form)
+_scan_passes = jax.lax.scan
 
 
 def forward_and_aux(
@@ -741,7 +833,13 @@ def forward_and_aux(
     """(logits [batch, seq, vocab] f32, summed MoE aux loss — 0 when dense)."""
     rules = rules or ShardingRules()
     x, aux, _ = _backbone(params, tokens, config, mesh, rules)
-    logits = _lm_head(x, params, config)
+    if config.looped:
+        # every pass runs, as early_exit_threshold 1 has inference do: the
+        # last pass's state, already normed
+        with jax.named_scope("head_loss"):
+            logits = _head_logits(x[-1], params, config)
+    else:
+        logits = _lm_head(x, params, config)
     return _constrainer(mesh, rules)(logits, "batch", "seq", "vocab"), aux
 
 
@@ -759,14 +857,26 @@ def _head_matrix(params, config: LlamaConfig):
     return head
 
 
+def _head_logits(x, params, config: LlamaConfig, rounded: bool = True) -> jax.Array:
+    """(Tied or separate) LM head over a normed state -> f32 logits:
+    the product rounded to the activations' dtype first, as the plain
+    decoder's head always was, or (rounded=False) as the matmul
+    accumulated it."""
+    if rounded:
+        logits = _mm(x, _head_matrix(params, config)).astype(jnp.float32)
+    else:
+        logits = jnp.matmul(x, _head_matrix(params, config),
+                            preferred_element_type=jnp.float32)
+    if config.final_logit_softcap:
+        logits = softcap(logits, config.final_logit_softcap)
+    return logits
+
+
 @jax.named_scope("head_loss")
 def _lm_head(x, params, config: LlamaConfig) -> jax.Array:
     """Final norm + (tied or separate) LM head -> f32 logits."""
     x = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
-    logits = _mm(x, _head_matrix(params, config)).astype(jnp.float32)
-    if config.final_logit_softcap:
-        logits = softcap(logits, config.final_logit_softcap)
-    return logits
+    return _head_logits(x, params, config)
 
 
 @jax.named_scope("head_loss")
@@ -828,6 +938,80 @@ def _next_token_ce_chunked(x, params, config: LlamaConfig, targets, n_chunks: in
     return jnp.mean(lse - tgt)
 
 
+# tokens whose float32 logits a looped stack's loss holds at a time: 4,096
+# x 49,152 are 0.8 GB, and the loss's backward holds three such arrays
+HEAD_TOKENS = 4096
+
+
+def _looped_loss(states, params, config: LlamaConfig, targets, mesh, rules):
+    """A looped stack's objective from every pass's normed state
+    [passes, b, t, d], and its counters.
+
+    Pass t's head gives each token's cross entropy CE_t, its gate
+    lambda_t = sigmoid(h_t w_g + b_g). A token leaves after pass t with
+    probability p_t = lambda_t prod_{j<t}(1 - lambda_j), and after the
+    last with what is left, prod_{j<T}(1 - lambda_j) (the last pass's
+    gate decides nothing). The loss is the mean over tokens of
+    sum_t p_t CE_t - beta H(p): the expected loss over the exit step
+    with a uniform prior over it (arXiv:2510.25741, stage I).
+
+    The passes' heads are a loop too, over pieces of HEAD_TOKENS tokens,
+    each recomputed in the backward pass, so that one piece's
+    [tokens, vocab] float32 logits live at a time and none from forward
+    to backward."""
+    n, (b, t) = config.total_ut_steps, targets.shape
+    constrain = _constrainer(mesh, rules)
+    # a call of the head sees HEAD_TOKENS tokens: the sequence in c pieces
+    c = max(1, b * t // HEAD_TOKENS)
+    c = c if t % c == 0 else 1
+
+    tc, d = t // c, states.shape[-1]
+    pieces = jnp.moveaxis(states.reshape(n, b, c, tc, d), 2, 1).reshape(
+        n * c, b, tc, d)
+    wanted = jnp.tile(jnp.moveaxis(targets.reshape(b, c, tc), 1, 0), (n, 1, 1))
+
+    def whole(a):  # [n * c, b, t / c] -> [n, b, t]
+        return jnp.moveaxis(a.reshape(n, c, b, tc), 1, 2).reshape(n, b, t)
+
+    @jax.checkpoint
+    def head_and_gate(piece):
+        h, want = piece
+        with jax.named_scope("head_loss"):
+            # float32 out of the matmul: the gate learns from the
+            # differences of the passes' losses, which on a token are no
+            # larger than a bf16 logit's rounding
+            logits = constrain(_head_logits(h, params, config, rounded=False),
+                               "batch", "seq", "vocab")
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            hit = jnp.take_along_axis(logits, want[..., None], axis=-1)[..., 0]
+        with jax.named_scope("exit_gate"):
+            gate = params["exit_gate"]
+            z = jnp.einsum("btd,do->bto", h, gate["w"],
+                           preferred_element_type=jnp.float32)[..., 0]
+        return lse - hit, z + gate["b"][0]
+
+    ce, z = jax.lax.map(head_and_gate, (pieces, wanted))
+    ce, z = whole(ce), whole(z)  # [passes, b, t] each
+    with jax.named_scope("exit_gate"):
+        log_stay = jax.nn.log_sigmoid(-z)  # log(1 - lambda_t)
+        stayed = jnp.cumsum(log_stay, axis=0) - log_stay  # sum over j < t
+        log_p = jnp.concatenate(
+            [stayed[:-1] + jax.nn.log_sigmoid(z[:-1]), stayed[-1:]], axis=0)
+        p = jnp.exp(log_p)
+        entropy = -jnp.sum(p * log_p, axis=0)
+        loss = jnp.mean(jnp.sum(p * ce, axis=0)
+                        - config.exit_entropy_beta * entropy)
+        mass, ce_mean = jnp.mean(p, axis=(1, 2)), jnp.mean(ce, axis=(1, 2))
+        stats = {"loop_passes": jnp.asarray(n, jnp.float32),
+                 "loop_layer_applications": jnp.asarray(
+                     n * len(params["layers"]), jnp.float32),
+                 "loop_exit_entropy": jnp.mean(entropy)}
+        for i in range(n):
+            stats[f"loop_exit_mass_{i + 1}"] = mass[i]
+            stats[f"loop_ce_{i + 1}"] = ce_mean[i]
+    return loss, stats
+
+
 def loss_fn(params, tokens, config: LlamaConfig, mesh=None, rules=None):
     """Next-token cross entropy (+ MoE aux); tokens [b, t], loss over [:, 1:].
 
@@ -838,13 +1022,15 @@ def loss_fn(params, tokens, config: LlamaConfig, mesh=None, rules=None):
 
 
 def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
-    """(loss_fn's loss, the step's expert-layer counters): what
+    """(loss_fn's loss, the step's counters): what
     make_train_step(has_aux=True) returns as metrics beside the loss.
-    {} for a dense model; for a model with expert layers on the
+    {} for a dense model run once; for a model with expert layers on the
     single-device dropless route, summed over its layers:
     moe_rows_routed, moe_rows_held, gmm_live_tiles, gmm_grid_tiles,
     moe_rows_moved, moe_rows_spanned, and moe_load_max_over_mean over
-    the held experts (docs/observability.md)."""
+    the held experts; for a looped stack (_looped_loss): loop_passes,
+    loop_layer_applications, loop_exit_mass_<t>, loop_ce_<t>,
+    loop_exit_entropy (docs/observability.md)."""
     rules = rules or ShardingRules()
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     chunked = config.ce_chunks > 1
@@ -852,13 +1038,20 @@ def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
         _warn_ce_chunks_ignored(mesh.shape.get("tensor", 1))
         chunked = False
     x, aux, stats = _backbone(params, inputs, config, mesh, rules)
-    if chunked:
+    if config.looped:
+        if chunked:
+            raise NotImplementedError(
+                "ce_chunks is not wired into a looped stack's loss (each "
+                "pass's head is recomputed in the backward pass as it is)")
+        ce, loop_stats = _looped_loss(x, params, config, targets, mesh, rules)
+        stats = {**stats, **loop_stats}
+    elif chunked:
         ce = _next_token_ce_chunked(x, params, config, targets, config.ce_chunks)
     else:
         logits = _constrainer(mesh, rules)(
             _lm_head(x, params, config), "batch", "seq", "vocab")
         ce = _next_token_ce(logits, targets)
-    if stats:
+    if "moe_rows_fullest" in stats:
         stats = dict(stats)
         held = config.n_experts_held or config.n_experts
         stats["moe_load_max_over_mean"] = (
@@ -950,6 +1143,7 @@ def forward_pipelined_and_aux(
     body, aux accumulated per valid microbatch window);
     tensor/context/expert must be size 1 on a pipelined mesh (those
     shardings need manual collectives inside shard_map)."""
+    config.require_single_pass("the pipelined forward")
     if config.layer_windows is not None:
         # the pipeline scans ONE compiled layer program over stacked
         # params; a per-layer static mask can't vary inside the scan

@@ -92,6 +92,8 @@ def _flash_calls(text: str) -> dict:
     # head size
     ((2, 32, 8192, 128), 4096),
     ((2, 32, 8192, 64), None),
+    # the looped cell's: 16 heads of 128, full causal at 8,192
+    ((2, 16, 8192, 128), None),
 ])
 def test_flash_fwd_bwd_compiles(one_chip, on_tpu, shape, window):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
@@ -228,6 +230,32 @@ def test_moe_loss_default_fused_compiles(one_chip, on_tpu):
         params, tokens)
     assert "gmm_swiglu" in text
     assert _kernels(text) >= 10
+
+
+def test_looped_loss_holds_one_stacks_kernels_and_a_piece_of_logits(one_chip, on_tpu):
+    """A looped stack at the published widths (depth cut to two layers),
+    four passes over 4 x 2,048 tokens: the passes are a loop in the
+    program, so the gradient holds one stack's flash kernels and not
+    four; each pass's head runs in pieces of HEAD_TOKENS tokens inside a
+    loop of its own, recomputed in the backward pass, so no array of all
+    the step's tokens by the vocabulary exists."""
+    config = llama.LlamaConfig.ouro_2_6b(n_layers=2, max_seq_len=2048)
+    params = _abstract_params(
+        config, lambda t: jax.tree_util.tree_map(lambda _: one_chip, t))
+    tokens = jax.ShapeDtypeStruct((4, 2049), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(
+        lambda p, t: llama.loss_fn(p, t, config))).lower(params, tokens).compile()
+    text = compiled.as_text()
+    assert _flash_calls(text) == dict.fromkeys(FLASH_INSTRUCTIONS, config.n_layers)
+    assert text.count(" while(") >= 4  # the passes and the heads, forward and backward
+    pieces, vocab = 4 * 2048 // llama.HEAD_TOKENS, config.vocab_size
+    assert pieces == 2
+    assert f"f32[{4 * 2048},{vocab}]" not in text and f"f32[4,2048,{vocab}]" not in text
+    assert f"f32[4,{2048 // pieces},{vocab}]" in text
+    # four passes' float32 logits would be 6.4 GB and one pass's 1.6 GB, of
+    # which a loss's backward holds three: a piece's three (2.4 GB) and
+    # the saved states stay under half of the first
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
 
 
 def test_sharded_train_step_with_flash_compiles(topo, on_tpu):
