@@ -160,11 +160,15 @@ class KubeClient:
         it from another thread and unblock the chunked read. `abort` is
         re-checked AFTER the connection is registered: a stopper either
         ran before registration (abort() is True -> return) or after (the
-        registered conn gets shut down) — no unstoppable window."""
+        registered conn gets shut down) — no unstoppable window. The
+        socket is opened BEFORE registration for the same reason: a
+        stopper shuts down `conn.sock`, and http.client opens it only at
+        the first request."""
         params = dict(params or {})
         params["watch"] = "true"
         qs = urllib.parse.urlencode(params)
         conn = self._new_conn(None)
+        conn.connect()
         if conn_holder is not None:
             conn_holder.append(conn)
         if abort is not None and abort():

@@ -13,9 +13,15 @@ installs a custom VJP wiring the two kernels together.
 
 Each kernel reads a block's fate off its position (`_segments`): a block
 with no pair inside the causal diagonal, the window and the true length
-is never visited. In `flash_bwd_dq`, the one kernel the vector unit binds
-on a v5e, a block whose every pair is inside them runs a body with no
-mask; `block_plan` counts both kinds for a shape.
+is never visited, and `block_plan` counts for a shape the blocks that
+are and those whose every pair is live. The mask has one definition,
+`_block_mask`: the block's column index less one scalar of its origin,
+compared with its row index, so that a program holds no
+`[block_q, block_k]` integer (three of them, kept in VMEM and read back
+every block, cost the forward a tenth of its time under a window:
+PERF.md section 6, PR 31). The forward and `flash_bwd_dkv` mask every
+block they visit; `flash_bwd_dq` runs its interior blocks through a body
+with no mask, the one kernel that gained by it (PR 27).
 
 Each `pallas_call` carries a fixed `name=`, which the device trace shows as
 the event's name: `flash_fwd`, `flash_fwd_streamed`, `flash_bwd_dq`,
@@ -36,15 +42,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from kubedl_tpu.ops import interpret
 
-# Swept on v5e (bf16 MXU inputs, causal fwd): at seq 2048, 512/512 hits
-# 53 TF/s vs 47 for 1024/1024 and ~3.5x over 128/128; bigger K/V tiles
-# amortize the online-softmax bookkeeping, but past 512 the f32 score
-# blocks start crowding the 16 MB scoped VMEM (2048-wide blocks OOM it).
+# What every ledger line of the 8k cells ran. Under Q blocks of 512, K
+# blocks of 256 and 128 cost 1.6 and 2.7 times as much a pair on a v5e
+# (PERF.md Open question 14 (f)); a grid step costs 0.9-1.2 us before its
+# first block and a block 1.1-1.8 us (`hack/probe_flash_blocks.py`).
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
-# Measured crossover on v5e (bf16): the fused kernel loses to plain XLA at
-# short sequences (0.26-0.46x at 256-512, where the [T,T] scores are tiny
-# and per-program overheads dominate) and wins from ~1024 up (2.6-2.8x).
+# Under this length the public entry takes plain XLA attention. No ledger
+# line holds the crossover: the benchmark's 512 cell runs under it, its
+# 8k cells far over it (ROADMAP Speed 10).
 FLASH_MIN_SEQ = 1024
 # Above this sequence length the default kernel's full-K/V-in-VMEM
 # BlockSpecs crowd the 16 MB scoped VMEM; the forward streams K/V blocks
@@ -127,14 +133,38 @@ def block_plan(seq_len, window, block_q, block_k, causal, side):
     return int(np.sum(stop - start)), int(np.sum(hi - lo))
 
 
-def _pair_mask(q_pos, k_pos, seq_len, causal, window):
-    """Which (query, key) pairs of a block are live."""
-    mask = (k_pos < seq_len) & (q_pos < seq_len)
+def _block_mask(q0, k0, *, block_q, block_k, seq_len, causal, window):
+    """Which pairs of the block at origin `(q0, k0)` are live: a mask that
+    broadcasts to `[block_q, block_k]`, or None where no pair of any
+    visited block can be dead (full attention over whole blocks).
+
+    Row `i` and column `j` of the block are live iff `k0 + j <= q0 + i`
+    (causal) and `k0 + j > q0 + i - window`. The block's origin enters as
+    one scalar taken off the `[1, block_k]` column index, which is then
+    compared with the `[block_q, 1]` row index: a compare an edge and no
+    `[block_q, block_k]` integer, where position tensors of that size sat
+    in VMEM and were read back every block. The length is compared only
+    where `seq_len` is no multiple of the block: elsewhere `_segments`
+    visits no block that reaches into the padded tail (the streamed
+    forward may run a Q block that lies wholly in it; the public entry
+    cuts those rows off)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    rel = col - (q0 - k0)
+    edges = []
     if causal:
-        mask = mask & (k_pos <= q_pos)
+        edges.append(rel <= row)
     if window is not None:
-        mask = mask & (k_pos > q_pos - window)
-    return mask
+        edges.append(rel + window > row)
+    if seq_len % block_k:
+        edges.append(col < seq_len - k0)
+    if seq_len % block_q:
+        edges.append(row < seq_len - q0)
+    return functools.reduce(jnp.logical_and, edges) if edges else None
+
+
+def _where_live(mask, x, fill):
+    return x if mask is None else jnp.where(mask, x, fill)
 
 
 # ---------------------------------------------------------------------------
@@ -159,7 +189,7 @@ def _online_softmax_step(q, k, v, m, l, acc, sm_scale, mask, softcap=None):
     ) * sm_scale
     if softcap is not None:
         s = _softcap_scores(s, softcap)
-    s = jnp.where(mask, s, NEG_INF)
+    s = _where_live(mask, s, NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m - m_new)
@@ -184,14 +214,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, head_dim), jnp.float32)
 
-    q_pos = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-
     def body(kb, carry):
         m, l, acc = carry
         k = k_ref[0, pl.ds(kb * block_k, block_k), :]
         v = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = _pair_mask(q_pos, k_pos, seq_len, causal, window)
+        mask = _block_mask(
+            qb * block_q, kb * block_k, block_q=block_q, block_k=block_k,
+            seq_len=seq_len, causal=causal, window=window)
         return _online_softmax_step(q, k, v, m, l, acc, sm_scale, mask,
                                     softcap)
 
@@ -278,17 +307,9 @@ def _fwd_streamed_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s,
         q = q_ref[0]  # [block_q, d] bf16
         k = k_ref[0]  # [block_k, d]
         v = v_ref[0]
-        q_pos = qb * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = (k_pos < seq_len) & (q_pos < seq_len)
-        if causal:
-            mask = mask & (k_pos <= q_pos)
-        if window is not None:
-            mask = mask & (k_pos > q_pos - window)
+        mask = _block_mask(
+            qb * block_q, kb * block_k, block_q=block_q, block_k=block_k,
+            seq_len=seq_len, causal=causal, window=window)
         m_new, l, acc = _online_softmax_step(
             q, k, v, m_s[...], l_s[...], acc_s[...], sm_scale, mask, softcap
         )
@@ -355,7 +376,6 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     do = do_ref[0]
     lse = lse_ref[0, 0][:, None]
     delta = delta_ref[0, 0][:, None]
-    q_pos = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
 
     def body(masked):
         def step(kb, dq):
@@ -367,10 +387,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 s = _softcap_scores(s, softcap)
             p = jnp.exp(s - lse)
             if masked:
-                k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                p = jnp.where(
-                    _pair_mask(q_pos, k_pos, seq_len, causal, window), p, 0.0)
+                p = _where_live(_block_mask(
+                    qb * block_q, kb * block_k, block_q=block_q,
+                    block_k=block_k, seq_len=seq_len, causal=causal,
+                    window=window), p, 0.0)
             dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
             ds = p * (dp - delta)
@@ -401,7 +421,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
     kb = pl.program_id(1)
     k = k_ref[0]  # bf16 into the MXU; f32 accumulation
     v = v_ref[0]
-    k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
 
     def body(qb, carry):
         dk, dv = carry
@@ -413,9 +432,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
                                 preferred_element_type=jnp.float32) * sm_scale
         if softcap is not None:
             s = _softcap_scores(s, softcap)
-        q_pos = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        mask = _pair_mask(q_pos, k_pos, seq_len, causal, window)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+        mask = _block_mask(
+            qb * block_q, kb * block_k, block_q=block_q, block_k=block_k,
+            seq_len=seq_len, causal=causal, window=window)
+        p = _where_live(mask, jnp.exp(s - lse), 0.0)
         pb = p.astype(do.dtype)
         dv = dv + jax.lax.dot_general(pb, do, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)

@@ -495,3 +495,134 @@ def test_block_plan_is_counted_on_the_host(monkeypatch):
 def test_block_plan_refuses_an_unknown_side():
     with pytest.raises(ValueError, match="side"):
         fa.block_plan(64, None, 8, 8, True, "fwd")
+
+
+# ---------------------------------------------------------------------------
+# A block's mask: the column index less one scalar of the block's origin
+# against the row index (ops/flash_attention.py:_block_mask)
+# ---------------------------------------------------------------------------
+
+
+MASKED_SHAPES = {
+    **{"-".join(map(str, plan)): plan for plan in PLANS},
+    **{name: shape[:5] for name, shape in BLOCK_KINDS.items()},
+}
+
+
+@pytest.mark.parametrize("seq,window,block_q,block_k,causal",
+                         MASKED_SHAPES.values(), ids=MASKED_SHAPES.keys())
+def test_block_mask_against_brute_force(seq, window, block_q, block_k, causal):
+    """Every block a kernel visits, pair by pair: the helper's mask is
+    `k < n and q < n and k <= q and k > q - window`; where it gives no
+    mask at all, every pair of the block is live."""
+    live = _live_pairs(seq, window, block_q, block_k, causal)
+    visited = np.argwhere(live.any(axis=(2, 3)))
+    assert len(visited) == fa.block_plan(
+        seq, window, block_q, block_k, causal, "fwd_dq")[0]
+    for qb, kb in visited:
+        mask = fa._block_mask(
+            int(qb) * block_q, int(kb) * block_k, block_q=block_q,
+            block_k=block_k, seq_len=seq, causal=causal, window=window)
+        if mask is None:
+            assert live[qb, kb].all(), (qb, kb)
+            continue
+        np.testing.assert_array_equal(
+            np.broadcast_to(np.asarray(mask), (block_q, block_k)),
+            live[qb, kb], err_msg=f"block ({qb}, {kb})")
+
+
+def test_block_mask_compares_the_length_only_in_a_ragged_tail():
+    """A sequence of whole blocks costs a block no compare with the
+    length, and full attention over one no mask at all; a ragged one
+    pays a row's and a column's compare, not a block's. No operand of
+    any compare is a `[block_q, block_k]` integer."""
+    blocks = dict(block_q=8, block_k=8)
+
+    def compares(**kw):
+        jaxpr = jax.make_jaxpr(
+            lambda q0, k0: fa._block_mask(q0, k0, **blocks, **kw))(0, 0)
+        for eqn in jaxpr.eqns:
+            for var in eqn.invars:
+                assert (getattr(var.aval, "shape", ()) != (8, 8)
+                        or var.aval.dtype == bool), eqn
+        return sorted(
+            (eqn.primitive.name, eqn.outvars[0].aval.shape)
+            for eqn in jaxpr.eqns if eqn.primitive.name in ("lt", "le", "gt"))
+
+    assert fa._block_mask(0, 0, **blocks, seq_len=64, causal=False,
+                          window=None) is None
+    assert compares(seq_len=64, causal=True, window=None) == [("le", (8, 8))]
+    assert compares(seq_len=64, causal=True, window=16) == [
+        ("gt", (8, 8)), ("le", (8, 8))]
+    assert compares(seq_len=61, causal=True, window=None) == [
+        ("le", (8, 8)), ("lt", (1, 8)), ("lt", (8, 1))]
+
+
+def _pair_mask(q0, k0, *, block_q, block_k, seq_len, causal, window):
+    """The mask as the kernels made it until PR 31: two `[block_q,
+    block_k]` position tensors and five compares."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    mask = (k_pos < seq_len) & (q_pos < seq_len)
+    if causal:
+        mask = mask & (k_pos <= q_pos)
+    if window is not None:
+        mask = mask & (k_pos > q_pos - window)
+    return mask
+
+
+@pytest.mark.parametrize("streamed", [False, True], ids=["whole_kv", "streamed"])
+@pytest.mark.parametrize("shape", [
+    (512, 300, 128, 128, True, None),
+    (512, 50, 128, 128, True, None),
+    (512, 700, 128, 128, True, None),
+    (450, 192, 128, 256, True, None),
+    (450, None, 256, 128, True, None),
+    (450, None, 256, 128, False, None),
+    (512, None, 128, 128, False, None),
+    (512, 300, 128, 128, True, 20.0),
+], ids=["window", "window_under_a_block", "window_over_the_sequence",
+        "ragged_window_wide_k_blocks", "ragged_wide_q_blocks",
+        "not_causal_ragged", "not_causal", "softcap"])
+def test_the_block_mask_changes_no_bit(shape, streamed, monkeypatch):
+    """The same booleans, so the same bits: `out`, `lse` and the three
+    gradients against the kernels run with the parent's mask in the
+    helper's place, through the forward that holds K and V whole and
+    through the streamed one."""
+    seq, window, block_q, block_k, causal, softcap = shape
+    q, k, v = rand_qkv(b=1, hq=2, hkv=2, s=seq, d=64)
+    kw = dict(causal=causal, window=window, softcap=softcap,
+              block_q=block_q, block_k=block_k)
+    if streamed:
+        monkeypatch.setattr(fa, "STREAM_MIN_SEQ", 128)
+    forward = "_fwd_streamed" if streamed else "_fwd"
+
+    def run():
+        lse = []
+        real = getattr(fa, forward)
+
+        def spy(*a, **kwargs):
+            got = real(*a, **kwargs)
+            lse.append(got[1])
+            return got
+
+        with monkeypatch.context() as m:
+            m.setattr(fa, forward, spy)
+            out = flash_attention(q, k, v, **kw)
+        grads = jax.grad(_loss(flash_attention, **kw), (0, 1, 2))(q, k, v)
+        assert len(lse) == 1
+        return (out, lse[0]) + grads
+
+    new = run()
+    asked = []
+
+    def pair_mask(*a, **kwargs):
+        asked.append(a)
+        return _pair_mask(*a, **kwargs)
+
+    monkeypatch.setattr(fa, "_block_mask", pair_mask)
+    old = run()
+    assert len(asked) >= 3  # forward, dq and dk/dv all asked
+    for a, b, name in zip(new, old, ("out", "lse", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+

@@ -501,6 +501,33 @@ def test_cache_resyncs_after_watch_stop(srv):
     assert not kstore.cache.synced("Pod")
 
 
+def test_watch_stops_between_registering_and_opening_its_connection(
+        srv, monkeypatch):
+    """stop() shuts down the sockets of the registered connections. One
+    that is registered and not yet opened has no socket to shut: the pump
+    then read its stream with no timeout and never saw the stop (the
+    test above hit that window on a loaded box). Held open here by a
+    request that starts late."""
+    import http.client
+
+    real = http.client.HTTPConnection.request
+
+    def late(self, method, url, *a, **kw):
+        if "watch=true" in url:
+            time.sleep(0.5)
+        return real(self, method, url, *a, **kw)
+
+    monkeypatch.setattr(http.client.HTTPConnection, "request", late)
+    kstore = KubeObjectStore(KubeClient(srv.url))
+    w = kstore.watch(["Pod"])
+    try:
+        assert kstore.wait_for_cache_sync(["Pod"], timeout=30)
+        time.sleep(0.1)  # the pump is inside `late` now
+    finally:
+        w.stop()
+    assert w.join(timeout=20), "watch pump failed to exit after stop()"
+
+
 # ---------------------------------------------------------------------------
 # Gang admission over the wire (VERDICT r2 missing #3): a gang-enabled
 # JAXJob mirrors a PodGroup through the apiserver — spec on the main path,
