@@ -4,7 +4,8 @@
     python hack/scope_shares.py <trace dir or .xplane.pb> [out.json]
 
 The model's and the step's `jax.named_scope`s (embed, attn > attn_core,
-short_conv, mlp > moe_route / moe_permute / moe_experts / moe_combine,
+short_conv, ssm > ssm_conv / ssm_scan > ssm_carry / ssm_gate_norm,
+mlp > moe_route / moe_permute / moe_experts / moe_combine,
 head_loss, exit_gate, optimizer, grad_norm, and loop_pass around a looped
 stack's pass: PERF.md section 3) reach each
 device operation's `op_name`, not its name. On a TPU the profiler keeps
@@ -22,9 +23,13 @@ the traced window (first operation's start to the last one's end; an
 instant under a `while` or `conditional` counts once, for the innermost
 operation: `own_times`), the share that is remat recompute
 (`rematted_computation` in the `op_name`), what no scope covers, each
-named kernel's calls and time (`flash*`, `gmm*`, `moe_gather`), the runs of
-each program on the modules line, and the host's `train.*` / `bench.*`
-spans with the device gaps that fall under each.
+named kernel's calls and time (`flash*`, `gmm*`, `moe_gather`), the
+operations that took the most device time with their scope and what the
+compiler's cost analysis says they move (`largest_ops`, and each scope's
+own in `largest_ops_by_scope`, where the same fusion of every layer is one
+entry), the runs of each
+program on the modules line, and the host's `train.*` / `bench.*` spans
+with the device gaps that fall under each.
 """
 from __future__ import annotations
 
@@ -41,7 +46,9 @@ if ROOT not in sys.path:
 from benchmarks import trace as tr  # interval arithmetic only; no JAX
 
 # innermost first: an operation under attn/attn_core counts as attn_core
-SCOPES = ("attn_core", "attn", "short_conv", "moe_route", "moe_permute",
+SCOPES = ("attn_core", "attn", "short_conv",
+          "ssm_carry", "ssm_scan", "ssm_conv", "ssm_gate_norm", "ssm",
+          "moe_route", "moe_permute",
           "moe_experts", "moe_combine", "mlp", "head_loss", "exit_gate",
           "embed", "optimizer", "grad_norm",
           # a looped stack's pass: what no scope inside it covers (its
@@ -50,6 +57,10 @@ SCOPES = ("attn_core", "attn", "short_conv", "moe_route", "moe_permute",
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 HOST_SPAN = re.compile(r"^(train|bench|ckpt|reshard)\.|^train$")
 OP_NAME_STAT = "tf_op"
+LARGEST_OPS = 24
+LARGEST_IN_A_SCOPE = 16
+# what the compiler's cost analysis left on an operation's metadata
+COST_STATS = ("flops", "bytes_accessed", "model_flops")
 
 
 def scope_of(op_name: str) -> str:
@@ -153,6 +164,8 @@ def read_plane(buf: bytes) -> dict:
                 text = plane["stat_names"].get(first(val, 7), "").encode()
             if text is not None:
                 stats[name] = text.decode(errors="replace")
+            elif name in COST_STATS:  # uint64_value=3, int64_value=4
+                stats[name] = first(val, 3, first(val, 4))
         plane["events_meta"][mid] = (first(meta, 2, b"").decode(errors="replace"), stats)
     return plane
 
@@ -178,6 +191,25 @@ def find_xplane(path: str) -> str:
     return path
 
 
+def largest(ops, n: int):
+    return sorted(ops, key=lambda o: -o["seconds"])[:n]
+
+
+def same_kind(ops):
+    """Operations that differ in their instruction numbers alone (one
+    layer's fusion and the next layer's) as one entry: calls, seconds,
+    flops and bytes summed, `instructions` how many there were."""
+    kinds = {}
+    for o in ops:
+        key = (re.sub(r"%([\w\-]+?)\.\d+", r"%\1", o["op"]), o["remat"])
+        k = kinds.setdefault(key, dict(o, op=key[0], instructions=0, calls=0,
+                                       seconds=0.0, **{c: 0 for c in COST_STATS if c in o}))
+        k["instructions"] += 1
+        for field in ("calls", "seconds") + tuple(c for c in COST_STATS if c in o):
+            k[field] += o[field]
+    return kinds.values()
+
+
 def summarize(path: str) -> dict:
     path = find_xplane(path)
     with open(path, "rb") as f:
@@ -198,12 +230,21 @@ def summarize(path: str) -> dict:
                 dev["modules_ms"] = runs
             if line_name != "XLA Ops":
                 continue
-            by_scope, kernels, spans = {}, {}, []
+            by_scope, kernels, spans, by_op = {}, {}, [], {}
             remat = named = 0
             events = [e for e in line_events(plane, line) if e[3] > 0]
             for name, stats, start, dur in events:
                 spans.append((start, start + dur))
                 named += bool(stats.get(OP_NAME_STAT))
+                if name not in by_op:  # an instruction: one scope, many events
+                    op_name = stats.get(OP_NAME_STAT, "")
+                    by_op[name] = {
+                        "op": name[:200], "scope": scope_of(op_name),
+                        "remat": "rematted_computation" in op_name,
+                        "calls": 0, "seconds": 0.0,
+                        **{k: stats[k] for k in COST_STATS if k in stats}}
+                by_op[name]["calls"] += 1
+                by_op[name]["seconds"] += dur / 1e9
                 m = re.match(r"^%((?:flash|gmm|moe_gather)\w*?)\.\d+ = ", name)
                 if m:
                     k = kernels.setdefault(m[1], [0, 0])
@@ -228,6 +269,12 @@ def summarize(path: str) -> dict:
                 "kernels": {k: {"calls": c, "seconds": d / 1e9,
                                 "ms_a_call": d / c / 1e6}
                             for k, (c, d) in sorted(kernels.items())},
+                "largest_ops": largest(by_op.values(), LARGEST_OPS),
+                "largest_ops_by_scope": {
+                    scope: largest(same_kind(o for o in by_op.values()
+                                             if o["scope"] == scope),
+                                   LARGEST_IN_A_SCOPE)
+                    for scope in by_scope},
             })
             if gaps_of_first is None:
                 gaps_of_first = tr.gaps(busy)

@@ -146,8 +146,9 @@ def _grow_widths(widths, row) -> None:
 
 def _span_detail(attrs) -> str:
     """The DETAIL column of `trace`: the attributes that say what a span
-    was, and of a looped stack's step its passes and exit distribution
-    (the `loop_*` counters, docs/observability.md)."""
+    was, of a looped stack's step its passes and exit distribution (the
+    `loop_*` counters) and of a state-space model's step what its scans
+    carried (the `ssm_*` counters, docs/observability.md)."""
     detail = [f"{k}={attrs[k]}" for k in
               ("step", "stage", "cause", "outcome", "shape", "reason", "error")
               if k in attrs]
@@ -157,6 +158,11 @@ def _span_detail(attrs) -> str:
         detail.append("exit=" + "/".join(
             f"{attrs.get(f'loop_exit_mass_{t}', 0.0):.2f}"
             for t in range(1, passes + 1)))
+    if "ssm_layers" in attrs:
+        detail.append(f"ssm_layers={int(attrs['ssm_layers'])}")
+        detail.append(f"chunks={int(attrs['ssm_chunks'])}")
+        detail.append(f"carry={attrs['ssm_state_carry']:.3f}")
+        detail.append(f"dt={attrs['ssm_dt_mean']:.4f}")
     return " ".join(detail)
 
 

@@ -40,6 +40,13 @@ def config_from_hf(hf_config, **overrides) -> LlamaConfig:
             f"{passes} times with four norms a layer and an exit gate: no "
             "checkpoint mapping is written for it (LlamaConfig.ouro_2_6b "
             "trains it from seeded weights)")
+    kinds = tuple(getattr(hf_config, "layer_types", None) or ())
+    if "mamba" in kinds:
+        raise ValueError(
+            f"model_type {model_type!r} holds {kinds.count('mamba')} "
+            "state-space (mamba) layers: no checkpoint mapping is written "
+            "for their leaves (LlamaConfig.granite_4_0_h_micro trains the "
+            "architecture from seeded weights)")
     if model_type not in ("llama", "mistral", "gemma", "gemma2", "qwen2"):
         raise ValueError(
             f"unsupported model_type {model_type!r} "

@@ -32,6 +32,7 @@ from kubedl_tpu.models.moe import moe_init, moe_layer, moe_param_specs
 from kubedl_tpu.models.quant import matmul as _mm
 from kubedl_tpu.models.short_conv import (short_conv, short_conv_init,
                                           short_conv_param_specs)
+from kubedl_tpu.models.ssm import ssm_init, ssm_mixer, ssm_param_specs
 from kubedl_tpu.ops.flash_attention import (FLASH_LSE, FLASH_OUT,
                                             flash_attention)
 from kubedl_tpu.ops.ring_attention import ring_attention
@@ -161,10 +162,27 @@ class LlamaConfig:
     n_experts_held: Optional[int] = None
     first_expert: int = 0
     # Token mixer per layer: None = attention in every layer, else a tuple
-    # of n_layers entries, "attention" or "conv" (a gated short
-    # convolution over conv_kernel tokens, models/short_conv.py)
+    # of n_layers entries, "attention", "conv" (a gated short
+    # convolution over conv_kernel tokens, models/short_conv.py) or "ssm"
+    # (a Mamba-2 state-space mixer, models/ssm.py)
     layer_types: Optional[tuple] = None
     conv_kernel: int = 3
+    # An "ssm" layer's sizes: ssm_heads heads of ssm_head_dim, each with
+    # a state of ssm_head_dim x ssm_state; B and C shared by all heads
+    # (one group); a depthwise convolution of ssm_conv_kernel taps with
+    # bias before the scan, which runs in chunks of ssm_chunk tokens
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 256
+    # False = no position embedding: q and k go to the scores as projected
+    use_rope: bool = True
+    # Granite's scalars: each branch's output times residual_multiplier
+    # before its residual add, the logits over logits_scaling. 1 = absent
+    # (no multiply is emitted)
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # RMSNorm over each head's entries of q and of k before RoPE
     # (q_norm / k_norm leaves of size head_dim)
     qk_norm: bool = False
@@ -199,10 +217,14 @@ class LlamaConfig:
                 raise ValueError(
                     f"layer_types has {len(self.layer_types)} entries "
                     f"for {self.n_layers} layers")
-            bad = set(self.layer_types) - {"attention", "conv"}
+            bad = set(self.layer_types) - {"attention", "conv", "ssm"}
             if bad:
                 raise ValueError(
-                    f"layer_types holds {sorted(bad)} (attention, conv)")
+                    f"layer_types holds {sorted(bad)} (attention, conv, ssm)")
+            if "ssm" in self.layer_types and self.ssm_heads < 1:
+                raise ValueError(
+                    "layer_types holds ssm layers and ssm_heads is "
+                    f"{self.ssm_heads}")
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"unknown moe_router {self.moe_router!r} (softmax, sigmoid)")
@@ -211,7 +233,7 @@ class LlamaConfig:
                 f"total_ut_steps must be >= 1, got {self.total_ut_steps}")
 
     def mixer_for(self, i: int) -> str:
-        """Layer i's token mixer: "attention" or "conv"."""
+        """Layer i's token mixer: "attention", "conv" or "ssm"."""
         return "attention" if self.layer_types is None else self.layer_types[i]
 
     def routed(self, i: int) -> bool:
@@ -245,6 +267,35 @@ class LlamaConfig:
                 f"inputs a layer would carry from step to step have no "
                 f"cache beside the keys and values (layer_types holds "
                 f"{self.layer_types.count('conv')} conv layers)")
+        self.require_no_ssm(what)
+
+    def require_no_ssm(self, what: str) -> None:
+        """Refusal of the paths that have no state for a state-space
+        layer."""
+        if self.layer_types is not None and "ssm" in self.layer_types:
+            raise NotImplementedError(
+                f"{what} has no state for a state-space (ssm) layer: the "
+                f"{self.ssm_heads} x {self.ssm_head_dim} x {self.ssm_state} "
+                f"recurrent state and the last ssm_conv_kernel - 1 = "
+                f"{self.ssm_conv_kernel - 1} convolution inputs a layer "
+                f"would carry from step to step have no cache beside the "
+                f"keys and values (layer_types holds "
+                f"{self.layer_types.count('ssm')} ssm layers); it trains "
+                f"(llama.loss_and_stats) and is not served")
+
+    def require_whole_sequences(self, what: str) -> None:
+        """Refusal of a sequence split over devices by the layers that
+        carry something along it."""
+        carried = [k for k in ("conv", "ssm")
+                   if self.layer_types is not None and k in self.layer_types]
+        if carried:
+            raise NotImplementedError(
+                f"{what} splits the sequence over devices: a "
+                f"{' or '.join(carried)} layer's taps and state reach across "
+                f"a shard's edge and nothing hands them from shard to shard "
+                f"(layer_types holds {len(self.layer_types)} layers, "
+                f"{sum(self.layer_types.count(k) for k in carried)} of them "
+                f"{' / '.join(carried)})")
 
     def window_for(self, i: int) -> Optional[int]:
         """Layer i's attention window: layer_windows wins, else the
@@ -295,6 +346,7 @@ class LlamaConfig:
             "llama-7b": LlamaConfig.llama_7b,
             "lfm2-8b-a1b": LlamaConfig.lfm2_8b_a1b,
             "ouro-2.6b": LlamaConfig.ouro_2_6b,
+            "granite-4.0-h-micro": LlamaConfig.granite_4_0_h_micro,
         }
         if name not in factories:
             raise ValueError(
@@ -341,6 +393,29 @@ class LlamaConfig:
         return LlamaConfig(**defaults)
 
     @staticmethod
+    def granite_4_0_h_micro(**kw) -> "LlamaConfig":
+        """Granite-4.0-H-Micro at its published sizes
+        (ibm-granite/granite-4.0-h-micro config.json): 40 layers of hidden
+        2,048 in four periods of five state-space layers, one attention
+        layer, four state-space layers; Mamba-2 mixers of 64 heads of 64
+        with a state of 128, 4 taps, chunks of 256; GQA of 32 query and 8
+        key/value heads of 64 with no position embedding and scores
+        scaled by 1/64; a SwiGLU of 8,192 in every layer; embeddings
+        times 12, each branch times 0.22, logits over 8; tied head over
+        100,352. 3.19B parameters."""
+        period = ("ssm",) * 5 + ("attention",) + ("ssm",) * 4
+        defaults = dict(
+            vocab_size=100352, d_model=2048, n_layers=40, n_heads=32,
+            n_kv_heads=8, d_ff=8192, max_seq_len=131072, rms_eps=1e-5,
+            tie_embeddings=True, layer_types=period * 4, ssm_heads=64,
+            ssm_head_dim=64, ssm_state=128, ssm_conv_kernel=4, ssm_chunk=256,
+            use_rope=False, query_pre_attn_scalar=4096.0, embed_scale=12.0,
+            residual_multiplier=0.22, logits_scaling=8.0,
+        )
+        defaults.update(kw)
+        return LlamaConfig(**defaults)
+
+    @staticmethod
     def bench_150m(**kw) -> "LlamaConfig":
         """~170M params — the single-chip quick-proof bench size."""
         defaults = dict(
@@ -373,6 +448,8 @@ def param_specs(config: LlamaConfig, rules: Optional[ShardingRules] = None) -> D
     def layer_specs(i: int) -> Dict:
         if config.mixer_for(i) == "conv":
             layer = {"conv_norm": r.spec("embed"), **short_conv_param_specs(r)}
+        elif config.mixer_for(i) == "ssm":
+            layer = {"ssm_norm": r.spec("embed"), **ssm_param_specs(r)}
         else:
             layer = {
                 "attn_norm": r.spec("embed"),
@@ -434,6 +511,10 @@ def init(config: LlamaConfig, key: jax.Array) -> Dict:
         if config.mixer_for(i) == "conv":
             layer = {"conv_norm": norm_init, **short_conv_init(
                 ks[0], d, config.conv_kernel, dtype=dt)}
+        elif config.mixer_for(i) == "ssm":
+            layer = {"ssm_norm": norm_init, **ssm_init(
+                ks[0], d, config.ssm_heads, config.ssm_head_dim,
+                config.ssm_state, config.ssm_conv_kernel, dtype=dt)}
         else:
             layer = {
                 "attn_norm": norm_init,
@@ -602,6 +683,15 @@ def _proj(h, layer, name, lora=None, adapter_ids=None):
     return out
 
 
+def _add_branch(x, out, config: LlamaConfig):
+    """x + residual_multiplier * out: a branch's output onto the residual
+    stream, the multiply in float32 and absent at 1."""
+    if config.residual_multiplier != 1.0:
+        out = (out.astype(jnp.float32)
+               * config.residual_multiplier).astype(x.dtype)
+    return x + out
+
+
 # The named scopes below (embed, attn > attn_core, mlp, head_loss) reach
 # each instruction's op_name in a profile, the same names whatever
 # implements the work, so a share read by scope compares a flash step with
@@ -661,8 +751,9 @@ def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
     if "q_norm" in layer:
         q = rms_norm(q, layer["q_norm"], config.rms_eps, config.norm_offset)
         k = rms_norm(k, layer["k_norm"], config.rms_eps, config.norm_offset)
-    q = _rope(q, positions, config.rope_theta, config.rope_scaling)
-    k = _rope(k, positions, config.rope_theta, config.rope_scaling)
+    if config.use_rope:
+        q = _rope(q, positions, config.rope_theta, config.rope_scaling)
+        k = _rope(k, positions, config.rope_theta, config.rope_scaling)
     if config.q_prescale != 1.0:
         q = q * jnp.asarray(config.q_prescale, q.dtype)
     if nq != nkv:
@@ -675,7 +766,7 @@ def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
     if "post_attn_norm" in layer:
         out = rms_norm(out, layer["post_attn_norm"], config.rms_eps,
                        config.norm_offset)
-    return x + out
+    return _add_branch(x, out, config)
 
 
 @jax.named_scope("short_conv")
@@ -683,16 +774,29 @@ def _short_conv_block(x, layer, config: LlamaConfig):
     """A convolution layer's mixer with its norm and residual, as
     _attention_block is an attention layer's."""
     h = rms_norm(x, layer["conv_norm"], config.rms_eps, config.norm_offset)
-    return x + short_conv(h, layer).astype(x.dtype)
+    return _add_branch(x, short_conv(h, layer).astype(x.dtype), config)
+
+
+@jax.named_scope("ssm")
+def _ssm_block(x, layer, config: LlamaConfig):
+    """A state-space layer's mixer with its norm and residual, and the
+    layer's counters (models/ssm.py ssm_mixer)."""
+    h = rms_norm(x, layer["ssm_norm"], config.rms_eps, config.norm_offset)
+    out, stats = ssm_mixer(h, layer, config.ssm_heads, config.ssm_head_dim,
+                           config.ssm_state, config.ssm_chunk, config.rms_eps)
+    return _add_branch(x, out.astype(x.dtype), config), stats
 
 
 def _mixer_block(x, layer, config: LlamaConfig, positions, mesh, rules,
                  context_size, window=None):
-    """The layer's token mixer, by what the layer holds."""
+    """The layer's token mixer, by what the layer holds, and its counters
+    ({} but for a state-space layer)."""
+    if "ssm_in" in layer:
+        return _ssm_block(x, layer, config)
     if "conv_in" in layer:
-        return _short_conv_block(x, layer, config)
+        return _short_conv_block(x, layer, config), {}
     return _attention_block(x, layer, config, positions, mesh, rules,
-                            context_size, window=window)
+                            context_size, window=window), {}
 
 
 @jax.named_scope("mlp")
@@ -723,7 +827,7 @@ def _mlp_block(x, layer, config: LlamaConfig, mesh=None, rules=None,
     if "post_mlp_norm" in layer:
         y = rms_norm(y, layer["post_mlp_norm"], config.rms_eps,
                      config.norm_offset)
-    return x + y, aux, stats
+    return _add_branch(x, y, config), aux, stats
 
 
 def _constrainer(mesh, rules):
@@ -750,6 +854,8 @@ def _backbone(
     context_size = 1
     if mesh is not None:
         context_size = mesh.shape.get("context", 1)
+    if context_size > 1:
+        config.require_whole_sequences(f"a mesh with context: {context_size}")
     constrain = _constrainer(mesh, rules)
 
     b, t = tokens.shape
@@ -770,11 +876,12 @@ def _backbone(
         # program), so it rides a closure, not a traced argument
         def layer_fn(carry, layer):
             x, aux = carry
-            x = _mixer_block(x, layer, config, positions, mesh, rules,
-                             context_size, window=window)
+            x, mixed = _mixer_block(x, layer, config, positions, mesh, rules,
+                                    context_size, window=window)
             x = constrain(x, "batch", "seq", None)
             x, a, counters = _mlp_block(x, layer, config, mesh, rules)
-            return (constrain(x, "batch", "seq", None), aux + a), counters
+            return (constrain(x, "batch", "seq", None), aux + a), {
+                **mixed, **counters}
 
         if config.remat:
             return jax.checkpoint(
@@ -867,6 +974,8 @@ def _head_logits(x, params, config: LlamaConfig, rounded: bool = True) -> jax.Ar
     else:
         logits = jnp.matmul(x, _head_matrix(params, config),
                             preferred_element_type=jnp.float32)
+    if config.logits_scaling != 1.0:
+        logits = logits / config.logits_scaling
     if config.final_logit_softcap:
         logits = softcap(logits, config.final_logit_softcap)
     return logits
@@ -903,10 +1012,17 @@ def _next_token_ce_chunked(x, params, config: LlamaConfig, targets, n_chunks: in
     cs = V // n_chunks
     hc = jnp.moveaxis(head.reshape(d, n_chunks, cs), 1, 0)  # [n, d, cs]
     offs = jnp.arange(n_chunks, dtype=targets.dtype) * cs
+    # the chunks' cotangents of xn are summed by the loop in the dtype of
+    # what it closes over: in float32, or a chunk's part (the softmax's
+    # pull, a hundredth of the target row's) falls under half an ulp of a
+    # bf16 running sum and is dropped, every token alike
+    xn32 = xn.astype(jnp.float32)
 
     @jax.checkpoint
     def chunk_stats(h_c, off):
-        logits = (xn @ h_c).astype(jnp.float32)  # [b, t, cs]
+        logits = (xn32.astype(xn.dtype) @ h_c).astype(jnp.float32)  # [b, t, cs]
+        if config.logits_scaling != 1.0:
+            logits = logits / config.logits_scaling
         if config.final_logit_softcap:
             # softcap is elementwise, so capping per chunk == capping the
             # full logits — the chunked loss must match _lm_head's math
@@ -1030,7 +1146,9 @@ def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
     moe_rows_moved, moe_rows_spanned, and moe_load_max_over_mean over
     the held experts; for a looped stack (_looped_loss): loop_passes,
     loop_layer_applications, loop_exit_mass_<t>, loop_ce_<t>,
-    loop_exit_entropy (docs/observability.md)."""
+    loop_exit_entropy; for a model with state-space layers (models/ssm.py):
+    ssm_layers, ssm_chunks, and, averaged over those layers, ssm_dt_mean
+    and ssm_state_carry (docs/observability.md)."""
     rules = rules or ShardingRules()
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     chunked = config.ce_chunks > 1
@@ -1051,6 +1169,11 @@ def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
         logits = _constrainer(mesh, rules)(
             _lm_head(x, params, config), "batch", "seq", "vocab")
         ce = _next_token_ce(logits, targets)
+    if "ssm_layers" in stats:
+        # the layers' means were summed over the layers with their count
+        stats = dict(stats)
+        for k in ("ssm_dt_mean", "ssm_state_carry"):
+            stats[k] = stats[k] / stats["ssm_layers"]
     if "moe_rows_fullest" in stats:
         stats = dict(stats)
         held = config.n_experts_held or config.n_experts
@@ -1144,6 +1267,7 @@ def forward_pipelined_and_aux(
     tensor/context/expert must be size 1 on a pipelined mesh (those
     shardings need manual collectives inside shard_map)."""
     config.require_single_pass("the pipelined forward")
+    config.require_no_ssm("the pipelined forward")
     if config.layer_windows is not None:
         # the pipeline scans ONE compiled layer program over stacked
         # params; a per-layer static mask can't vary inside the scan

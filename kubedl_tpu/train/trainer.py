@@ -54,7 +54,7 @@ class StepRecorder:
                    dispatch_s: float, compiled: bool, counters=None) -> None:
         """`counters`: what the step returned beside its loss and gradient
         norm (an expert model's `moe_*` / `gmm_*`, a looped stack's
-        `loop_*`: llama.loss_and_stats);
+        `loop_*`, a state-space model's `ssm_*`: llama.loss_and_stats);
         they ride the step's record, read when its loss is."""
         self.pending.append((step, loss, t_start, data_s, dispatch_s, compiled,
                              counters or {}))
@@ -90,7 +90,7 @@ def parse_args(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--model", default=os.environ.get("KUBEDL_MODEL", "tiny"),
                    choices=["tiny", "bench-1b", "llama-7b", "lfm2-8b-a1b",
-                            "ouro-2.6b"])
+                            "ouro-2.6b", "granite-4.0-h-micro"])
     p.add_argument("--steps", type=int, default=int(os.environ.get("KUBEDL_STEPS", 100)))
     p.add_argument("--batch", type=int, default=int(os.environ.get("KUBEDL_BATCH", 8)))
     p.add_argument("--seq-len", type=int, default=int(os.environ.get("KUBEDL_SEQ_LEN", 512)))
@@ -437,9 +437,9 @@ def main(argv=None) -> int:
                     step_loss = loss_on(a_mesh)
                 else:
                     def step_loss(params, batch):
-                        # (loss, an expert model's or a looped stack's
-                        # counters, which ride the train.step record: {}
-                        # for a dense model run once)
+                        # (loss, an expert model's, a looped stack's or
+                        # a state-space model's counters, which ride the
+                        # train.step record: {} for a dense model run once)
                         return llama.loss_and_stats(
                             params, batch, config, mesh=a_mesh, rules=rules)
                 return make_train_step(
@@ -804,7 +804,7 @@ def main(argv=None) -> int:
                             step + 1, metrics["loss"], t_step0, data_span.dur,
                             dispatch_span.dur, compile_pending["v"],
                             {k: v for k, v in metrics.items()
-                             if k.startswith(("moe_", "gmm_", "loop_"))})
+                             if k.startswith(("moe_", "gmm_", "loop_", "ssm_"))})
                         compile_pending["v"] = False
             if prof is not None and prof.should_stop(step):
                 settle(metrics["loss"])

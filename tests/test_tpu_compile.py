@@ -258,6 +258,32 @@ def test_looped_loss_holds_one_stacks_kernels_and_a_piece_of_logits(one_chip, on
     assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
 
 
+def test_state_space_loss_holds_one_piece_of_logits_and_float32_carries(one_chip, on_tpu):
+    """A state-space hybrid at the published widths (depth cut to one
+    state-space and one attention layer), 2 x 8,192 tokens, the head's
+    loss over 7 vocabulary pieces: the chunked scan is plain XLA (the one
+    attention layer's flash kernels are the only Mosaic calls), the state
+    goes from chunk to chunk in float32 through a loop of its own, and no
+    array of all the step's tokens by the vocabulary exists."""
+    config = llama.LlamaConfig.granite_4_0_h_micro(
+        n_layers=2, layer_types=("ssm", "attention"), max_seq_len=8192, ce_chunks=7)
+    params = _abstract_params(
+        config, lambda t: jax.tree_util.tree_map(lambda _: one_chip, t))
+    tokens = jax.ShapeDtypeStruct((2, 8193), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(
+        lambda p, t: llama.loss_fn(p, t, config))).lower(params, tokens).compile()
+    text = compiled.as_text()
+    assert _flash_calls(text) == dict.fromkeys(FLASH_INSTRUCTIONS, 1)
+    assert text.count(" while(") >= 4  # the carry and the head's pieces, both ways
+    assert "f32[2,64,64,128]" in text  # a sequence's state between chunks
+    vocab, piece = config.vocab_size, config.vocab_size // 7
+    assert f"f32[2,8192,{vocab}]" not in text and f"bf16[2,8192,{vocab}]" not in text
+    assert f"f32[2,8192,{piece}]" in text
+    # the whole logits in float32 would be 6.6 GB; a piece's and the scan's
+    # [2, 64, 32, 256, 256] float32 tensors (1.07 GB each) stay under 6
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+
+
 def test_sharded_train_step_with_flash_compiles(topo, on_tpu):
     """bench-1b widths under fsdp: 4 on the described 2x2 mesh, flash
     attention on: Mosaic kernels cannot be partitioned by GSPMD, so this
