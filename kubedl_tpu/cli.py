@@ -163,6 +163,8 @@ def _span_detail(attrs) -> str:
         detail.append(f"chunks={int(attrs['ssm_chunks'])}")
         detail.append(f"carry={attrs['ssm_state_carry']:.3f}")
         detail.append(f"dt={attrs['ssm_dt_mean']:.4f}")
+        if "ssm_kernel_chunks" in attrs:  # a record from before the kernels has none
+            detail.append(f"kernel_chunks={int(attrs['ssm_kernel_chunks'])}")
     return " ".join(detail)
 
 

@@ -18,8 +18,16 @@ decay-weighted C B^T product against x, quadratic in the chunk and all
 matmuls; a chunk's end state from B, x and the decays; the states passed
 from chunk to chunk in float32; the carried state's part of each output.
 Every decay is a cumulative sum in float32, matmul operands are in the
-model's dtype. Plain XLA; the backward is autodiff under the layer's
-remat.
+model's dtype. On a TPU, at shapes of whole tiles (a chunk and a state
+that are multiples of 128, heads in blocks of 8), all four steps run as
+ops/ssm_scan.py's two Pallas kernels, one call a layer and pass:
+`ssm_scan_fwd`, which walks a sequence's chunks in order with the scores
+and the state in VMEM and writes y once, token before head, and a
+hand-written backward `ssm_scan_bwd`, which walks them in reverse; XLA
+keeps the decays' cumulative sums. Every other shape, the CPU and a mesh
+that shards the inner channels take the same steps as XLA operations,
+whose backward is autodiff. Both under the layer's remat.
+`ssm_kernel_chunks` counts the chunks the kernels took.
 
 What a decoder would carry from step to step is S (heads x head size x
 state size a layer) and the convolution's last K - 1 inputs, not keys and
@@ -29,6 +37,7 @@ layer (LlamaConfig.require_kv_state_only).
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -37,6 +46,7 @@ import numpy as np
 
 from kubedl_tpu.models.quant import matmul as _mm
 from kubedl_tpu.models.short_conv import causal_taps
+from kubedl_tpu.ops import interpret, ssm_scan
 from kubedl_tpu.parallel.mesh import ShardingRules
 
 
@@ -83,9 +93,23 @@ def ssm_init(key: jax.Array, d_model: int, heads: int, head_dim: int,
     }
 
 
+def scan_takes_kernel(x_shape: Tuple[int, ...], state: int, chunk: int,
+                      mesh=None) -> bool:
+    """Whether chunked_scan runs as the Pallas kernels (ops/ssm_scan.py):
+    on a TPU, at shapes of whole tiles, and under no mesh that shards the
+    inner channels (a Mosaic call cannot be partitioned; over `batch` it
+    rides a shard_map)."""
+    _, t, h, p = x_shape
+    if interpret() or not ssm_scan.supports(h, p, state, chunk, t):
+        return False
+    return mesh is None or mesh.shape.get("tensor", 1) == 1
+
+
 @jax.named_scope("ssm_scan")
 def chunked_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b_: jax.Array,
-                 c_: jax.Array, chunk: int) -> Tuple[jax.Array, jax.Array]:
+                 c_: jax.Array, chunk: int, mesh=None,
+                 rules: Optional[ShardingRules] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
     """y_t = S_t C_t for S_t = exp(dt_t a) S_{t-1} + dt_t x_t B_t^T, S_0 = 0.
 
     x [b, t, h, p] and b_, c_ [b, t, n] in the model's dtype; dt [b, t, h]
@@ -94,7 +118,12 @@ def chunked_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b_: jax.Array,
     decays: the share of a chunk's incoming state that leaves it.
 
     A sequence that is no multiple of the chunk is padded with steps of
-    dt = 0, which leave the state alone and whose outputs are dropped."""
+    dt = 0, which leave the state alone and whose outputs are dropped.
+
+    One algorithm in two forms, chosen by `scan_takes_kernel` from the
+    shapes and the mesh: ops/ssm_scan.py's kernels, which take x, dt, B,
+    C and the decays' cumulative sums and keep the scores and the state
+    in VMEM, or the XLA operations below."""
     bsz, t, h, p = x.shape
     n = b_.shape[-1]
     q = min(chunk, t)
@@ -104,6 +133,18 @@ def chunked_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b_: jax.Array,
                          for v in (x, dt, b_, c_))
     nc = (t + pad) // q
     f32, dtype = jnp.float32, x.dtype
+    if scan_takes_kernel((bsz, t, h, p), n, chunk, mesh):
+        cum = jnp.cumsum((dt * a).reshape(bsz, nc, q, h), axis=2)
+        scan = functools.partial(ssm_scan.scan, chunk=q)
+        if mesh is not None and mesh.size > 1:
+            # GSPMD cannot partition a Mosaic kernel: each device scans
+            # its own sequences (the scan mixes no two)
+            rows = (rules or ShardingRules()).spec("batch", None, None)
+            scan = jax.shard_map(scan, mesh=mesh, in_specs=(rows,) * 5,
+                                 out_specs=rows, check_vma=False)
+        y = scan(x.reshape(bsz, nc * q, h * p), dt, cum.reshape(bsz, nc * q, h),
+                 b_, c_)
+        return y.reshape(bsz, nc * q, h, p)[:, :t], jnp.exp(cum[:, :, -1])
     xdt = (x.astype(f32) * dt[..., None]).astype(dtype).reshape(bsz, nc, q, h, p)
     b_, c_ = b_.reshape(bsz, nc, q, n), c_.reshape(bsz, nc, q, n)
     # log of the decay from a chunk's start through each of its tokens
@@ -143,10 +184,12 @@ def chunked_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b_: jax.Array,
 
 
 def ssm_mixer(u: jax.Array, layer: Dict, heads: int, head_dim: int,
-              state: int, chunk: int, eps: float) -> Tuple[jax.Array, Dict]:
+              state: int, chunk: int, eps: float, mesh=None,
+              rules: Optional[ShardingRules] = None) -> Tuple[jax.Array, Dict]:
     """The mixer's output for normed input u [b, t, d], and the layer's
-    counters: chunks scanned, the mean step size after the softplus, the
-    mean share of a chunk's incoming state that leaves it."""
+    counters: chunks scanned, how many of them went through the scan's
+    kernels, the mean step size after the softplus, the mean share of a
+    chunk's incoming state that leaves it."""
     bsz, t, _ = u.shape
     d_inner, f32 = heads * head_dim, jnp.float32
     z, xbc, dt = jnp.split(_mm(u, layer["ssm_in"]),
@@ -158,14 +201,18 @@ def ssm_mixer(u: jax.Array, layer: Dict, heads: int, head_dim: int,
     x, b_, c_ = jnp.split(xbc, [d_inner, d_inner + state], axis=-1)
     x = x.reshape(bsz, t, heads, head_dim)
     dt = jax.nn.softplus(dt.astype(f32) + layer["ssm_dt_bias"])
-    y, through = chunked_scan(x, dt, -jnp.exp(layer["ssm_A_log"]), b_, c_, chunk)
+    y, through = chunked_scan(x, dt, -jnp.exp(layer["ssm_A_log"]), b_, c_, chunk,
+                              mesh, rules)
     y = y + x.astype(f32) * layer["ssm_D"][:, None]
     with jax.named_scope("ssm_gate_norm"):
         g = y.reshape(bsz, t, d_inner) * jax.nn.silu(z.astype(f32))
         g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
         g = (g * layer["ssm_gate_norm"]).astype(u.dtype)
+    chunks = through.shape[0] * through.shape[1]
     stats = {"ssm_layers": jnp.ones((), f32),
-             "ssm_chunks": jnp.asarray(through.shape[0] * through.shape[1], f32),
+             "ssm_chunks": jnp.asarray(chunks, f32),
+             "ssm_kernel_chunks": jnp.asarray(
+                 chunks * scan_takes_kernel(x.shape, state, chunk, mesh), f32),
              "ssm_dt_mean": jnp.mean(dt),
              "ssm_state_carry": jnp.mean(through)}
     return _mm(g, layer["ssm_out"]), stats

@@ -79,8 +79,12 @@ def _flash_calls(text: str) -> dict:
     """How many instructions each flash kernel's name defines: what
     benchmarks/reducers/flash_roofline.py counts as calls, so a kernel
     split in two under one name would double the least time it credits."""
+    return _calls(text, FLASH_INSTRUCTIONS)
+
+
+def _calls(text: str, names) -> dict:
     return {name: len(re.findall(re.escape(name) + r"\d+ = ", text))
-            for name in FLASH_INSTRUCTIONS}
+            for name in names}
 
 
 @pytest.mark.parametrize("shape,window", [
@@ -258,13 +262,24 @@ def test_looped_loss_holds_one_stacks_kernels_and_a_piece_of_logits(one_chip, on
     assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
 
 
+# ops/ssm_scan.py's two kernels, by the names a trace is read by
+SCAN_INSTRUCTIONS = ("%ssm_scan_fwd.", "%ssm_scan_bwd.")
+
+
+def _scan_calls(text: str) -> dict:
+    return _calls(text, SCAN_INSTRUCTIONS)
+
+
 def test_state_space_loss_holds_one_piece_of_logits_and_float32_carries(one_chip, on_tpu):
     """A state-space hybrid at the published widths (depth cut to one
     state-space and one attention layer), 2 x 8,192 tokens, the head's
-    loss over 7 vocabulary pieces: the chunked scan is plain XLA (the one
-    attention layer's flash kernels are the only Mosaic calls), the state
-    goes from chunk to chunk in float32 through a loop of its own, and no
-    array of all the step's tokens by the vocabulary exists."""
+    loss over 7 vocabulary pieces: the chunked scan is its two Pallas
+    kernels (the forward again in the remat copy), so no [sequences,
+    chunks, heads, 256, 256] array exists in either order and no loop but
+    the head's pieces; the state goes from chunk to chunk in float32
+    inside the kernels and each chunk's entering state is the forward's
+    second output; no array of all the step's tokens by the vocabulary
+    exists."""
     config = llama.LlamaConfig.granite_4_0_h_micro(
         n_layers=2, layer_types=("ssm", "attention"), max_seq_len=8192, ce_chunks=7)
     params = _abstract_params(
@@ -274,14 +289,40 @@ def test_state_space_loss_holds_one_piece_of_logits_and_float32_carries(one_chip
         lambda p, t: llama.loss_fn(p, t, config))).lower(params, tokens).compile()
     text = compiled.as_text()
     assert _flash_calls(text) == dict.fromkeys(FLASH_INSTRUCTIONS, 1)
-    assert text.count(" while(") >= 4  # the carry and the head's pieces, both ways
-    assert "f32[2,64,64,128]" in text  # a sequence's state between chunks
+    assert _scan_calls(text) == {"%ssm_scan_fwd.": 2, "%ssm_scan_bwd.": 1}
+    assert "[2,32,64,256,256]" not in text and "[2,64,32,256,256]" not in text
+    assert text.count(" while(") == 2  # the head's pieces, both ways
+    assert "f32[32,2,128,4096]" in text  # the states entering each chunk, transposed
     vocab, piece = config.vocab_size, config.vocab_size // 7
     assert f"f32[2,8192,{vocab}]" not in text and f"bf16[2,8192,{vocab}]" not in text
     assert f"f32[2,8192,{piece}]" in text
-    # the whole logits in float32 would be 6.6 GB; a piece's and the scan's
-    # [2, 64, 32, 256, 256] float32 tensors (1.07 GB each) stay under 6
-    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+    # the whole logits in float32 would be 6.6 GB; with the scan's
+    # [2, 64, 32, 256, 256] float32 tensors (1.07 GB each) the step held
+    # 4.59 GB; now 2.95 GB: a piece's logits and the kernels' operands
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
+
+
+def test_state_space_layer_under_fsdp_rides_a_shard_map(topo, on_tpu):
+    """One state-space layer at the published widths under fsdp: 4 on the
+    described 2x2, a sequence of 1,024 a chip: GSPMD cannot partition a
+    Mosaic call, so this compiles only while the scan's kernels sit
+    inside a shard_map over `batch`, each chip on its own sequence."""
+    config = llama.LlamaConfig.granite_4_0_h_micro(
+        n_layers=1, layer_types=("ssm",), max_seq_len=1024, ce_chunks=7)
+    mesh = build_mesh({"fsdp": 4}, devices=topo.devices)
+    rules = ShardingRules()
+    params = _abstract_params(
+        config, lambda t: jax.tree_util.tree_map(
+            lambda s: NamedSharding(mesh, s), llama.param_specs(config, rules)))
+    tokens = jax.ShapeDtypeStruct(
+        (4, 1025), jnp.int32,
+        sharding=NamedSharding(mesh, rules.spec("batch", None)))
+    text = _compile(jax.value_and_grad(
+        lambda p, t: llama.loss_fn(p, t, config, mesh=mesh, rules=rules)),
+        params, tokens)
+    assert _scan_calls(text) == {"%ssm_scan_fwd.": 2, "%ssm_scan_bwd.": 1}
+    assert "bf16[1,1024,4096]" in text  # a chip's own sequence of x * dt
+    assert "all-gather" in text or "all-reduce" in text
 
 
 def test_sharded_train_step_with_flash_compiles(topo, on_tpu):
