@@ -56,7 +56,9 @@ SCOPES = ("attn_core", "attn", "short_conv",
           # final norm, the loop's own copies)
           "loop_pass")
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
-HOST_SPAN = re.compile(r"^(train|bench|ckpt|reshard)\.|^train$")
+# jax.trace / jax.lower / jax.compile: a recompile inside the window
+# (kubedl_tpu/obs/compiles.py), so the gap under it has a name
+HOST_SPAN = re.compile(r"^(train|bench|ckpt|reshard|jax)\.|^train$")
 OP_NAME_STAT = "tf_op"
 LARGEST_OPS = 24
 LARGEST_IN_A_SCOPE = 16

@@ -150,7 +150,8 @@ def _span_detail(attrs) -> str:
     `loop_*` counters) and of a state-space model's step what its scans
     carried (the `ssm_*` counters, docs/observability.md)."""
     detail = [f"{k}={attrs[k]}" for k in
-              ("step", "stage", "cause", "outcome", "shape", "reason", "error")
+              ("step", "stage", "cause", "outcome", "shape", "reason", "error",
+               "fun", "cache")
               if k in attrs]
     if "loop_passes" in attrs:
         passes = int(attrs["loop_passes"])
@@ -596,7 +597,53 @@ def cmd_trace(args) -> int:
     for bucket, secs in (gp.get("buckets") or {}).items():
         rows.append((bucket, f"{secs:.3f}", f"{secs / wall:.1%}"))
     _print_table(rows)
+    _print_compile_table(spans)
     return 0
+
+
+def _print_compile_table(spans) -> None:
+    """Under the goodput table: one row a `jax.compile` span, a function
+    compiled as JAX reported it (obs/compiles.py: tracing, the conversion
+    to MLIR, the backend's compile or the persistent cache's read), with
+    the step whose record covers it, and under them the inner functions
+    the longest trace spent most of its own time in."""
+    compiled = [s for s in spans if s.get("name") == "jax.compile"]
+    if not compiled:
+        return
+    steps = [s for s in spans if s.get("name") in
+             ("train.compile", "train.step", "pipeline.step")]
+
+    def step_of(span) -> str:
+        for st in steps:
+            if (st.get("service") == span.get("service")
+                    and st["ts"] <= span["ts"] <= st["ts"] + st.get("dur", 0.0)):
+                return str(st.get("attrs", {}).get("step", "?"))
+        return "-"
+
+    print()
+    print("compiles (as jax.monitoring reported them):")
+    rows = [("FUN", "SERVICE", "TRACE_S", "LOWER_S", "EXECUTABLE_S", "CACHE",
+             "CACHE_READ_S", "STEP")]
+    for s in compiled:
+        a = s.get("attrs") or {}
+        rows.append((a.get("fun", "?"), s.get("service", ""),
+                     f"{a.get('trace_s', 0.0):.3f}", f"{a.get('lower_s', 0.0):.3f}",
+                     f"{s.get('dur', 0.0):.3f}", a.get("cache", "?"),
+                     f"{a.get('cache_read_s', 0.0):.3f}", step_of(s)))
+    _print_table(rows)
+    traced = [s for s in spans if s.get("name") == "jax.trace"
+              and (s.get("attrs") or {}).get("nested")]
+    if not traced:
+        return
+    longest = max(traced, key=lambda s: s.get("dur", 0.0))
+    print()
+    print(f"inner functions of {longest['attrs'].get('fun', '?')}'s trace "
+          f"({longest.get('dur', 0.0):.3f}s), by their own tracing time:")
+    rows = [("FUN", "CALLS", "OWN_S", "TOTAL_S")]
+    for n in longest["attrs"]["nested"]:
+        rows.append((n.get("fun", "?"), n.get("calls", 0),
+                     f"{n.get('own_s', 0.0):.3f}", f"{n.get('total_s', 0.0):.3f}"))
+    _print_table(rows)
 
 
 def cmd_history(args) -> int:

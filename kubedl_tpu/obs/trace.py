@@ -116,6 +116,15 @@ class Span:
             self.attrs.setdefault("error", f"{type(exc).__name__}: {exc}"[:200])
         self.end()
 
+    def cancel(self) -> None:
+        """Close a span opened with ``__enter__`` and make no record of
+        it: the profiler has its annotation, the ring and the file get
+        nothing (obs/compiles.py, which learns only later whether a
+        compile was worth a record, and writes it then with JAX's own
+        times)."""
+        self._done = True
+        self.__exit__(None, None, None)
+
 
 class Tracer:
     """Bounded flight recorder: in-process ring + optional JSONL export.

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from kubedl_tpu.obs import compiles
 from kubedl_tpu.parallel.mesh import ShardingRules
 
 
@@ -58,6 +59,10 @@ def make_train_step(
     before applying the update (optax.MultiSteps) — the HBM-for-batch
     trade when the global batch doesn't fit.
     """
+    # whoever drives the step (a trainer, the benchmark's runner, a probe
+    # under hack/) finds its trace, lowering and compile in the process's
+    # compile log (obs/compiles.py)
+    compiles.install()
     rules = rules or ShardingRules()
     if accum_steps > 1:
         tx = optax.MultiSteps(tx, every_k_schedule=accum_steps)

@@ -55,7 +55,7 @@ def parse_args(argv=None):
     p.add_argument("--checkpoint-interval", type=int,
                    default=int(os.environ.get("KUBEDL_CHECKPOINT_INTERVAL", 0)))
     # JAX profiler window, same contract as the SPMD trainer
-    # (train/profile_window.py): N steps after the compile step, stopped
+    # (train/profile_window.py): N steps after the first, stopped
     # cleanly on preemption too
     p.add_argument("--profile-dir",
                    default=os.environ.get("KUBEDL_PROFILE_DIR", ""))
@@ -93,75 +93,79 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2  # permanent config error (utils/exit_codes.py)
 
-    from kubedl_tpu.train import coordinator
+    # flight recorder (docs/observability.md): per-stage step spans +
+    # telemetry stream, correlated by the injected gang trace id — the
+    # MPMD plane's pods share the job's KUBEDL_TRACE_DIR
+    from kubedl_tpu.obs import StepStream, compiles, tracer_from_env
 
-    coordinator.start_local()
+    tracer = tracer_from_env()
+    step_stream = StepStream.from_env()
 
-    import jax
-    import numpy as np
-    import optax
+    # trainer.init's children, as in the SPMD trainer (a stage builds no
+    # mesh, so there is no init.mesh)
+    with tracer.span("init.imports"):
+        import jax
+        import numpy as np
+        import optax
 
-    from kubedl_tpu.models import llama
-    from kubedl_tpu.train import pipeline_runtime
-    from kubedl_tpu.utils.exit_codes import EXIT_TPU_PREEMPTED
+        from kubedl_tpu.models import llama
+        from kubedl_tpu.train import coordinator, pipeline_runtime
+        from kubedl_tpu.utils.exit_codes import EXIT_TPU_PREEMPTED
+
+    compile_log = compiles.install(tracer)
+    with tracer.span("init.backend"):
+        coordinator.start_local()
 
     config = llama.LlamaConfig.config_for(args.model)
     stage = int(os.environ.get("KUBEDL_PP_STAGE", "0"))
     n_stages = int(os.environ.get("KUBEDL_PP_STAGES", "1"))
-
-    # flight recorder (docs/observability.md): per-stage step spans +
-    # telemetry stream, correlated by the injected gang trace id — the
-    # MPMD plane's pods share the job's KUBEDL_TRACE_DIR
-    from kubedl_tpu.obs import StepStream, tracer_from_env
-
-    tracer = tracer_from_env()
-    step_stream = StepStream.from_env()
     tx = optax.adamw(args.lr, weight_decay=0.01)
-    try:
-        rt = pipeline_runtime.runtime_from_env(
-            config, llama.init(config, jax.random.PRNGKey(0)), tx)
-    except ValueError as e:
-        print(f"pipeline config invalid: {e}", file=sys.stderr)
-        return 2
-    endpoint = stage == 0 or stage == n_stages - 1
-    print(f"stage {stage}/{n_stages}: layers "
-          f"{rt.plan.layer_range(stage)} of {config.n_layers}, "
-          f"microbatches={rt.plan.n_microbatches}, "
-          f"{'endpoint (drives data)' if endpoint else 'middle'}",
-          flush=True)
+    with tracer.span("init.state", stage=stage):
+        try:
+            rt = pipeline_runtime.runtime_from_env(
+                config, llama.init(config, jax.random.PRNGKey(0)), tx)
+        except ValueError as e:
+            print(f"pipeline config invalid: {e}", file=sys.stderr)
+            return 2
+        endpoint = stage == 0 or stage == n_stages - 1
+        print(f"stage {stage}/{n_stages}: layers "
+              f"{rt.plan.layer_range(stage)} of {config.n_layers}, "
+              f"microbatches={rt.plan.n_microbatches}, "
+              f"{'endpoint (drives data)' if endpoint else 'middle'}",
+              flush=True)
 
-    # stage-local Orbax checkpoint: {params, opt_state, step}
-    mngr = None
-    start_step = 0
-    if args.checkpoint_path:
-        import orbax.checkpoint as ocp
+        # stage-local Orbax checkpoint: {params, opt_state, step}
+        mngr = None
+        start_step = 0
+        if args.checkpoint_path:
+            import orbax.checkpoint as ocp
 
-        mngr = ocp.CheckpointManager(
-            os.path.join(args.checkpoint_path, f"stage-{stage}"),
-            options=ocp.CheckpointManagerOptions(max_to_keep=3, create=True))
-        # Restore the latest step EVERY stage has, not this stage's own
-        # latest: interval saves are per-stage and a crash can land
-        # between them, so stages' latest steps may differ — restoring
-        # independently would silently resume the gang at inconsistent
-        # optimizer steps (and deadlock the tail, which expects equal
-        # remaining step counts). The stage dirs share the checkpoint
-        # volume, so every stage can compute the same common step.
-        restore = _common_restore_step(args.checkpoint_path, n_stages)
-        if restore is not None and os.environ.get(
-                "KUBEDL_CHECKPOINT_RESTORE", "1") == "1":
-            with tracer.span("ckpt.restore", step=restore, stage=stage):
-                target = {"params": rt.params, "opt_state": rt.opt_state}
-                abstract = jax.tree.map(
-                    ocp.utils.to_shape_dtype_struct, target)
-                restored = mngr.restore(
-                    restore, args=ocp.args.StandardRestore(abstract))
-                rt.params, rt.opt_state = (
-                    restored["params"], restored["opt_state"])
-            start_step = restore
-            own = mngr.latest_step()
-            note = f" (own latest {own})" if own != restore else ""
-            print(f"stage {stage}: restored gang-common checkpoint at "
-                  f"step {restore}{note}", flush=True)
+            mngr = ocp.CheckpointManager(
+                os.path.join(args.checkpoint_path, f"stage-{stage}"),
+                options=ocp.CheckpointManagerOptions(max_to_keep=3, create=True))
+            # Restore the latest step EVERY stage has, not this stage's own
+            # latest: interval saves are per-stage and a crash can land
+            # between them, so stages' latest steps may differ — restoring
+            # independently would silently resume the gang at inconsistent
+            # optimizer steps (and deadlock the tail, which expects equal
+            # remaining step counts). The stage dirs share the checkpoint
+            # volume, so every stage can compute the same common step.
+            restore = _common_restore_step(args.checkpoint_path, n_stages)
+            if restore is not None and os.environ.get(
+                    "KUBEDL_CHECKPOINT_RESTORE", "1") == "1":
+                with tracer.span("ckpt.restore", step=restore, stage=stage):
+                    target = {"params": rt.params, "opt_state": rt.opt_state}
+                    abstract = jax.tree.map(
+                        ocp.utils.to_shape_dtype_struct, target)
+                    restored = mngr.restore(
+                        restore, args=ocp.args.StandardRestore(abstract))
+                    rt.params, rt.opt_state = (
+                        restored["params"], restored["opt_state"])
+                start_step = restore
+                own = mngr.latest_step()
+                note = f" (own latest {own})" if own != restore else ""
+                print(f"stage {stage}: restored gang-common checkpoint at "
+                      f"step {restore}{note}", flush=True)
 
     ckpt_stall = {"v": 0.0}
 
@@ -184,7 +188,7 @@ def main(argv=None) -> int:
     signal.signal(signal.SIGTERM, lambda *_: preempted.update(flag=True))
 
     # the SPMD trainer's profiler window, previously missing here
-    # entirely: N steps after the compile step, stopped idempotently on
+    # entirely: N steps after the first, stopped idempotently on
     # the preemption path and the finally backstop
     from kubedl_tpu.train.profile_window import window_from_args
 
@@ -207,18 +211,25 @@ def main(argv=None) -> int:
                     (args.batch, args.seq_len), dtype=np.int32)
             # the step's span is open while it runs (run_step ends in a
             # wait), so a --profile-dir window shows it on its own clock;
-            # it reaches the JSONL only under the injected trace env
-            with tracer.span(
-                    "train.compile" if step == start_step else "pipeline.step",
-                    step=step + 1, stage=stage) as step_span:
+            # it reaches the JSONL only under the injected trace env. A
+            # step in which this thread compiled (the stage's programs on
+            # the first, whatever traces anew later) is written as
+            # train.compile with what compiled
+            compiles_before = compile_log.count(thread=True)
+            with tracer.span("pipeline.step", step=step + 1,
+                             stage=stage) as step_span:
                 out = rt.run_step(tokens)
                 step_span.set(wait_s=round(out["wait_s"], 6))
                 if out["loss"] is not None:
                     step_span.set(loss=out["loss"])
+                compiled = compile_log.since(compiles_before)
+                if compiled:
+                    step_span.name = "train.compile"
+                    step_span.set(**compiles.summed(compiled))
             if step_stream is not None:
                 step_stream.record(
                     step + 1, out["step_s"], data_s=out["wait_s"],
-                    loss=out["loss"], compile=step == start_step,
+                    loss=out["loss"], compile=bool(compiled),
                     ckpt_s=ckpt_stall["v"])
                 ckpt_stall["v"] = 0.0
             if prof is not None and prof.should_stop(step):
@@ -249,6 +260,7 @@ def main(argv=None) -> int:
     tracer.record("trainer.done", step=args.steps, stage=stage)
     if step_stream is not None:
         step_stream.close()
+    compile_log.release(tracer)
     tracer.close()
     print(f"stage {stage}: done at step {args.steps}", flush=True)
     return 0

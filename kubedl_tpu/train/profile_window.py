@@ -3,8 +3,11 @@
 One class owns the ``--profile-dir`` start/stop discipline so the SPMD
 trainer (train/trainer.py) and the MPMD stage trainer
 (train/pipeline_trainer.py) cannot drift: the trace covers
-``[start_step+1, start_step+1+n_steps)`` — skipping the compile step —
-and ``stop()`` is
+``[start_step+1, start_step+1+n_steps)`` — skipping the first step after
+a start, which traces and lowers the step and compiles it or reads it
+from the cache (obs/compiles.py says which, on its ``train.compile``
+record; a recompile inside the window shows as ``jax.trace``,
+``jax.lower``, ``jax.compile`` on the host plane) — and ``stop()`` is
 
   * idempotent: the flag flips BEFORE the profiler call, so the SIGTERM
     preemption path, the end-of-loop path, and the ``finally`` backstop
@@ -33,7 +36,7 @@ class ProfileWindow:
         profiler=None,
     ) -> None:
         self.profile_dir = profile_dir
-        # [start+1, start+1+n): skip the compile step
+        # [start+1, start+1+n): skip the first step after a start
         self.start_at = start_step + 1 if profile_dir else -1
         self.stop_after = self.start_at + max(n_steps, 1)
         self.tracing = False

@@ -546,6 +546,14 @@ def main(argv=None) -> int:
 
     import jax
 
+    # the engine's programs compile on first use, on whichever thread
+    # ticks it: with the trace env injected each shows in `kubedl-tpu
+    # trace` as jax.trace / jax.lower / jax.compile (obs/compiles.py,
+    # whose own tracer is the injected env's)
+    from kubedl_tpu.obs import compiles
+
+    compiles.install()
+
     from kubedl_tpu.models.serving import ServingEngine
     from kubedl_tpu.train.generate import resolve_params
 

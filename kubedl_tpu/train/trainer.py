@@ -22,6 +22,9 @@ import signal
 import sys
 import time
 from collections import deque
+from typing import Optional
+
+from kubedl_tpu.obs.compiles import summed
 
 # steps the recorder leaves on the device while it waits for an older one
 # (benchmarks/runners/train.py keeps the same two in its window)
@@ -51,38 +54,52 @@ class StepRecorder:
         self.ckpt_stall = 0.0
 
     def dispatched(self, step: int, loss, t_start: float, data_s: float,
-                   dispatch_s: float, compiled: bool, counters=None) -> None:
-        """`counters`: what the step returned beside its loss and gradient
+                   dispatch_s: float, compiled, counters=None) -> None:
+        """`compiled`: the compile log's records of what this thread
+        compiled inside the step's dispatch (obs/compiles.py `since`;
+        nothing for an ordinary step): such a step is written as
+        `train.compile` with their `fun`, times and `cache`.
+        `counters`: what the step returned beside its loss and gradient
         norm (an expert model's `moe_*` / `gmm_*`, a looped stack's
         `loop_*`, a state-space model's `ssm_*`: llama.loss_and_stats);
         they ride the step's record, read when its loss is."""
+        if compiled:
+            # the steps before it ended while the host compiled, or
+            # earlier: their records end where this dispatch began, so
+            # that the compile lies in this step's record and in no other
+            self.flush(until=t_start + data_s)
         self.pending.append((step, loss, t_start, data_s, dispatch_s, compiled,
                              counters or {}))
         while len(self.pending) > IN_FLIGHT:
             self._await_oldest()
 
-    def flush(self) -> None:
+    def flush(self, until: Optional[float] = None) -> None:
         while self.pending:
-            self._await_oldest()
+            self._await_oldest(until)
 
-    def _await_oldest(self) -> None:
+    def _await_oldest(self, until: Optional[float] = None) -> None:
+        """`until`: a perf_counter reading the step is known to have been
+        over by, where the wait itself comes later than that."""
         (step, loss, t_start, data_s, dispatch_s, compiled,
          counters) = self.pending.popleft()
         with self.tracer.span("train.wait", export=False, step=step) as wait:
             loss_v = float(loss)
         counters = {k: float(v) for k, v in counters.items()}
-        now = time.perf_counter()
-        step_s = now - max(self.last_done, t_start)
+        read_at = time.perf_counter()
+        now = read_at if until is None else min(read_at, until)
+        step_s = max(now - max(self.last_done, t_start), 0.0)
         self.last_done = now
         self.tracer.record(
             "train.compile" if compiled else "train.step",
-            duration_s=step_s, step=step, loss=loss_v,
+            duration_s=step_s, end_ts=time.time() - (read_at - now),
+            step=step, loss=loss_v,
             data_wait_s=round(data_s, 6), dispatch_s=round(dispatch_s, 6),
-            wait_s=round(wait.dur, 6), **counters)
+            wait_s=round(wait.dur, 6), **counters,
+            **(summed(compiled) if compiled else {}))
         if self.step_stream is not None:
             self.step_stream.record(
-                step, step_s, data_s=data_s, loss=loss_v, compile=compiled,
-                ckpt_s=self.ckpt_stall)
+                step, step_s, data_s=data_s, loss=loss_v,
+                compile=bool(compiled), ckpt_s=self.ckpt_stall)
             self.ckpt_stall = 0.0
 
 
@@ -176,32 +193,42 @@ def main(argv=None) -> int:
     t_main0 = time.perf_counter()
     args = parse_args(argv)
 
-    from kubedl_tpu.train import coordinator
-    from kubedl_tpu.utils.exit_codes import EXIT_TPU_PREEMPTED, EXIT_XLA_COMPILE_ERROR
-
-    info = coordinator.initialize()
-
     # flight recorder (docs/observability.md): spans to the pod's JSONL in
     # the injected KUBEDL_TRACE_DIR + a bounded per-step telemetry stream
     # with a control-dir heartbeat the operator aggregates for straggler
     # detection. Without the env both stay inert (ring-only / None) and
     # the step loop keeps its plain async-dispatch behavior. Spans opened
     # with `with` also show in a --profile-dir window, on its clock.
-    from kubedl_tpu.obs import StepStream, tracer_from_env
+    from kubedl_tpu.obs import StepStream, compiles, tracer_from_env
 
     tracer = tracer_from_env()
     step_stream = StepStream.from_env()
 
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
+    # trainer.init's children (init.imports, init.backend, init.mesh,
+    # init.state) are open where the work is; what lies between them is
+    # argument checks and the live-reshard staging's files
+    with tracer.span("init.imports"):
+        import dataclasses
 
-    from kubedl_tpu.models import llama
-    from kubedl_tpu.parallel.mesh import ShardingRules, build_mesh_from_env
-    from kubedl_tpu.parallel.train_step import make_train_step
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        import optax
 
-    import dataclasses
+        from kubedl_tpu.models import llama
+        from kubedl_tpu.parallel.mesh import (
+            ShardingRules, build_mesh, build_mesh_from_env)
+        from kubedl_tpu.parallel.train_step import make_train_step
+        from kubedl_tpu.train import coordinator, reshard_runtime
+        from kubedl_tpu.utils.exit_codes import (
+            EXIT_TPU_PREEMPTED, EXIT_XLA_COMPILE_ERROR)
+
+    # from here on every compile is seen as JAX reports it: the state's,
+    # a restore's, the step's, a recompile in the middle of the run
+    compile_log = compiles.install(tracer)
+    with tracer.span("init.backend"):
+        # the coordinator's rendezvous and the first jax.devices()
+        info = coordinator.initialize()
 
     hf_base = None
     if args.hf_model:
@@ -260,9 +287,6 @@ def main(argv=None) -> int:
     # Live-reshard plumbing (train/reshard_runtime.py): control channel +
     # staging dir, active only when the operator opted the job in
     # (spec.elastic.liveReshard -> KUBEDL_LIVE_RESHARD=1).
-    from kubedl_tpu.train import reshard_runtime
-    from kubedl_tpu.parallel.mesh import build_mesh
-
     reshard_on = info.live_reshard
     reshard_dir = info.reshard_dir
     # transport-selected control endpoint: socket plane in kube mode,
@@ -318,34 +342,35 @@ def main(argv=None) -> int:
                 reshard_runtime.clear_staging(reshard_dir)
                 staged = None
 
-    # hybrid ICIxDCN when the operator injected KUBEDL_DCN_MESH (multislice)
-    if staged is not None:
-        import math as _math
+    with tracer.span("init.mesh"):
+        # hybrid ICIxDCN when the operator injected KUBEDL_DCN_MESH (multislice)
+        if staged is not None:
+            import math as _math
 
-        n = _math.prod(staged[1].values())
-        if n <= len(jax.devices()):
-            mesh = build_mesh(staged[1], devices=jax.devices()[:n])
+            n = _math.prod(staged[1].values())
+            if n <= len(jax.devices()):
+                mesh = build_mesh(staged[1], devices=jax.devices()[:n])
+            else:
+                reshard_runtime.clear_staging(reshard_dir)
+                staged = None
+                mesh = build_mesh_from_env()
         else:
-            reshard_runtime.clear_staging(reshard_dir)
-            staged = None
-            mesh = build_mesh_from_env()
-    else:
-        devices = None
-        if reshard_on and os.environ.get("TPU_SLICE_TYPE"):
-            # size the mesh to the GRANTED slice, not to every visible
-            # device: after an elastic shrink the pod may see more
-            # devices than its slice has chips (local-executor sim), and
-            # a later grow must have headroom to reshard into
-            from kubedl_tpu.executor.tpu_topology import parse_slice_type
+            devices = None
+            if reshard_on and os.environ.get("TPU_SLICE_TYPE"):
+                # size the mesh to the GRANTED slice, not to every visible
+                # device: after an elastic shrink the pod may see more
+                # devices than its slice has chips (local-executor sim), and
+                # a later grow must have headroom to reshard into
+                from kubedl_tpu.executor.tpu_topology import parse_slice_type
 
-            try:
-                chips = parse_slice_type(
-                    os.environ["TPU_SLICE_TYPE"]).chips
-                if 0 < chips <= len(jax.devices()):
-                    devices = jax.devices()[:chips]
-            except ValueError:
-                pass
-        mesh = build_mesh_from_env(devices=devices)
+                try:
+                    chips = parse_slice_type(
+                        os.environ["TPU_SLICE_TYPE"]).chips
+                    if 0 < chips <= len(jax.devices()):
+                        devices = jax.devices()[:chips]
+                except ValueError:
+                    pass
+            mesh = build_mesh_from_env(devices=devices)
     rules = ShardingRules()
     model_name = args.hf_model or args.model
     print(f"mesh: {dict(mesh.shape)} devices={len(jax.devices())} "
@@ -358,24 +383,6 @@ def main(argv=None) -> int:
         preempted["flag"] = True
 
     signal.signal(signal.SIGTERM, on_sigterm)
-
-    params = (hf_base if hf_base is not None
-              else llama.init(config, jax.random.PRNGKey(0)))
-    if pipelined:
-        # stacked-layer layout for the stage-axis schedule; the mesh must
-        # carry the stage axis the operator validated at submit
-        if mesh.shape.get("stage", 1) != pp_stages:
-            print(f"KUBEDL_PP_STAGES={pp_stages} but the mesh stage axis "
-                  f"is {mesh.shape.get('stage', 1)} (spec.mesh.stage must "
-                  f"match spec.pipeline.stages)", file=sys.stderr)
-            return 2
-        from kubedl_tpu.parallel import pipeline as _pipeline
-
-        params = llama.stack_params(params)
-        print(f"pipeline: {pp_schedule} stages={pp_stages} "
-              f"microbatches={pp_micro} interleave={pp_interleave} "
-              f"(bubble {_pipeline.bubble_fraction(pp_micro, pp_stages, pp_interleave):.3f})",
-              flush=True)
 
     def loss_on(a_mesh):
         if pipelined:
@@ -407,119 +414,138 @@ def main(argv=None) -> int:
     tx = optax.adamw(lr, weight_decay=0.01)
     if args.grad_clip > 0:
         tx = optax.chain(optax.clip_by_global_norm(args.grad_clip), tx)
-    try:
-        if args.lora_rank > 0:
-            # adapter-only training: gradients + optimizer state cover the
-            # low-rank deltas; the frozen base rides sharded through the
-            # step (models/lora.py)
-            from kubedl_tpu.models import lora as lora_mod
+    with tracer.span("init.state"):
+        params = (hf_base if hf_base is not None
+                  else llama.init(config, jax.random.PRNGKey(0)))
+        if pipelined:
+            # stacked-layer layout for the stage-axis schedule; the mesh must
+            # carry the stage axis the operator validated at submit
+            if mesh.shape.get("stage", 1) != pp_stages:
+                print(f"KUBEDL_PP_STAGES={pp_stages} but the mesh stage axis "
+                      f"is {mesh.shape.get('stage', 1)} (spec.mesh.stage must "
+                      f"match spec.pipeline.stages)", file=sys.stderr)
+                return 2
+            from kubedl_tpu.parallel import pipeline as _pipeline
 
-            adapters0, init_state, train_step = lora_mod.make_lora_step(
-                params, config, tx, mesh, rules=rules, rank=args.lora_rank,
-                alpha=args.lora_alpha, accum_steps=args.accum_steps,
-            )
-            state = init_state(adapters0)
-            n_ad = lora_mod.adapter_count(adapters0)
-            print(f"lora: rank {args.lora_rank}, {n_ad} adapter params "
-                  f"({100.0 * n_ad / llama.param_count(params):.2f}% of base)",
+            params = llama.stack_params(params)
+            print(f"pipeline: {pp_schedule} stages={pp_stages} "
+                  f"microbatches={pp_micro} interleave={pp_interleave} "
+                  f"(bubble {_pipeline.bubble_fraction(pp_micro, pp_stages, pp_interleave):.3f})",
                   flush=True)
-            if args.eval_every:
-                print("note: --eval-every is skipped under --lora-rank "
-                      "(restore with generate/serve --lora-checkpoint-path "
-                      "to evaluate the merged model)", flush=True)
-                args.eval_every = 0
-        else:
-            def build_step(a_mesh):
-                """Mesh-dependent compute, rebuilt after a live reshard."""
-                spec_tree = (llama.param_specs_pp(config, rules) if pipelined
-                             else llama.param_specs(config, rules))
-                if pipelined:
-                    step_loss = loss_on(a_mesh)
-                else:
-                    def step_loss(params, batch):
-                        # (loss, an expert model's, a looped stack's or
-                        # a state-space model's counters, which ride the
-                        # train.step record: {} for a dense model run once)
-                        return llama.loss_and_stats(
-                            params, batch, config, mesh=a_mesh, rules=rules)
-                return make_train_step(
-                    step_loss, tx, a_mesh, spec_tree,
-                    rules.spec("batch", None), rules,
-                    accum_steps=args.accum_steps, has_aux=not pipelined,
+
+        try:
+            if args.lora_rank > 0:
+                # adapter-only training: gradients + optimizer state cover the
+                # low-rank deltas; the frozen base rides sharded through the
+                # step (models/lora.py)
+                from kubedl_tpu.models import lora as lora_mod
+
+                adapters0, init_state, train_step = lora_mod.make_lora_step(
+                    params, config, tx, mesh, rules=rules, rank=args.lora_rank,
+                    alpha=args.lora_alpha, accum_steps=args.accum_steps,
                 )
-
-            init_state, train_step = build_step(mesh)
-            if staged is not None:
-                # staged-restart lane: the previous incarnation quiesced
-                # and streamed its shard intersections here — rebuild the
-                # resharded state instead of restoring a checkpoint. Any
-                # gap falls back closed to the Orbax path below.
-                try:
-                    template = init_state(params)
-                    state = reshard_runtime.state_from_staging(
-                        staged[2], template)
-                    del template
-                    # NOT cleared here: peers may still be assembling from
-                    # the same staging (clearing would fork the gang onto
-                    # divergent restore points). Replay is safe: a stale
-                    # staging is rejected by the granted-topology and
-                    # newer-checkpoint guards above, and a valid replay IS
-                    # the newest state.
-                    print(f"restored live-reshard staging at step "
-                          f"{staged[0]} (mesh {staged[1]})", flush=True)
-                except Exception as e:  # noqa: BLE001 — fallback closed
-                    print(f"staging unusable ({e}); falling back to "
-                          f"checkpoint restore", file=sys.stderr)
-                    reshard_runtime.clear_staging(reshard_dir)
-                    staged = None
-                    state = init_state(params)
+                state = init_state(adapters0)
+                n_ad = lora_mod.adapter_count(adapters0)
+                print(f"lora: rank {args.lora_rank}, {n_ad} adapter params "
+                      f"({100.0 * n_ad / llama.param_count(params):.2f}% of base)",
+                      flush=True)
+                if args.eval_every:
+                    print("note: --eval-every is skipped under --lora-rank "
+                          "(restore with generate/serve --lora-checkpoint-path "
+                          "to evaluate the merged model)", flush=True)
+                    args.eval_every = 0
             else:
-                state = init_state(params)
-        # the sharded copies live on the mesh now; a 7B HF import would
-        # otherwise pin ~14 GB of dead host arrays for the whole run
-        del params
-        hf_base = None
-    except Exception as e:
-        if "RESOURCE_EXHAUSTED" in str(e) or "XlaRuntimeError" in type(e).__name__:
-            print(f"compile/alloc failure: {e}", file=sys.stderr)
-            return EXIT_XLA_COMPILE_ERROR
-        raise
+                def build_step(a_mesh):
+                    """Mesh-dependent compute, rebuilt after a live reshard."""
+                    spec_tree = (llama.param_specs_pp(config, rules) if pipelined
+                                 else llama.param_specs(config, rules))
+                    if pipelined:
+                        step_loss = loss_on(a_mesh)
+                    else:
+                        def step_loss(params, batch):
+                            # (loss, an expert model's, a looped stack's or
+                            # a state-space model's counters, which ride the
+                            # train.step record: {} for a dense model run once)
+                            return llama.loss_and_stats(
+                                params, batch, config, mesh=a_mesh, rules=rules)
+                    return make_train_step(
+                        step_loss, tx, a_mesh, spec_tree,
+                        rules.spec("batch", None), rules,
+                        accum_steps=args.accum_steps, has_aux=not pipelined,
+                    )
 
-    # where the state landed: a sharded job's bytes are spread over its
-    # devices, not sitting on the first (the CPU backend reports none)
-    if jax.local_devices()[0].memory_stats() is not None:
-        # the unsharded init copy is freed only once the init program,
-        # dispatched asynchronously, has ended
-        jax.block_until_ready(state)
-        print("device memory after init: " + " ".join(
-            f"{d.id}={d.memory_stats()['bytes_in_use'] / 2**20:.0f}MiB"
-            for d in mesh.local_devices), flush=True)
+                init_state, train_step = build_step(mesh)
+                if staged is not None:
+                    # staged-restart lane: the previous incarnation quiesced
+                    # and streamed its shard intersections here — rebuild the
+                    # resharded state instead of restoring a checkpoint. Any
+                    # gap falls back closed to the Orbax path below.
+                    try:
+                        template = init_state(params)
+                        state = reshard_runtime.state_from_staging(
+                            staged[2], template)
+                        del template
+                        # NOT cleared here: peers may still be assembling from
+                        # the same staging (clearing would fork the gang onto
+                        # divergent restore points). Replay is safe: a stale
+                        # staging is rejected by the granted-topology and
+                        # newer-checkpoint guards above, and a valid replay IS
+                        # the newest state.
+                        print(f"restored live-reshard staging at step "
+                              f"{staged[0]} (mesh {staged[1]})", flush=True)
+                    except Exception as e:  # noqa: BLE001 — fallback closed
+                        print(f"staging unusable ({e}); falling back to "
+                              f"checkpoint restore", file=sys.stderr)
+                        reshard_runtime.clear_staging(reshard_dir)
+                        staged = None
+                        state = init_state(params)
+                else:
+                    state = init_state(params)
+            # the sharded copies live on the mesh now; a 7B HF import would
+            # otherwise pin ~14 GB of dead host arrays for the whole run
+            del params
+            hf_base = None
+        except Exception as e:
+            if "RESOURCE_EXHAUSTED" in str(e) or "XlaRuntimeError" in type(e).__name__:
+                print(f"compile/alloc failure: {e}", file=sys.stderr)
+                return EXIT_XLA_COMPILE_ERROR
+            raise
 
-    # checkpointing (Orbax)
-    mngr = None
-    start_step = staged[0] if staged is not None else 0
-    if args.checkpoint_path:
-        import orbax.checkpoint as ocp
+        # where the state landed: a sharded job's bytes are spread over its
+        # devices, not sitting on the first (the CPU backend reports none)
+        if jax.local_devices()[0].memory_stats() is not None:
+            # the unsharded init copy is freed only once the init program,
+            # dispatched asynchronously, has ended
+            jax.block_until_ready(state)
+            print("device memory after init: " + " ".join(
+                f"{d.id}={d.memory_stats()['bytes_in_use'] / 2**20:.0f}MiB"
+                for d in mesh.local_devices), flush=True)
 
-        options = ocp.CheckpointManagerOptions(
-            max_to_keep=args.checkpoint_keep, create=True
-        )
-        mngr = ocp.CheckpointManager(args.checkpoint_path, options=options)
-        latest = mngr.latest_step()
-        if staged is not None:
-            pass  # live-reshard staging beats restore (start_step set above)
-        elif latest is not None and os.environ.get("KUBEDL_CHECKPOINT_RESTORE", "1") == "1":
-            # Restore straight into the SHARDED state: the live arrays act
-            # as the abstract target, so each leaf comes back with its
-            # param_specs sharding instead of landing replicated on one
-            # device (mandatory for models that only fit sharded).
-            with tracer.span("ckpt.restore") as restore_span:
-                abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, state)
-                state = mngr.restore(
-                    latest, args=ocp.args.StandardRestore(abstract))
-                start_step = int(state.step)
-                restore_span.set(step=start_step)
-            print(f"restored checkpoint at step {start_step}", flush=True)
+        # checkpointing (Orbax)
+        mngr = None
+        start_step = staged[0] if staged is not None else 0
+        if args.checkpoint_path:
+            import orbax.checkpoint as ocp
+
+            options = ocp.CheckpointManagerOptions(
+                max_to_keep=args.checkpoint_keep, create=True
+            )
+            mngr = ocp.CheckpointManager(args.checkpoint_path, options=options)
+            latest = mngr.latest_step()
+            if staged is not None:
+                pass  # live-reshard staging beats restore (start_step set above)
+            elif latest is not None and os.environ.get("KUBEDL_CHECKPOINT_RESTORE", "1") == "1":
+                # Restore straight into the SHARDED state: the live arrays act
+                # as the abstract target, so each leaf comes back with its
+                # param_specs sharding instead of landing replicated on one
+                # device (mandatory for models that only fit sharded).
+                with tracer.span("ckpt.restore") as restore_span:
+                    abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, state)
+                    state = mngr.restore(
+                        latest, args=ocp.args.StandardRestore(abstract))
+                    start_step = int(state.step)
+                    restore_span.set(step=start_step)
+                print(f"restored checkpoint at step {start_step}", flush=True)
 
     # interval saves are ASYNC: orbax's save() blocks only for the
     # device->host copy (so the next step may donate the state buffers
@@ -752,7 +778,10 @@ def main(argv=None) -> int:
               f"({args.eval_batches} {tag} batches)", flush=True)
 
     # profiler window: [start+1, start+1+profile_steps) — skips the
-    # compile step. Shared with the MPMD stage trainer
+    # first step, whose dispatch holds the step's compile (a cache read on
+    # a resumed pod: its train.compile record says which). A later
+    # recompile inside the window shows there as jax.trace / jax.lower /
+    # jax.compile. Shared with the MPMD stage trainer
     # (train/profile_window.py): stop() is idempotent and runs from the
     # preemption path AND the finally backstop, so SIGTERM (or a raise)
     # DURING the traced window still lands the trace on disk.
@@ -766,8 +795,10 @@ def main(argv=None) -> int:
     # calls under train.data / train.dispatch spans and one
     # StepTraceAnnotation, which a profile shows beside the device's
     # operations; the recorder reads a step's loss two steps later
-    # (StepRecorder), so recording does not drain the device.
-    compile_pending = {"v": True}  # first step after (re)build compiles
+    # (StepRecorder), so recording does not drain the device. Whether a
+    # step compiled is read off the compile log: this thread's count of
+    # compiled functions across the dispatch, so a save's or a
+    # transport's thread that compiles a copy turns no step into one.
 
     def settle(loss_arr) -> None:
         """Every step dispatched so far has ended, and the recorder has
@@ -796,16 +827,16 @@ def main(argv=None) -> int:
                     t_step0 = time.perf_counter()
                     with tracer.span("train.data", export=False) as data_span:
                         batch = next_batch(step)
+                    compiles_before = compile_log.count(thread=True)
                     with tracer.span("train.dispatch",
                                      export=False) as dispatch_span:
                         state, metrics = train_step(state, batch)
                     if recorder is not None:
                         recorder.dispatched(
                             step + 1, metrics["loss"], t_step0, data_span.dur,
-                            dispatch_span.dur, compile_pending["v"],
+                            dispatch_span.dur, compile_log.since(compiles_before),
                             {k: v for k, v in metrics.items()
                              if k.startswith(("moe_", "gmm_", "loop_", "ssm_"))})
-                        compile_pending["v"] = False
             if prof is not None and prof.should_stop(step):
                 settle(metrics["loss"])
                 prof.stop()
@@ -829,8 +860,6 @@ def main(argv=None) -> int:
                     if cmsg.get("type") == "RESIZE":
                         settle(metrics["loss"])
                         handle_resize(cmsg, step + 1)
-                        # the rebuilt step compiles on the next dispatch
-                        compile_pending["v"] = True
                     else:
                         ctl.reply(cmsg, outcome="failed",
                                   error=f"unknown control message "
@@ -868,6 +897,7 @@ def main(argv=None) -> int:
                   wall_s=round(total, 3))
     if step_stream is not None:
         step_stream.close()
+    compile_log.release(tracer)
     tracer.close()
     return 0
 

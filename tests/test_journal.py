@@ -566,6 +566,18 @@ def test_history_outlives_job_ttl_and_trace_dir(tmp_path):
         snap = op.journal.snapshot()
         assert snap["appends_total"] >= 3  # grant + 2 pods_start
 
+        # a TTL fires seconds after the job ended, when the persist
+        # controller has long written the terminal status; a delete in the
+        # same instant as the condition would close the row as Stopped
+        deadline = time.monotonic() + 20
+        while time.monotonic() < deadline:
+            rec = op.history_store.get("default", "ttl-job")
+            if rec and (rec["job_record"] or {}).get("status") == "Succeeded":
+                break
+            time.sleep(0.05)
+        else:
+            pytest.fail("the job's terminal status was never persisted")
+
         # TTL fires: the CRD disappears, then the trace dir is GC'd
         op.store.delete(TEST_KIND, "default", "ttl-job")
         deadline = time.monotonic() + 20

@@ -394,7 +394,7 @@ def test_profile_window_covers_post_compile_steps_and_stop_idempotent():
 
     fp = _FakeProfiler()
     w = ProfileWindow("/tmp/prof", start_step=10, n_steps=2, profiler=fp)
-    w.maybe_start(10)          # compile step: not traced
+    w.maybe_start(10)          # the first step (compile or cache read): not traced
     assert fp.starts == 0
     w.maybe_start(11)
     assert fp.starts == 1 and w.tracing
@@ -425,6 +425,52 @@ def test_pipeline_trainer_has_profiler_flags():
 
     args = parse_args(["--profile-dir", "/tmp/p", "--profile-steps", "3"])
     assert args.profile_dir == "/tmp/p" and args.profile_steps == 3
+
+
+def test_pipeline_stage_says_which_steps_compiled_and_where_init_went(
+        tmp_path, monkeypatch, capsys):
+    """The MPMD stage trainer, one stage in this process, under the trace
+    env: the step in which the stage's programs compiled is train.compile
+    with what compiled (asked of the compile log, not of the step's
+    index), the rest pipeline.step, and trainer.init has its children."""
+    trace_dir = str(tmp_path / "trace")
+    monkeypatch.setenv("KUBEDL_TRACE_DIR", trace_dir)
+    monkeypatch.setenv("KUBEDL_TRACE_ID", "1" * 32)
+    monkeypatch.setenv("POD_NAME", "pp-stage-0")
+    monkeypatch.setenv("KUBEDL_PP_STAGES", "1")
+    monkeypatch.setenv("KUBEDL_PP_STAGE", "0")
+    monkeypatch.setenv("KUBEDL_PP_MICROBATCHES", "2")
+    monkeypatch.setenv("KUBEDL_PP_BOUNDARY_DIR", str(tmp_path / "pp"))
+    monkeypatch.delenv("KUBEDL_CONTROL_DIR", raising=False)
+    from kubedl_tpu.train import pipeline_trainer
+
+    assert pipeline_trainer.main([
+        "--model", "tiny", "--batch", "8", "--seq-len", "17", "--steps", "3"]) == 0
+    spans = load_spans(trace_dir)
+    steps = [s for s in spans if s["name"] in ("train.compile", "pipeline.step")]
+    assert [(s["name"], s["attrs"]["step"]) for s in steps] == [
+        ("train.compile", 1), ("pipeline.step", 2), ("pipeline.step", 3)]
+    first = steps[0]["attrs"]
+    assert "loss_body" in first["fun"].split("+") and first["cache"] in ("hit", "miss", "off")
+    assert first["trace_s"] > 0 and first["lower_s"] > 0 and first["executable_s"] > 0
+    assert first["trace_s"] + first["lower_s"] + first["executable_s"] <= steps[0]["dur"]
+    assert "fun" not in steps[1]["attrs"]
+    # the stage's programs, each with its three spans inside that step
+    for name in ("jax.trace", "jax.lower", "jax.compile"):
+        (mine,) = [s for s in spans if s["name"] == name
+                   and s["attrs"].get("fun") == "loss_body"]
+        assert steps[0]["ts"] <= mine["ts"]
+        assert mine["ts"] + mine["dur"] <= steps[0]["ts"] + steps[0]["dur"] + 1e-3
+    init = next(s for s in spans if s["name"] == "trainer.init")
+    children = [s for s in spans if s["name"].startswith("init.")]
+    assert [s["name"] for s in children] == ["init.imports", "init.backend", "init.state"]
+    for s in children:
+        assert init["ts"] - 1e-2 <= s["ts"]
+        assert s["ts"] + s["dur"] <= init["ts"] + init["dur"] + 1e-2
+    recs = load_step_records(os.path.join(trace_dir, "pp-stage-0.steps.jsonl"))
+    assert [r["compile"] for r in recs] == [True, False, False]
+    gp = goodput(spans)
+    assert gp["buckets"]["init_compile"] > 0 and gp["buckets"]["steps"] > 0
 
 
 # ---------------------------------------------------------------------------

@@ -318,6 +318,36 @@ def test_recorder_reads_a_loss_two_dispatches_late_and_save_flushes(
         assert a["ts"] + a["dur"] <= b["ts"] + b["dur"]
 
 
+def test_a_compile_lies_in_its_own_steps_record_and_in_no_other():
+    """Two steps are in flight when step 3's dispatch stalls on a compile:
+    their records end where that dispatch began, and step 3's holds it."""
+    import time
+
+    from kubedl_tpu.train.trainer import StepRecorder
+
+    tracer, events = Tracer(service="t"), []
+    rec = StepRecorder(tracer, None)
+    what = [{"fun": "train_step", "trace_s": 0.02, "lower_s": 0.01,
+             "executable_s": 0.03, "cache": "miss"}]
+    starts = {}
+    for step in (1, 2, 3, 4, 5):
+        starts[step] = t0 = time.perf_counter()
+        if step == 3:
+            time.sleep(0.06)  # the compile, inside the dispatch
+        rec.dispatched(step, _FakeLoss(step, events), t0, 0.0,
+                       time.perf_counter() - t0, what if step == 3 else [])
+    rec.flush()
+    spans = {s["attrs"]["step"]: s for s in tracer.spans()
+             if s["name"] in ("train.compile", "train.step")}
+    assert [spans[n]["name"] for n in (1, 2, 3, 4, 5)] == [
+        "train.step", "train.step", "train.compile", "train.step", "train.step"]
+    assert spans[3]["attrs"]["fun"] == "train_step" and spans[3]["attrs"]["cache"] == "miss"
+    assert spans[3]["dur"] >= 0.06 > spans[1]["dur"] + spans[2]["dur"]
+    # the records still tile: each ends where the next begins
+    for a, b in zip((1, 2, 3, 4), (2, 3, 4, 5)):
+        assert spans[a]["ts"] + spans[a]["dur"] <= spans[b]["ts"] + 1e-4
+
+
 def test_plain_loop_reads_no_loss_and_makes_no_span(tmp_path, monkeypatch):
     """Neither trace env nor profile window: dispatch only."""
     monkeypatch.setenv("KUBEDL_MESH", "data=-1")
@@ -361,4 +391,161 @@ def test_preemption_flushes_the_pending_steps(tmp_path, monkeypatch):
     assert events == [("dispatch", 1), ("dispatch", 2), ("dispatch", 3),
                       ("read", 1), ("read", 2), ("read", 3)]
     names = [s["name"] for s in load_spans(trace_dir)]
-    assert names.count("train.step") == 2 and "trainer.preempted" in names
+    # the fake step compiles nothing, and the compile log says so: no
+    # step is train.compile for being the first
+    assert names.count("train.step") == 3 and "train.compile" not in names
+    assert "trainer.preempted" in names
+
+
+# -- (e) a compile is what JAX says it was -----------------------------------
+
+
+class _OddBatchLoader:
+    """The trainer's token loader, handing one batch of another length:
+    the step that takes it traces, lowers and compiles anew."""
+
+    ODD_AT = 3  # the fourth batch, inside the profile window [1, 4)
+    n_windows, is_native = 1 << 20, False
+
+    def __init__(self, paths, batch, seq_len, seed=0, n_threads=0):
+        self.batch, self.seq_len = batch, seq_len
+
+    def batch_at(self, i):
+        import numpy as np
+
+        seq = self.seq_len + (4 if i == self.ODD_AT else 0)
+        return np.random.default_rng(i).integers(
+            0, 256, (self.batch, seq), dtype=np.int32)
+
+
+@pytest.fixture(scope="module")
+def recompile_run(tmp_path_factory):
+    """One tiny trainer run under the trace env and a profile window,
+    its fourth step made to trace anew."""
+    import io
+    from contextlib import redirect_stdout
+
+    from kubedl_tpu.native import loader
+    from kubedl_tpu.train import trainer
+
+    tmp = tmp_path_factory.mktemp("recompile")
+    shard = tmp / "shard-0.bin"
+    shard.write_bytes(b"\0" * 64)
+    steps = 6
+    with pytest.MonkeyPatch.context() as mp:
+        trace_dir = _trace_env(mp, tmp)
+        mp.setattr(loader, "TokenLoader", _OddBatchLoader)
+        out = io.StringIO()
+        with redirect_stdout(out):
+            rc = trainer.main([
+                "--model", "tiny", "--batch", "8", "--seq-len", "17",
+                "--steps", str(steps), "--log-every", "1000",
+                "--data-path", str(shard),
+                "--profile-dir", str(tmp / "prof"), "--profile-steps", "3"])
+    assert rc == 0, out.getvalue()
+    return {"trace_dir": trace_dir, "profile_dir": str(tmp / "prof"),
+            "spans": load_spans(trace_dir), "steps": steps, "tmp": tmp}
+
+
+def test_train_compile_is_the_steps_that_compiled_and_no_other(recompile_run):
+    spans = recompile_run["spans"]
+    step_spans = [s for s in spans if s["name"] in ("train.compile", "train.step")]
+    odd = _OddBatchLoader.ODD_AT + 1
+    assert [(s["name"], s["attrs"]["step"]) for s in step_spans] == [
+        ("train.compile" if n in (1, odd) else "train.step", n)
+        for n in range(1, recompile_run["steps"] + 1)]
+    for s in step_spans:
+        if s["name"] == "train.compile":
+            a = s["attrs"]
+            assert a["fun"] == "train_step" and a["cache"] in ("hit", "miss", "off")
+            assert a["trace_s"] > 0 and a["lower_s"] > 0 and a["executable_s"] > 0
+            # the dispatch held the compile, and the record says how much of it
+            assert a["trace_s"] + a["lower_s"] + a["executable_s"] <= a["dispatch_s"]
+        else:
+            assert not {"fun", "trace_s", "cache"} & set(s["attrs"])
+    recs = load_step_records(os.path.join(
+        recompile_run["trace_dir"], "tn-worker-0.steps.jsonl"))
+    assert [r["compile"] for r in recs] == [n in (1, odd) for n in range(1, 7)]
+    assert recs[-1]["compiles"] == 2  # what kubedl_compile_events_total sums
+
+
+def test_each_compile_of_the_step_has_its_three_spans_inside_its_record(recompile_run):
+    spans = recompile_run["spans"]
+    compiled = [s for s in spans if s["name"] == "train.compile"]
+    for name in ("jax.trace", "jax.lower", "jax.compile"):
+        mine = [s for s in spans if s["name"] == name
+                and s["attrs"].get("fun") == "train_step"]
+        assert len(mine) == 2
+        for rec, span in zip(compiled, mine):
+            assert rec["ts"] - 1e-3 <= span["ts"]
+            assert span["ts"] + span["dur"] <= rec["ts"] + rec["dur"] + 1e-3
+            assert span["trace_id"] == rec["trace_id"]
+    first = next(s for s in spans if s["name"] == "jax.trace"
+                 and s["attrs"].get("fun") == "train_step")
+    assert first["attrs"]["nested"] and len(first["attrs"]["nested"]) <= 5
+    # the step's record sums what compiled inside its dispatch: the step,
+    # and whatever small eager primitive a new shape brought with it
+    last = [s for s in spans if s["name"] == "jax.compile"
+            and s["attrs"].get("fun") == "train_step"][-1]
+    whole = compiled[-1]["attrs"]["executable_s"]
+    assert 0.5 * whole <= last["dur"] <= whole + 1e-5
+
+
+def test_trainer_init_has_its_children_where_the_work_is(recompile_run):
+    spans = recompile_run["spans"]
+    init = next(s for s in spans if s["name"] == "trainer.init")
+    children = [s for s in spans if s["name"].startswith("init.")]
+    assert [s["name"] for s in children] == [
+        "init.imports", "init.backend", "init.mesh", "init.state"]
+    for a, b in zip(children, children[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1e-3
+    for s in children:
+        assert init["ts"] - 1e-2 <= s["ts"]
+        assert s["ts"] + s["dur"] <= init["ts"] + init["dur"] + 1e-2
+    assert sum(s["dur"] for s in children) <= init["dur"] + 1e-2
+    # the state's own compiles lie inside init.state
+    state = children[-1]
+    inside = [s for s in spans if s["name"] == "jax.compile"
+              and state["ts"] <= s["ts"] <= state["ts"] + state["dur"]]
+    assert inside and "train_step" not in {s["attrs"]["fun"] for s in inside}
+
+
+def test_profile_window_names_the_gap_under_a_recompile(recompile_run):
+    """Read as the benchmark reads a trace (benchmarks/trace.py:load): the
+    three phases lie on the host plane inside the dispatch that held them."""
+    from benchmarks import trace as tr
+
+    trace = tr.load(tr.find_xplane(recompile_run["profile_dir"]))
+    events = [ev for plane in trace["planes"] if plane["name"] == "/host:CPU"
+              for line in plane["lines"] for ev in line["events"]]
+    by_name = {}
+    for name, start, dur in events:
+        by_name.setdefault(name, []).append((start, start + dur))
+    assert {"jax.trace", "jax.lower", "jax.compile", "train.dispatch"} <= set(by_name)
+    dispatches = by_name["train.dispatch"]
+    for name in ("jax.trace", "jax.lower", "jax.compile"):
+        # the longest of a name is the step's (eager primitives are annotations too)
+        start, end = max(by_name[name], key=lambda iv: iv[1] - iv[0])
+        assert any(d0 <= start and end <= d1 for d0, d1 in dispatches), name
+
+
+def test_trace_cli_prints_a_row_a_compile_and_the_inner_functions(recompile_run, capsys):
+    from kubedl_tpu import cli
+
+    assert cli.main(["trace", "tn", "--dir", recompile_run["trace_dir"]]) == 0
+    out = capsys.readouterr().out
+    table = out[out.index("compiles (as jax.monitoring reported them):"):]
+    rows = [line.split() for line in table.splitlines() if line.startswith("train_step")]
+    assert [row[-1] for row in rows] == ["1", str(_OddBatchLoader.ODD_AT + 1)]
+    for row in rows:
+        assert row[5] in ("hit", "miss", "off") and float(row[4]) > 0
+    assert "inner functions of train_step's trace" in table
+    assert out.index("goodput:") < out.index("compiles (as")
+
+
+def test_compile_pending_is_gone():
+    for rel in ("train/trainer.py", "train/pipeline_trainer.py"):
+        with open(os.path.join(ROOT, "kubedl_tpu", rel)) as f:
+            text = f.read()
+        assert "compile_pending" not in text
+        assert "step == start_step" not in text
