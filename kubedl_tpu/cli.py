@@ -166,6 +166,9 @@ def _span_detail(attrs) -> str:
         detail.append(f"dt={attrs['ssm_dt_mean']:.4f}")
         if "ssm_kernel_chunks" in attrs:  # a record from before the kernels has none
             detail.append(f"kernel_chunks={int(attrs['ssm_kernel_chunks'])}")
+        if "ssm_conv_kernel_layers" in attrs:
+            detail.append(
+                f"conv_kernel_layers={int(attrs['ssm_conv_kernel_layers'])}")
     return " ".join(detail)
 
 

@@ -781,7 +781,8 @@ def _short_conv_block(x, layer, config: LlamaConfig):
 def _ssm_block(x, layer, config: LlamaConfig, mesh, rules):
     """A state-space layer's mixer with its norm and residual, and the
     layer's counters (models/ssm.py ssm_mixer). The mesh is for the
-    scan's kernels, which ride a shard_map over `batch` as flash does."""
+    scan's and the convolution's kernels, which ride a shard_map over
+    `batch` as flash does."""
     h = rms_norm(x, layer["ssm_norm"], config.rms_eps, config.norm_offset)
     out, stats = ssm_mixer(h, layer, config.ssm_heads, config.ssm_head_dim,
                            config.ssm_state, config.ssm_chunk, config.rms_eps,
@@ -1149,9 +1150,11 @@ def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
     the held experts; for a looped stack (_looped_loss): loop_passes,
     loop_layer_applications, loop_exit_mass_<t>, loop_ce_<t>,
     loop_exit_entropy; for a model with state-space layers (models/ssm.py):
-    ssm_layers, ssm_chunks, ssm_kernel_chunks (the chunks that went
-    through ops/ssm_scan.py's kernels) and, averaged over those layers,
-    ssm_dt_mean and ssm_state_carry (docs/observability.md)."""
+    ssm_layers, ssm_conv_kernel_layers (the layers whose convolution ran
+    as ops/causal_conv.py's kernels), ssm_chunks, ssm_kernel_chunks (the
+    chunks that went through ops/ssm_scan.py's kernels) and, averaged
+    over those layers, ssm_dt_mean and ssm_state_carry
+    (docs/observability.md)."""
     rules = rules or ShardingRules()
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     chunked = config.ce_chunks > 1

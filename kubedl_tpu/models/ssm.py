@@ -18,16 +18,28 @@ decay-weighted C B^T product against x, quadratic in the chunk and all
 matmuls; a chunk's end state from B, x and the decays; the states passed
 from chunk to chunk in float32; the carried state's part of each output.
 Every decay is a cumulative sum in float32, matmul operands are in the
-model's dtype. On a TPU, at shapes of whole tiles (a chunk and a state
-that are multiples of 128, heads in blocks of 8), all four steps run as
-ops/ssm_scan.py's two Pallas kernels, one call a layer and pass:
-`ssm_scan_fwd`, which walks a sequence's chunks in order with the scores
-and the state in VMEM and writes y once, token before head, and a
-hand-written backward `ssm_scan_bwd`, which walks them in reverse; XLA
-keeps the decays' cumulative sums. Every other shape, the CPU and a mesh
-that shards the inner channels take the same steps as XLA operations,
-whose backward is autodiff. Both under the layer's remat.
-`ssm_kernel_chunks` counts the chunks the kernels took.
+model's dtype.
+
+Which steps run as which kernels, on a TPU under no mesh that shards the
+inner channels (both choices from shapes, backend and mesh alone; every
+other shape, the CPU and such a mesh take the same steps as XLA
+operations, whose backward is autodiff; all under the layer's remat):
+
+- the second and third lines above, where the sequence is whole 128-token
+  blocks and the inner width and the state are whole 128-lane blocks
+  (`conv_takes_kernel`): ops/causal_conv.py's `ssm_conv_fwd`, which reads
+  xBC once where it lies in the in projection's output and writes x, B
+  and C once, and a hand-written backward `ssm_conv_bwd`, which walks a
+  sequence's tokens in reverse and sums the taps' and the bias's
+  gradients in float32; the projections, z and dt stay XLA's.
+  `ssm_conv_kernel_layers` counts the layers that took them;
+- the recurrence, at shapes of whole tiles (a chunk and a state that are
+  multiples of 128, heads in blocks of 8; `scan_takes_kernel`): all four
+  steps as ops/ssm_scan.py's `ssm_scan_fwd`, which walks a sequence's
+  chunks in order with the scores and the state in VMEM and writes y
+  once, token before head, and a hand-written backward `ssm_scan_bwd`,
+  which walks them in reverse; XLA keeps the decays' cumulative sums.
+  `ssm_kernel_chunks` counts the chunks they took.
 
 What a decoder would carry from step to step is S (heads x head size x
 state size a layer) and the convolution's last K - 1 inputs, not keys and
@@ -43,10 +55,11 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from kubedl_tpu.models.quant import matmul as _mm
 from kubedl_tpu.models.short_conv import causal_taps
-from kubedl_tpu.ops import interpret, ssm_scan
+from kubedl_tpu.ops import causal_conv, interpret, ssm_scan
 from kubedl_tpu.parallel.mesh import ShardingRules
 
 
@@ -183,22 +196,63 @@ def chunked_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b_: jax.Array,
     return y.reshape(bsz, nc * q, h, p)[:, :t], through
 
 
+def conv_takes_kernel(seq: int, d_inner: int, state: int, taps: int,
+                      mesh=None) -> bool:
+    """Whether the convolution runs as the Pallas kernels
+    (ops/causal_conv.py): on a TPU, where xBC and its three parts are
+    whole 128-lane blocks of the in projection's output and the sequence
+    whole 128-token blocks, and under no mesh that shards the inner
+    channels (over `batch` it rides a shard_map, as the scan does)."""
+    if interpret() or not causal_conv.supports(
+            seq, d_inner, (d_inner, state, state), taps):
+        return False
+    return mesh is None or mesh.shape.get("tensor", 1) == 1
+
+
+def split_conv(h: jax.Array, w: jax.Array, bias: jax.Array, d_inner: int,
+               state: int, mesh=None, rules: Optional[ShardingRules] = None
+               ) -> Tuple[Tuple[jax.Array, ...], bool]:
+    """(z, x, B, C, dt) of the in projection's output h [b, t, 2 d_inner +
+    2 state + heads]: [z, xBC, dt] = split(h), [x, B, C] =
+    split(silu(causal_taps(xBC, w) + bias)), in h's dtype; and whether the
+    kernels made them.
+
+    One step in two forms, chosen by `conv_takes_kernel` from the shapes
+    and the mesh: ops/causal_conv.py's kernels, which read xBC in place
+    and write x, B and C apart, or the XLA operations below."""
+    widths = (d_inner, state, state)
+    if conv_takes_kernel(h.shape[1], d_inner, state, w.shape[1], mesh):
+        conv = functools.partial(causal_conv.split_conv, offset=d_inner,
+                                 widths=widths)
+        if mesh is not None and mesh.size > 1:
+            # each device its own sequences; the taps' and the bias's
+            # gradients are summed over the devices by the map's transpose
+            rows = (rules or ShardingRules()).spec("batch", None, None)
+            conv = jax.shard_map(conv, mesh=mesh, in_specs=(rows, P(), P()),
+                                 out_specs=(rows,) * 5, check_vma=False)
+        with jax.named_scope("ssm_conv"):
+            return conv(h, w, bias), True
+    z, xbc, dt = jnp.split(h, [d_inner, 2 * d_inner + 2 * state], axis=-1)
+    with jax.named_scope("ssm_conv"):
+        xbc = jax.nn.silu(
+            causal_taps(xbc, w).astype(jnp.float32) + bias).astype(h.dtype)
+    x, b_, c_ = jnp.split(xbc, [d_inner, d_inner + state], axis=-1)
+    return (z, x, b_, c_, dt), False
+
+
 def ssm_mixer(u: jax.Array, layer: Dict, heads: int, head_dim: int,
               state: int, chunk: int, eps: float, mesh=None,
               rules: Optional[ShardingRules] = None) -> Tuple[jax.Array, Dict]:
     """The mixer's output for normed input u [b, t, d], and the layer's
-    counters: chunks scanned, how many of them went through the scan's
-    kernels, the mean step size after the softplus, the mean share of a
-    chunk's incoming state that leaves it."""
+    counters: whether the convolution ran as its kernels, chunks scanned,
+    how many of them went through the scan's kernels, the mean step size
+    after the softplus, the mean share of a chunk's incoming state that
+    leaves it."""
     bsz, t, _ = u.shape
     d_inner, f32 = heads * head_dim, jnp.float32
-    z, xbc, dt = jnp.split(_mm(u, layer["ssm_in"]),
-                           [d_inner, 2 * d_inner + 2 * state], axis=-1)
-    with jax.named_scope("ssm_conv"):
-        xbc = jax.nn.silu(
-            causal_taps(xbc, layer["ssm_conv_w"]).astype(f32)
-            + layer["ssm_conv_b"]).astype(u.dtype)
-    x, b_, c_ = jnp.split(xbc, [d_inner, d_inner + state], axis=-1)
+    (z, x, b_, c_, dt), conv_kernel = split_conv(
+        _mm(u, layer["ssm_in"]), layer["ssm_conv_w"], layer["ssm_conv_b"],
+        d_inner, state, mesh, rules)
     x = x.reshape(bsz, t, heads, head_dim)
     dt = jax.nn.softplus(dt.astype(f32) + layer["ssm_dt_bias"])
     y, through = chunked_scan(x, dt, -jnp.exp(layer["ssm_A_log"]), b_, c_, chunk,
@@ -210,6 +264,7 @@ def ssm_mixer(u: jax.Array, layer: Dict, heads: int, head_dim: int,
         g = (g * layer["ssm_gate_norm"]).astype(u.dtype)
     chunks = through.shape[0] * through.shape[1]
     stats = {"ssm_layers": jnp.ones((), f32),
+             "ssm_conv_kernel_layers": jnp.asarray(conv_kernel, f32),
              "ssm_chunks": jnp.asarray(chunks, f32),
              "ssm_kernel_chunks": jnp.asarray(
                  chunks * scan_takes_kernel(x.shape, state, chunk, mesh), f32),

@@ -270,6 +270,14 @@ def _scan_calls(text: str) -> dict:
     return _calls(text, SCAN_INSTRUCTIONS)
 
 
+# ops/causal_conv.py's two kernels, likewise
+CONV_INSTRUCTIONS = ("%ssm_conv_fwd.", "%ssm_conv_bwd.")
+
+
+def _conv_calls(text: str) -> dict:
+    return _calls(text, CONV_INSTRUCTIONS)
+
+
 def test_state_space_loss_holds_one_piece_of_logits_and_float32_carries(one_chip, on_tpu):
     """A state-space hybrid at the published widths (depth cut to one
     state-space and one attention layer), 2 x 8,192 tokens, the head's
@@ -278,8 +286,11 @@ def test_state_space_loss_holds_one_piece_of_logits_and_float32_carries(one_chip
     chunks, heads, 256, 256] array exists in either order and no loop but
     the head's pieces; the state goes from chunk to chunk in float32
     inside the kernels and each chunk's entering state is the forward's
-    second output; no array of all the step's tokens by the vocabulary
-    exists."""
+    second output; the convolution is its two kernels too (the forward
+    again in the remat copy), reading xBC where it lies in the in
+    projection's [2, 8192, 8512] output, so neither a padded nor a float32
+    copy of xBC exists; no array of all the step's tokens by the
+    vocabulary exists."""
     config = llama.LlamaConfig.granite_4_0_h_micro(
         n_layers=2, layer_types=("ssm", "attention"), max_seq_len=8192, ce_chunks=7)
     params = _abstract_params(
@@ -290,6 +301,8 @@ def test_state_space_loss_holds_one_piece_of_logits_and_float32_carries(one_chip
     text = compiled.as_text()
     assert _flash_calls(text) == dict.fromkeys(FLASH_INSTRUCTIONS, 1)
     assert _scan_calls(text) == {"%ssm_scan_fwd.": 2, "%ssm_scan_bwd.": 1}
+    assert _conv_calls(text) == {"%ssm_conv_fwd.": 2, "%ssm_conv_bwd.": 1}
+    assert "[2,8195,4352]" not in text and "f32[2,8192,4352]" not in text
     assert "[2,32,64,256,256]" not in text and "[2,64,32,256,256]" not in text
     assert text.count(" while(") == 2  # the head's pieces, both ways
     assert "f32[32,2,128,4096]" in text  # the states entering each chunk, transposed
@@ -305,8 +318,9 @@ def test_state_space_loss_holds_one_piece_of_logits_and_float32_carries(one_chip
 def test_state_space_layer_under_fsdp_rides_a_shard_map(topo, on_tpu):
     """One state-space layer at the published widths under fsdp: 4 on the
     described 2x2, a sequence of 1,024 a chip: GSPMD cannot partition a
-    Mosaic call, so this compiles only while the scan's kernels sit
-    inside a shard_map over `batch`, each chip on its own sequence."""
+    Mosaic call, so this compiles only while the scan's and the
+    convolution's kernels sit inside a shard_map over `batch`, each chip
+    on its own sequence."""
     config = llama.LlamaConfig.granite_4_0_h_micro(
         n_layers=1, layer_types=("ssm",), max_seq_len=1024, ce_chunks=7)
     mesh = build_mesh({"fsdp": 4}, devices=topo.devices)
@@ -321,6 +335,7 @@ def test_state_space_layer_under_fsdp_rides_a_shard_map(topo, on_tpu):
         lambda p, t: llama.loss_fn(p, t, config, mesh=mesh, rules=rules)),
         params, tokens)
     assert _scan_calls(text) == {"%ssm_scan_fwd.": 2, "%ssm_scan_bwd.": 1}
+    assert _conv_calls(text) == {"%ssm_conv_fwd.": 2, "%ssm_conv_bwd.": 1}
     assert "bf16[1,1024,4096]" in text  # a chip's own sequence of x * dt
     assert "all-gather" in text or "all-reduce" in text
 
