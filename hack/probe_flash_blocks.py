@@ -1,8 +1,10 @@
 #!/usr/bin/env python3
-"""The three flash kernels, each alone, on the chip, at the two shapes the
+"""The three flash kernels, each alone, on the chip, at the shapes the
 benchmark's 8k cells run: `[64, 8192, 128]` under a window of 4,096 (the
-Mistral cells; the LFM2 cell has the shape, full causal) and
-`[32, 8192, 128]` full causal (the Ouro cell), blocks of 512.
+Mistral cells; the LFM2 cell has the shape, full causal), `[32, 8192, 128]`
+full causal (the Ouro cell) and `[64, 8192, 256 | 128]` full causal (the
+latent-attention cell: q and k of 192 padded to 256, v of 128; a tree
+whose kernels give q, k and v one width is not run there), blocks of 512.
 
     python hack/probe_flash_blocks.py [--parent .parent] [--reps 5] \
         [--out chiprun_out/probe_flash_blocks.json]
@@ -45,14 +47,16 @@ import numpy as np
 from benchmarks import trace as tr
 
 BLOCK = 512
-# name -> (batch * heads, sequence, head size, window)
+# name -> (batch * heads, sequence, head size, window[, value head size])
 SHAPES = {
     "window_4096": (64, 8192, 128, 4096),
     "full_causal": (32, 8192, 128, None),
+    "latent_256_128": (64, 8192, 256, None, 128),
 }
 TINY = {
     "window_512": (2, 1024, 128, 512),
     "full_causal": (2, 1024, 128, None),
+    "latent_256_128": (2, 1024, 256, None, 128),
 }
 # kernel -> the side of `block_plan` that counts its blocks
 KERNELS = {"flash_fwd": "fwd_dq", "flash_bwd_dq": "fwd_dq",
@@ -72,7 +76,7 @@ def load_kernels(tree: str, name: str):
 def calls_of(fa, shape, emptied: bool):
     """Jitted `_fwd` and `_bwd` at `shape`; with `emptied`, traced while
     `_segments` hands every loop an empty range."""
-    _, seq, d, window = shape
+    _, seq, d, window = shape[:4]
     scale = 1.0 / d ** 0.5
     segments = fa._segments
 
@@ -120,10 +124,11 @@ def kernel_ms(run, reps: int):
 
 def measure(fa, shape, reps: int):
     """Times of the three kernels at `shape`, and the arrays they gave."""
-    bh, seq, d, window = shape
+    bh, seq, d, window = shape[:4]
+    d_v = shape[4] if len(shape) > 4 else d
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    q, k, v, do = (jax.random.normal(key, (bh, seq, d), jnp.bfloat16)
-                   for key in keys)
+    q, k, v, do = (jax.random.normal(key, (bh, seq, w), jnp.bfloat16)
+                   for key, w in zip(keys, (d, d, d_v, d_v)))
     rows = {}
     for name, emptied in (("whole", False), ("emptied", True)):
         fwd, bwd = calls_of(fa, shape, emptied)
@@ -172,17 +177,19 @@ def main(argv=None) -> int:
     for name, shape in (TINY if args.tiny else SHAPES).items():
         sides, arrays = {}, {}
         for tree, fa in trees.items():
+            if len(shape) > 4 and not hasattr(fa, "_whole_seq_params"):
+                continue  # kernels of one width for q, k and v
             sides[tree], arrays[tree] = measure(fa, shape, args.reps)
         entry = {"shape": shape, **sides}
-        if args.parent:
+        if len(sides) == 2:
             entry["equal_bits"] = all(
                 np.array_equal(a, b)
                 for a, b in zip(arrays["parent"], arrays["change"]))
         record["shapes"][name] = entry
         print(f"{name} {list(shape)}"
-              + (f" equal_bits={entry['equal_bits']}" if args.parent else ""))
+              + (f" equal_bits={entry['equal_bits']}" if len(sides) == 2 else ""))
         for kernel in KERNELS:
-            for tree in trees:
+            for tree in sides:
                 row = sides[tree][kernel]
                 times = ("not measured" if "ms_a_call" not in row else
                          f"{row['ms_a_call']:.3f} ms a call, "

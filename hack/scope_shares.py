@@ -3,9 +3,11 @@
 
     python hack/scope_shares.py <trace dir or .xplane.pb> [out.json]
 
-The model's and the step's `jax.named_scope`s (embed, attn > attn_core,
-short_conv, ssm > ssm_conv / ssm_scan > ssm_carry / ssm_gate_norm,
-mlp > moe_route / moe_permute / moe_experts / moe_combine,
+The model's and the step's `jax.named_scope`s (embed, attn > attn_core
+(a latent layer's attn > mla_q, mla_kv, attn_core), hc_map and hc_mix
+around a several-stream layer's sublayers, mtp around a multi-token
+prediction module (`mtp>attn_core`, ...), short_conv, ssm > ssm_conv / ssm_scan > ssm_carry / ssm_gate_norm,
+mlp > moe_route / moe_permute / moe_experts / moe_combine / shared_expert,
 head_loss, exit_gate, optimizer, grad_norm, and loop_pass around a looped
 stack's pass: PERF.md section 3) reach each
 device operation's `op_name`, not its name. On a TPU the profiler keeps
@@ -47,7 +49,8 @@ if ROOT not in sys.path:
 from benchmarks import trace as tr  # interval arithmetic only; no JAX
 
 # innermost first: an operation under attn/attn_core counts as attn_core
-SCOPES = ("attn_core", "attn", "short_conv",
+SCOPES = ("hc_map", "hc_mix", "mla_q", "mla_kv", "shared_expert",
+          "attn_core", "attn", "short_conv",
           "ssm_carry", "ssm_scan", "ssm_conv", "ssm_gate_norm", "ssm",
           "moe_route", "moe_permute",
           "moe_experts", "moe_combine", "mlp", "head_loss", "exit_gate",
@@ -55,6 +58,9 @@ SCOPES = ("attn_core", "attn", "short_conv",
           # a looped stack's pass: what no scope inside it covers (its
           # final norm, the loop's own copies)
           "loop_pass")
+# a scope around whole layers: an operation under it reads `mtp>attn_core`,
+# so that the module's share is the sum of its entries
+OUTER_SCOPES = ("mtp",)
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 # jax.trace / jax.lower / jax.compile: a recompile inside the window
 # (kubedl_tpu/obs/compiles.py), so the gap under it has a name
@@ -69,10 +75,14 @@ COST_STATS = ("flops", "bytes_accessed", "model_flops")
 def scope_of(op_name: str) -> str:
     """A scope is one component of the name stack, bare or wrapped by a
     transformation: mlp, jvp(mlp), transpose(jvp(mlp)), checkpoint/mlp."""
-    for scope in SCOPES:
-        if re.search(rf"(?:^|[/(]){scope}(?:[/)]|$)", op_name):
-            return scope
-    return "unscoped"
+    def under(scope):
+        return re.search(rf"(?:^|[/(]){scope}(?:[/)]|$)", op_name)
+
+    inner = next((scope for scope in SCOPES if under(scope)), "unscoped")
+    for outer in OUTER_SCOPES:
+        if under(outer):
+            return outer if inner == "unscoped" else f"{outer}>{inner}"
+    return inner
 
 
 def own_times(events):

@@ -147,8 +147,10 @@ def _grow_widths(widths, row) -> None:
 def _span_detail(attrs) -> str:
     """The DETAIL column of `trace`: the attributes that say what a span
     was, of a looped stack's step its passes and exit distribution (the
-    `loop_*` counters) and of a state-space model's step what its scans
-    carried (the `ssm_*` counters, docs/observability.md)."""
+    `loop_*` counters), of a state-space model's step what its scans
+    carried (the `ssm_*` counters), of a several-stream model's step how
+    its streams mixed (the `hc_*` counters) and of a multi-token
+    prediction module's step its two losses (docs/observability.md)."""
     detail = [f"{k}={attrs[k]}" for k in
               ("step", "stage", "cause", "outcome", "shape", "reason", "error",
                "fun", "cache")
@@ -169,6 +171,16 @@ def _span_detail(attrs) -> str:
         if "ssm_conv_kernel_layers" in attrs:
             detail.append(
                 f"conv_kernel_layers={int(attrs['ssm_conv_kernel_layers'])}")
+    if "hc_mappings" in attrs:
+        detail.append(f"hc_mappings={int(attrs['hc_mappings'])}")
+        detail.append(f"offdiag={attrs['hc_res_offdiag']:.3f}")
+        detail.append(f"sinkhorn_residual={attrs['hc_sinkhorn_residual']:.1e}")
+        detail.append(f"pre={attrs['hc_pre_mean']:.3f}")
+        detail.append(f"post={attrs['hc_post_mean']:.3f}")
+    if "mtp_ce" in attrs:
+        detail.append(f"ce={attrs['ce']:.4f}")
+        detail.append(f"mtp_ce={attrs['mtp_ce']:.4f}")
+        detail.append(f"mtp_positions={int(attrs['mtp_positions'])}")
     return " ".join(detail)
 
 

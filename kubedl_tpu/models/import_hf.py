@@ -47,6 +47,16 @@ def config_from_hf(hf_config, **overrides) -> LlamaConfig:
             "state-space (mamba) layers: no checkpoint mapping is written "
             "for their leaves (LlamaConfig.granite_4_0_h_micro trains the "
             "architecture from seeded weights)")
+    if getattr(hf_config, "kv_lora_rank", None) or int(
+            getattr(hf_config, "hc_mult", 1) or 1) > 1:
+        raise ValueError(
+            f"model_type {model_type!r} holds latent-attention (MLA) layers "
+            f"(kv_lora_rank {getattr(hf_config, 'kv_lora_rank', None)}) or "
+            f"several-stream layers (hyper-connections, hc_mult "
+            f"{getattr(hf_config, 'hc_mult', 1)}): no checkpoint mapping is "
+            "written for their leaves, nor for the rotary columns' "
+            "interleaved layout (LlamaConfig.xing4_0_29b_a4b trains the "
+            "architecture from seeded weights)")
     if model_type not in ("llama", "mistral", "gemma", "gemma2", "qwen2"):
         raise ValueError(
             f"unsupported model_type {model_type!r} "

@@ -28,6 +28,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from kubedl_tpu.models.hyper import (WORST_OF, finish_stats, hc_init, hc_map,
+                                     hc_mix, hc_param_specs, hc_pre)
+from kubedl_tpu.models.mla import mla_init, mla_param_specs, mla_qkv
 from kubedl_tpu.models.moe import moe_init, moe_layer, moe_param_specs
 from kubedl_tpu.models.quant import matmul as _mm
 from kubedl_tpu.models.short_conv import (short_conv, short_conv_init,
@@ -43,15 +46,23 @@ from kubedl_tpu.parallel.mesh import ShardingRules
 @dataclass(frozen=True)
 class RopeScaling:
     """RoPE frequency rescaling for long-context checkpoints
-    (Llama 3.1's "llama3" scheme or plain "linear" position
-    interpolation) — see _rope_freqs for the math. Frozen so
+    (Llama 3.1's "llama3" scheme, plain "linear" position
+    interpolation, or "yarn") — see _rope_freqs for the math. Frozen so
     LlamaConfig stays hashable."""
 
-    kind: str  # "llama3" | "linear"
+    kind: str  # "llama3" | "linear" | "yarn"
     factor: float
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_position_embeddings: int = 8192
+    # YaRN's keys: the rotations within the original context under which
+    # a frequency is kept (beta_fast) and over which it is interpolated
+    # (beta_slow), and the two magnitudes whose ratio scales cos and sin
+    # and whose second scales the scores (yarn_mscale)
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -196,6 +207,38 @@ class LlamaConfig:
     # weight of the exit distribution's entropy in the looped objective
     # (the paper's stage-I beta, a uniform prior over exit steps)
     exit_entropy_beta: float = 0.05
+    # Latent attention (models/mla.py): kv_lora_rank set makes every
+    # attention layer an MLA layer, its keys qk_nope_head_dim +
+    # qk_rope_head_dim wide (the rope part shared by all heads), its
+    # values v_head_dim; q through a rank of q_lora_rank
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    # Hyper-connections (models/hyper.py): hc_mult residual streams mixed
+    # around every sublayer by mappings whose residual part goes through
+    # hc_sinkhorn_iters Sinkhorn-Knopp iterations. 1 = one residual x
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp_min: float = -30.0
+    hc_res_clamp_max: float = 30.0
+    # shared experts of width d_ff_expert beside the routed ones, run as
+    # one dense SwiGLU of n_shared_experts times that width
+    n_shared_experts: int = 0
+    # the sigmoid router's weights: times routed_scaling_factor, the k
+    # chosen scores normalised over their sum + moe_norm_eps (None = the
+    # LFM2 family's 1e-6, models/moe.py SIGMOID_NORM_EPS)
+    routed_scaling_factor: float = 1.0
+    moe_norm_eps: Optional[float] = None
+    # Multi-token prediction (DeepSeek-V3's): this many extra modules,
+    # each a projection of [embedding of the next token; the stack's
+    # state], one more layer and the shared head, predicting one token
+    # further; their losses enter at mtp_loss_weight (over the modules'
+    # mean). Training only; 0 or 1 modules.
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
 
     def __post_init__(self):
         if self.sliding_window is not None and self.sliding_window < 1:
@@ -231,6 +274,22 @@ class LlamaConfig:
         if self.total_ut_steps < 1:
             raise ValueError(
                 f"total_ut_steps must be >= 1, got {self.total_ut_steps}")
+        if self.kv_lora_rank is not None and not self.q_lora_rank:
+            raise ValueError(
+                f"kv_lora_rank {self.kv_lora_rank} makes the attention layers "
+                f"latent ones, whose q goes through a rank too: q_lora_rank "
+                f"is {self.q_lora_rank}")
+        if self.hc_mult < 1:
+            raise ValueError(f"hc_mult must be >= 1, got {self.hc_mult}")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError(
+                "num_nextn_predict_layers must be 0 or 1, got "
+                f"{self.num_nextn_predict_layers}")
+        if self.looped and (self.hc_mult > 1 or self.num_nextn_predict_layers):
+            raise ValueError(
+                "a looped stack (total_ut_steps > 1) has one residual and "
+                "its own loss: hc_mult > 1 and num_nextn_predict_layers are "
+                "not combined with it")
 
     def mixer_for(self, i: int) -> str:
         """Layer i's token mixer: "attention", "conv" or "ssm"."""
@@ -244,6 +303,44 @@ class LlamaConfig:
     def looped(self) -> bool:
         """Whether the stack is applied more than once (total_ut_steps)."""
         return self.total_ut_steps > 1
+
+    @property
+    def latent(self) -> bool:
+        """Whether the attention layers are latent-attention (MLA) layers."""
+        return self.kv_lora_rank is not None
+
+    @property
+    def softmax_scale(self) -> Optional[float]:
+        """The scores' scale where it is not the kernels' own
+        head_dim^-0.5 of q's width: a latent layer's (nope + rope)^-0.5
+        times YaRN's squared magnitude. None = the kernels' own."""
+        if not self.latent:
+            return None
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        sc = self.rope_scaling
+        if sc is not None and sc.kind == "yarn" and sc.mscale_all_dim:
+            scale *= yarn_mscale(sc.factor, sc.mscale_all_dim) ** 2
+        return scale
+
+    def require_kv_heads(self, what: str) -> None:
+        """Refusal of the paths whose state is keys and values a head."""
+        if self.latent:
+            raise NotImplementedError(
+                f"{what} holds keys and values a head and has no latent "
+                f"cache: a latent-attention (MLA) layer would carry its "
+                f"kv_lora_rank + qk_rope_head_dim = "
+                f"{self.kv_lora_rank + self.qk_rope_head_dim} compressed "
+                f"numbers a token, with prefill and decode paths of their "
+                f"own; it trains (llama.loss_and_stats) and is not served")
+
+    def require_one_stream(self, what: str) -> None:
+        """Refusal of the paths that carry one residual a token."""
+        if self.hc_mult > 1:
+            raise NotImplementedError(
+                f"{what} carries one residual a token: a several-stream "
+                f"layer (hyper-connections, hc_mult = {self.hc_mult}) mixes "
+                f"{self.hc_mult} streams around every sublayer; it trains "
+                f"(llama.loss_and_stats) and is not served")
 
     def require_single_pass(self, what: str) -> None:
         """Refusal of the paths that visit each layer once a token."""
@@ -268,6 +365,8 @@ class LlamaConfig:
                 f"cache beside the keys and values (layer_types holds "
                 f"{self.layer_types.count('conv')} conv layers)")
         self.require_no_ssm(what)
+        self.require_kv_heads(what)
+        self.require_one_stream(what)
 
     def require_no_ssm(self, what: str) -> None:
         """Refusal of the paths that have no state for a state-space
@@ -288,6 +387,13 @@ class LlamaConfig:
         carry something along it."""
         carried = [k for k in ("conv", "ssm")
                    if self.layer_types is not None and k in self.layer_types]
+        if self.latent:
+            raise NotImplementedError(
+                f"{what} splits the sequence over devices: the ring and "
+                f"all-to-all attention paths give q, k and v one head size, "
+                f"and a latent-attention (MLA) layer's keys are "
+                f"{self.qk_nope_head_dim + self.qk_rope_head_dim} wide and "
+                f"its values {self.v_head_dim}")
         if carried:
             raise NotImplementedError(
                 f"{what} splits the sequence over devices: a "
@@ -347,6 +453,7 @@ class LlamaConfig:
             "lfm2-8b-a1b": LlamaConfig.lfm2_8b_a1b,
             "ouro-2.6b": LlamaConfig.ouro_2_6b,
             "granite-4.0-h-micro": LlamaConfig.granite_4_0_h_micro,
+            "xing4.0-29b-a4b": LlamaConfig.xing4_0_29b_a4b,
         }
         if name not in factories:
             raise ValueError(
@@ -416,6 +523,35 @@ class LlamaConfig:
         return LlamaConfig(**defaults)
 
     @staticmethod
+    def xing4_0_29b_a4b(**kw) -> "LlamaConfig":
+        """Xing4.0-29B-A4B at its published sizes (XingChen-AGI/
+        Xing4.0-29B-A4B config.json): 40 layers of hidden 3,584 on four
+        residual streams mixed by manifold-constrained hyper-connections
+        (20 Sinkhorn iterations); latent attention of 32 heads, q rank
+        768, kv rank 512, keys of 128 + 64 (YaRN, factor 64 over 4,096)
+        and values of 128; two leading dense FFNs of 9,216, then 4 of 64
+        experts of 1,024 by a sigmoid router with a selection bias,
+        weights times 2, beside one shared expert; one multi-token
+        prediction module; an untied head over 131,072. 30.3B parameters
+        (29.5B without the module), 3.9B active."""
+        defaults = dict(
+            vocab_size=131072, d_model=3584, n_layers=40, n_heads=32,
+            n_kv_heads=32, d_ff=9216, max_seq_len=262144, rope_theta=10000.0,
+            rms_eps=1e-6, rope_scaling=RopeScaling(
+                kind="yarn", factor=64.0, original_max_position_embeddings=4096,
+                beta_fast=32.0, beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0),
+            kv_lora_rank=512, q_lora_rank=768, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, hc_mult=4,
+            hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp_min=-30.0,
+            hc_res_clamp_max=30.0, n_experts=64, expert_top_k=4,
+            n_dense_layers=2, d_ff_expert=1024, moe_router="sigmoid",
+            n_shared_experts=1, routed_scaling_factor=2.0, moe_norm_eps=1e-20,
+            num_nextn_predict_layers=1, mtp_loss_weight=0.3,
+        )
+        defaults.update(kw)
+        return LlamaConfig(**defaults)
+
+    @staticmethod
     def bench_150m(**kw) -> "LlamaConfig":
         """~170M params — the single-chip quick-proof bench size."""
         defaults = dict(
@@ -446,10 +582,15 @@ def param_specs(config: LlamaConfig, rules: Optional[ShardingRules] = None) -> D
     r = rules or ShardingRules()
 
     def layer_specs(i: int) -> Dict:
-        if config.mixer_for(i) == "conv":
+        # i = n_layers is the multi-token prediction module's block
+        kind = config.mixer_for(i) if i < config.n_layers else "attention"
+        if kind == "conv":
             layer = {"conv_norm": r.spec("embed"), **short_conv_param_specs(r)}
-        elif config.mixer_for(i) == "ssm":
+        elif kind == "ssm":
             layer = {"ssm_norm": r.spec("embed"), **ssm_param_specs(r)}
+        elif config.latent:
+            layer = {"attn_norm": r.spec("embed"),
+                     **mla_param_specs(r)}
         else:
             layer = {
                 "attn_norm": r.spec("embed"),
@@ -470,13 +611,17 @@ def param_specs(config: LlamaConfig, rules: Optional[ShardingRules] = None) -> D
                           "post_mlp_norm": r.spec("embed")})
         if config.routed(i):
             layer["moe"] = moe_param_specs(
-                r, router_bias=config.moe_router == "sigmoid")
+                r, router_bias=config.moe_router == "sigmoid",
+                shared=config.n_shared_experts > 0)
         else:
             layer.update({
                 "w1": r.spec("embed", "mlp"),
                 "w3": r.spec("embed", "mlp"),
                 "w2": r.spec("mlp", "embed"),
             })
+        if config.hc_mult > 1:
+            layer.update({"hc_mixer": hc_param_specs(r),
+                          "hc_mlp": hc_param_specs(r)})
         return layer
 
     specs = {
@@ -488,6 +633,13 @@ def param_specs(config: LlamaConfig, rules: Optional[ShardingRules] = None) -> D
         specs["lm_head"] = r.spec("embed", "vocab")
     if config.looped:
         specs["exit_gate"] = {"w": r.spec("embed", None), "b": r.spec(None)}
+    if config.num_nextn_predict_layers:
+        specs["mtp"] = {
+            "embed_norm": r.spec("embed"), "hidden_norm": r.spec("embed"),
+            "w_eh": r.spec(None, "embed"),
+            "block": layer_specs(config.n_layers),
+            "final_norm": r.spec("embed"),
+        }
     return specs
 
 
@@ -504,17 +656,24 @@ def init(config: LlamaConfig, key: jax.Array) -> Dict:
     # keys[-1] was never drawn from: the gate takes it, and a model with
     # no gate keeps the weights it had
     keys = jax.random.split(key, config.n_layers + 3)
-    layers = []
-    for i in range(config.n_layers):
-        ks = jax.random.split(keys[i], 7)
-        norm_init = jnp.full((d,), 1.0 - config.norm_offset, jnp.float32)
-        if config.mixer_for(i) == "conv":
+    norm_init = jnp.full((d,), 1.0 - config.norm_offset, jnp.float32)
+
+    def make_layer(i: int, key) -> Dict:
+        # i = n_layers is the multi-token prediction module's block
+        ks = jax.random.split(key, 7)
+        kind = config.mixer_for(i) if i < config.n_layers else "attention"
+        if kind == "conv":
             layer = {"conv_norm": norm_init, **short_conv_init(
                 ks[0], d, config.conv_kernel, dtype=dt)}
-        elif config.mixer_for(i) == "ssm":
+        elif kind == "ssm":
             layer = {"ssm_norm": norm_init, **ssm_init(
                 ks[0], d, config.ssm_heads, config.ssm_head_dim,
                 config.ssm_state, config.ssm_conv_kernel, dtype=dt)}
+        elif config.latent:
+            layer = {"attn_norm": norm_init, **mla_init(
+                ks[0], d, nq, config.q_lora_rank, config.kv_lora_rank,
+                config.qk_nope_head_dim, config.qk_rope_head_dim,
+                config.v_head_dim, dtype=dt)}
         else:
             layer = {
                 "attn_norm": norm_init,
@@ -537,17 +696,26 @@ def init(config: LlamaConfig, key: jax.Array) -> Dict:
             layer["post_attn_norm"] = norm_init
             layer["post_mlp_norm"] = norm_init
         if config.routed(i):
+            dff_e = config.d_ff_expert or dff
             layer["moe"] = moe_init(
-                ks[4], d, config.d_ff_expert or dff, config.n_experts,
+                ks[4], d, dff_e, config.n_experts,
                 dtype=dt, n_held=config.n_experts_held,
-                router_bias=config.moe_router == "sigmoid")
+                router_bias=config.moe_router == "sigmoid",
+                d_ff_shared=config.n_shared_experts * dff_e)
         else:
             layer.update({
                 "w1": dense(ks[4], (d, dff), d),
                 "w3": dense(ks[5], (d, dff), d),
                 "w2": dense(ks[6], (dff, d), dff),
             })
-        layers.append(layer)
+        if config.hc_mult > 1:
+            # keys of their own: a model of one stream keeps its weights
+            hk = jax.random.split(jax.random.fold_in(key, 7), 2)
+            layer["hc_mixer"] = hc_init(hk[0], d, config.hc_mult)
+            layer["hc_mlp"] = hc_init(hk[1], d, config.hc_mult)
+        return layer
+
+    layers = [make_layer(i, keys[i]) for i in range(config.n_layers)]
     params = {
         "embed": dense(keys[-3], (config.vocab_size, d), d),
         "layers": layers,
@@ -558,6 +726,14 @@ def init(config: LlamaConfig, key: jax.Array) -> Dict:
     if config.looped:
         params["exit_gate"] = {"w": dense(keys[-1], (d, 1), d),
                                "b": jnp.zeros((1,), jnp.float32)}
+    if config.num_nextn_predict_layers:
+        mk = jax.random.split(jax.random.fold_in(key, config.n_layers), 2)
+        params["mtp"] = {
+            "embed_norm": norm_init, "hidden_norm": norm_init,
+            "w_eh": dense(mk[0], (2 * d, d), 2 * d),
+            "block": make_layer(config.n_layers, mk[1]),
+            "final_norm": norm_init,
+        }
     return params
 
 
@@ -626,9 +802,11 @@ def _rope_freqs(half: int, theta: float, scaling) -> np.ndarray:
         return freqs
     if scaling.kind == "linear":
         return (freqs / scaling.factor).astype(np.float32)
+    if scaling.kind == "yarn":
+        return _yarn_freqs(freqs, theta, scaling)
     if scaling.kind != "llama3":
         raise ValueError(f"unknown rope scaling kind {scaling.kind!r} "
-                         "(linear, llama3)")
+                         "(linear, llama3, yarn)")
     orig = float(scaling.original_max_position_embeddings)
     low_wl = orig / scaling.low_freq_factor
     high_wl = orig / scaling.high_freq_factor
@@ -642,6 +820,31 @@ def _rope_freqs(half: int, theta: float, scaling) -> np.ndarray:
     return scaled.astype(np.float32)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude for a context stretched `factor` times:
+    0.1 mscale ln(factor) + 1 (1 where nothing is stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
+def _yarn_freqs(freqs: np.ndarray, theta: float, scaling) -> np.ndarray:
+    """YaRN (arXiv:2309.00071), per frequency a blend of the frequency
+    itself and the same over `factor`: a dimension that turns more than
+    beta_fast times within the original context keeps its frequency, one
+    that turns fewer than beta_slow times is interpolated, and between
+    the two correction dimensions the blend is a linear ramp."""
+    half = freqs.shape[0]
+    dim, orig = 2 * half, float(scaling.original_max_position_embeddings)
+
+    def correction_dim(rotations: float) -> float:
+        return dim * np.log(orig / (rotations * 2.0 * np.pi)) / (2.0 * np.log(theta))
+
+    low = max(int(np.floor(correction_dim(scaling.beta_fast))), 0)
+    high = min(int(np.ceil(correction_dim(scaling.beta_slow))), dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (freqs / scaling.factor * ramp + freqs * (1.0 - ramp)).astype(np.float32)
+
+
 def _rope(x, positions, theta, scaling=None):
     """Rotary embeddings over [b, h, t, d_head]."""
     d = x.shape[-1]
@@ -650,6 +853,12 @@ def _rope(x, positions, theta, scaling=None):
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]
     cos = jnp.cos(angles)[:, None, :, :]  # [b, 1, t, half]
     sin = jnp.sin(angles)[:, None, :, :]
+    if scaling is not None and scaling.kind == "yarn":
+        # cos and sin times mscale / mscale_all_dim (1 for equal keys)
+        mag = (yarn_mscale(scaling.factor, scaling.mscale)
+               / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if mag != 1.0:
+            cos, sin = cos * mag, sin * mag
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
@@ -683,13 +892,48 @@ def _proj(h, layer, name, lora=None, adapter_ids=None):
     return out
 
 
-def _add_branch(x, out, config: LlamaConfig):
+def _branch_input(x, hc: Optional[Dict], config: LlamaConfig):
+    """(what a sublayer reads of the residual, what its output goes back
+    onto): x itself and None, or, where the layer carries the sublayer's
+    hyper-connection leaves `hc`, the mix of the streams x [b, t, n, d]
+    by the mappings made from them, and (the streams, the mappings)
+    (models/hyper.py)."""
+    if hc is None:
+        return x, None
+    mapping = hc_map(x, hc, config.hc_sinkhorn_iters, config.hc_eps,
+                     (config.hc_res_clamp_min, config.hc_res_clamp_max))
+    return hc_pre(x, mapping), (x, mapping)
+
+
+def _add_branch(x, out, config: LlamaConfig, onto=None):
     """x + residual_multiplier * out: a branch's output onto the residual
-    stream, the multiply in float32 and absent at 1."""
+    stream it read, the multiply in float32 and absent at 1. `onto`
+    (`_branch_input`'s) is the residual of several streams that x was
+    mixed from: each stream becomes a mix of all of them plus its own
+    share of the output."""
     if config.residual_multiplier != 1.0:
         out = (out.astype(jnp.float32)
                * config.residual_multiplier).astype(x.dtype)
+    if onto is not None:
+        return hc_mix(onto[0], out, onto[1])
     return x + out
+
+
+def _hc_counters(onto) -> Dict:
+    return {} if onto is None else onto[1]["stats"]
+
+
+def _add_counters(into: Dict, new: Dict) -> Dict:
+    """`into` with a sublayer's or a layer's counters added: sums, but
+    for the few that are a largest (models/hyper.py WORST_OF)."""
+    for k, v in new.items():
+        if k not in into:
+            into[k] = v
+        elif k in WORST_OF:
+            into[k] = jnp.maximum(into[k], v)
+        else:
+            into[k] = into[k] + v
+    return into
 
 
 # The named scopes below (embed, attn > attn_core, mlp, head_loss) reach
@@ -703,8 +947,13 @@ def _add_branch(x, out, config: LlamaConfig):
 def _attention_core(q, k, v, config: LlamaConfig, mesh, rules, context_size,
                     window):
     """softmax(q k^T) v over [b, heads, t, head_dim] by whichever of ring,
-    ulysses, flash or plain XLA the config and the mesh select."""
+    ulysses, flash or plain XLA the config and the mesh select. v's head
+    size may be unlike q's and k's (a latent layer's), and the scale the
+    config's own (`softmax_scale`) where the kernels' head_dim^-0.5 is
+    not it."""
     softcap = config.attn_logit_softcap or None
+    scale = {} if config.softmax_scale is None else {
+        "sm_scale": config.softmax_scale}
     if context_size > 1:
         if config.has_windows:
             raise NotImplementedError(
@@ -724,7 +973,8 @@ def _attention_core(q, k, v, config: LlamaConfig, mesh, rules, context_size,
         return ring_attention(q, k, v, mesh=mesh, causal=True)
     if config.use_flash:
         flash = functools.partial(
-            flash_attention, causal=True, window=window, softcap=softcap)
+            flash_attention, causal=True, window=window, softcap=softcap,
+            **scale)
         if mesh is not None and mesh.size > 1:
             # GSPMD cannot partition a Mosaic kernel: each device runs it
             # on its own batch and head shard (attention mixes neither)
@@ -736,15 +986,37 @@ def _attention_core(q, k, v, config: LlamaConfig, mesh, rules, context_size,
     from kubedl_tpu.ops.flash_attention import attention_reference
 
     return attention_reference(q, k, v, causal=True, window=window,
-                               softcap=softcap)
+                               softcap=softcap, **scale)
+
+
+def _latent_attention(h, layer, config: LlamaConfig, positions, mesh, rules,
+                      context_size, window):
+    """A latent-attention layer's output from its normed input h
+    [b, t, d] (models/mla.py): the low-rank projections, the core over
+    keys wider than the values, the output projection."""
+    b, t, _ = h.shape
+    norm = lambda x, w: rms_norm(x, w, config.rms_eps, config.norm_offset)
+    rope = lambda x: _rope(x, positions, config.rope_theta, config.rope_scaling)
+    q, k, v = mla_qkv(h, layer, config.n_heads, config.qk_nope_head_dim,
+                      config.qk_rope_head_dim, config.v_head_dim, norm, rope)
+    attn = _attention_core(q, k, v, config, mesh, rules, context_size, window)
+    attn = attn.transpose(0, 2, 1, 3).reshape(
+        b, t, config.n_heads * config.v_head_dim)
+    return _mm(attn, layer["wo"]).astype(h.dtype)
 
 
 @jax.named_scope("attn")
 def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
-                     context_size, window=None):
+                     context_size, window=None, onto=None):
+    """The residual after the layer's attention over x [b, t, d]; `onto`
+    as `_add_branch` takes it."""
     b, t, d = x.shape
     hd, nq, nkv = config.head_dim, config.n_heads, config.n_kv_heads
     h = rms_norm(x, layer["attn_norm"], config.rms_eps, config.norm_offset)
+    if "wkv_a" in layer:
+        out = _latent_attention(h, layer, config, positions, mesh, rules,
+                                context_size, window)
+        return _add_branch(x, out, config, onto)
     q = _proj(h, layer, "q").reshape(b, t, nq, hd).transpose(0, 2, 1, 3)
     k = _proj(h, layer, "k").reshape(b, t, nkv, hd).transpose(0, 2, 1, 3)
     v = _proj(h, layer, "v").reshape(b, t, nkv, hd).transpose(0, 2, 1, 3)
@@ -766,19 +1038,19 @@ def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
     if "post_attn_norm" in layer:
         out = rms_norm(out, layer["post_attn_norm"], config.rms_eps,
                        config.norm_offset)
-    return _add_branch(x, out, config)
+    return _add_branch(x, out, config, onto)
 
 
 @jax.named_scope("short_conv")
-def _short_conv_block(x, layer, config: LlamaConfig):
+def _short_conv_block(x, layer, config: LlamaConfig, onto=None):
     """A convolution layer's mixer with its norm and residual, as
     _attention_block is an attention layer's."""
     h = rms_norm(x, layer["conv_norm"], config.rms_eps, config.norm_offset)
-    return _add_branch(x, short_conv(h, layer).astype(x.dtype), config)
+    return _add_branch(x, short_conv(h, layer).astype(x.dtype), config, onto)
 
 
 @jax.named_scope("ssm")
-def _ssm_block(x, layer, config: LlamaConfig, mesh, rules):
+def _ssm_block(x, layer, config: LlamaConfig, mesh, rules, onto=None):
     """A state-space layer's mixer with its norm and residual, and the
     layer's counters (models/ssm.py ssm_mixer). The mesh is for the
     scan's and the convolution's kernels, which ride a shard_map over
@@ -787,19 +1059,23 @@ def _ssm_block(x, layer, config: LlamaConfig, mesh, rules):
     out, stats = ssm_mixer(h, layer, config.ssm_heads, config.ssm_head_dim,
                            config.ssm_state, config.ssm_chunk, config.rms_eps,
                            mesh, rules)
-    return _add_branch(x, out.astype(x.dtype), config), stats
+    return _add_branch(x, out.astype(x.dtype), config, onto), stats
 
 
 def _mixer_block(x, layer, config: LlamaConfig, positions, mesh, rules,
                  context_size, window=None):
     """The layer's token mixer, by what the layer holds, and its counters
-    ({} but for a state-space layer)."""
+    ({} but for a state-space layer and for a layer of several streams,
+    whose mixer reads a mix of them: `_branch_input`)."""
+    u, onto = _branch_input(x, layer.get("hc_mixer"), config)
     if "ssm_in" in layer:
-        return _ssm_block(x, layer, config, mesh, rules)
+        y, stats = _ssm_block(u, layer, config, mesh, rules, onto)
+        return y, {**stats, **_hc_counters(onto)}
     if "conv_in" in layer:
-        return _short_conv_block(x, layer, config), {}
-    return _attention_block(x, layer, config, positions, mesh, rules,
-                            context_size, window=window), {}
+        return _short_conv_block(u, layer, config, onto), _hc_counters(onto)
+    return _attention_block(u, layer, config, positions, mesh, rules,
+                            context_size, window=window,
+                            onto=onto), _hc_counters(onto)
 
 
 @jax.named_scope("mlp")
@@ -810,6 +1086,7 @@ def _mlp_block(x, layer, config: LlamaConfig, mesh=None, rules=None,
     dense one. lora/adapter_ids: per-row serving adapters on w1/w3/w2
     (see _proj); MoE layers carry no dense projections for adapters to
     target."""
+    x, onto = _branch_input(x, layer.get("hc_mlp"), config)
     h = rms_norm(x, layer["mlp_norm"], config.rms_eps, config.norm_offset)
     stats = {}
     if "moe" in layer:
@@ -819,6 +1096,8 @@ def _mlp_block(x, layer, config: LlamaConfig, mesh=None, rules=None,
             dropless=config.moe_dropless, fused=config.moe_fused,
             a2a_chunks=config.moe_a2a_chunks,
             first_expert=config.first_expert,
+            routed_scale=config.routed_scaling_factor,
+            norm_eps=config.moe_norm_eps,
         )
         y = y.astype(x.dtype)
     else:
@@ -830,7 +1109,7 @@ def _mlp_block(x, layer, config: LlamaConfig, mesh=None, rules=None,
     if "post_mlp_norm" in layer:
         y = rms_norm(y, layer["post_mlp_norm"], config.rms_eps,
                      config.norm_offset)
-    return _add_branch(x, y, config), aux, stats
+    return _add_branch(x, y, config, onto), aux, {**stats, **_hc_counters(onto)}
 
 
 def _constrainer(mesh, rules):
@@ -841,6 +1120,48 @@ def _constrainer(mesh, rules):
     return constrain
 
 
+def _layer_maker(config: LlamaConfig, positions, mesh, rules, context_size):
+    """window -> the function that applies one layer, `layer_fn((x, aux),
+    layer) -> ((x, aux), counters)`, rematerialised as the config says.
+    x is [b, t, d], or [b, t, n, d] where the layers mix several
+    streams. The stack and the multi-token prediction module's block run
+    their layers through it."""
+    constrain = _constrainer(mesh, rules)
+
+    def make_layer_fn(window):
+        # window is trace-time static (it selects the attention mask
+        # program), so it rides a closure, not a traced argument
+        def layer_fn(carry, layer):
+            x, aux = carry
+            wide = (None,) * (x.ndim - 2)  # streams and embed, unsharded
+            x, mixed = _mixer_block(x, layer, config, positions, mesh, rules,
+                                    context_size, window=window)
+            x = constrain(x, "batch", "seq", *wide)
+            x, a, counters = _mlp_block(x, layer, config, mesh, rules)
+            return (constrain(x, "batch", "seq", *wide), aux + a), _add_counters(
+                dict(mixed), counters)
+
+        if config.remat:
+            return jax.checkpoint(
+                layer_fn, policy=_remat_policy(config.remat_policy))
+        return layer_fn
+
+    return make_layer_fn
+
+
+def _context_size(config: LlamaConfig, mesh) -> int:
+    """The mesh's `context` axis, refused by the layers that cannot have
+    their sequence split."""
+    context_size = 1 if mesh is None else mesh.shape.get("context", 1)
+    if context_size > 1:
+        config.require_whole_sequences(f"a mesh with context: {context_size}")
+    return context_size
+
+
+def _positions(b: int, t: int):
+    return jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
+
+
 def _backbone(
     params: Dict,
     tokens: jax.Array,  # [batch, seq] int32
@@ -848,21 +1169,18 @@ def _backbone(
     mesh: Optional[Mesh],
     rules: ShardingRules,
 ) -> Tuple[jax.Array, jax.Array, Dict]:
-    """(what the head reads, summed MoE aux loss, the expert layers'
-    counters summed over layers: {} for a dense model). What the head
-    reads is the pre-final-norm activations [batch, seq, d] of a stack
-    run once, and of a looped one (total_ut_steps > 1) every pass's
-    state after the final norm, [passes, batch, seq, d]: the norm sits
-    inside the loop there, its output feeds the next pass."""
-    context_size = 1
-    if mesh is not None:
-        context_size = mesh.shape.get("context", 1)
-    if context_size > 1:
-        config.require_whole_sequences(f"a mesh with context: {context_size}")
+    """(what the head reads, summed MoE aux loss, the layers' counters
+    summed over layers: {} for a dense model). What the head reads is
+    the pre-final-norm activations [batch, seq, d] of a stack run once
+    (of a stack of several residual streams, their sum), and of a looped
+    one (total_ut_steps > 1) every pass's state after the final norm,
+    [passes, batch, seq, d]: the norm sits inside the loop there, its
+    output feeds the next pass."""
+    context_size = _context_size(config, mesh)
     constrain = _constrainer(mesh, rules)
 
     b, t = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
+    positions = _positions(b, t)
     # FSDP-gather the table's embed dim before the lookup: a gather whose
     # output inherits a feature-dim sharding forces SPMD into an involuntary
     # full rematerialization when the result is then batch-sharded; with the
@@ -873,23 +1191,14 @@ def _backbone(
         if config.embed_scale != 1.0:
             x = x * jnp.asarray(config.embed_scale, config.dtype)
         x = constrain(x, "batch", "seq", None)
+        if config.hc_mult > 1:
+            # every stream starts as the embedding
+            x = constrain(
+                jnp.broadcast_to(x[:, :, None, :], (b, t, config.hc_mult,
+                                                    x.shape[-1])),
+                "batch", "seq", None, None)
 
-    def make_layer_fn(window):
-        # window is trace-time static (it selects the attention mask
-        # program), so it rides a closure, not a traced argument
-        def layer_fn(carry, layer):
-            x, aux = carry
-            x, mixed = _mixer_block(x, layer, config, positions, mesh, rules,
-                                    context_size, window=window)
-            x = constrain(x, "batch", "seq", None)
-            x, a, counters = _mlp_block(x, layer, config, mesh, rules)
-            return (constrain(x, "batch", "seq", None), aux + a), {
-                **mixed, **counters}
-
-        if config.remat:
-            return jax.checkpoint(
-                layer_fn, policy=_remat_policy(config.remat_policy))
-        return layer_fn
+    make_layer_fn = _layer_maker(config, positions, mesh, rules, context_size)
 
     def stack(x):
         """Every layer once over x."""
@@ -897,8 +1206,10 @@ def _backbone(
         stats: Dict = {}
         for i, layer in enumerate(params["layers"]):
             (x, aux), counters = make_layer_fn(config.window_for(i))((x, aux), layer)
-            for k, v in counters.items():
-                stats[k] = stats[k] + v if k in stats else v
+            _add_counters(stats, counters)
+        if config.hc_mult > 1:
+            with jax.named_scope("hc_mix"):
+                x = jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
         return x, aux, stats
 
     if not config.looped:
@@ -991,23 +1302,35 @@ def _lm_head(x, params, config: LlamaConfig) -> jax.Array:
     return _head_logits(x, params, config)
 
 
+def _mean_over(nll, mask):
+    """Mean of the per-position losses: over all of them, or over those
+    `mask` [b, t] counts."""
+    if mask is None:
+        return jnp.mean(nll)
+    return jnp.sum(nll * mask) / jnp.sum(mask)
+
+
 @jax.named_scope("head_loss")
-def _next_token_ce(logits, targets):
+def _next_token_ce(logits, targets, mask=None):
     logp = jax.nn.log_softmax(logits, axis=-1)
     ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    return -jnp.mean(ll) if mask is None else _mean_over(-ll, mask)
 
 
 @jax.named_scope("head_loss")
-def _next_token_ce_chunked(x, params, config: LlamaConfig, targets, n_chunks: int):
+def _next_token_ce_chunked(x, params, config: LlamaConfig, targets,
+                           n_chunks: int, final_norm=None, mask=None):
     """CE without materializing [b, t, V] f32 logits.
 
     lax.scan over vocab chunks: each chunk's lm_head matmul fuses with its
     max/sumexp reduction (only [b, t] statistics leave the chunk), and
     jax.checkpoint recomputes the chunk logits in backward instead of
     saving them. Online-logsumexp merge across chunks is exact.
+    `final_norm` is the norm's weight where it is not the model's own (a
+    multi-token prediction module's), `mask` as `_mean_over` takes it.
     """
-    xn = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
+    xn = rms_norm(x, params["final_norm"] if final_norm is None else final_norm,
+                  config.rms_eps, config.norm_offset)
     head = _head_matrix(params, config)
     d, V = head.shape
     if V % n_chunks:
@@ -1054,7 +1377,7 @@ def _next_token_ce_chunked(x, params, config: LlamaConfig, targets, n_chunks: in
     )
     (big_m, big_l, tgt), _ = jax.lax.scan(body, init, (hc, offs))
     lse = big_m + jnp.log(big_l)
-    return jnp.mean(lse - tgt)
+    return _mean_over(lse - tgt, mask)
 
 
 # tokens whose float32 logits a looped stack's loss holds at a time: 4,096
@@ -1131,6 +1454,77 @@ def _looped_loss(states, params, config: LlamaConfig, targets, mesh, rules):
     return loss, stats
 
 
+@jax.named_scope("mtp")
+def _mtp_hidden(h, params, tokens, config: LlamaConfig, mesh, rules):
+    """The multi-token prediction module's state before its final norm
+    [b, t, d], its block's MoE aux loss and counters (DeepSeek-V3,
+    arXiv:2412.19437, one module).
+
+    h [b, t, d] is the main stack's state before its final norm, position
+    i having read tokens 0..i of the t + 1 fed. The module reads
+    z_i = W_eh [norm_e(E[t_{i+1}]); norm_h(h_i)] and runs its one block
+    over z (under hyper-connections, on streams that each start as z); E
+    is the model's embedding. Position i has then read tokens 0..i+1."""
+    mtp = params["mtp"]
+    b, t, d = h.shape
+    constrain = _constrainer(mesh, rules)
+    norm = lambda x, w: rms_norm(x, w, config.rms_eps, config.norm_offset)
+    with jax.named_scope("embed"):
+        tbl = constrain(params["embed"], "vocab", None)
+        e = tbl[tokens[:, 1:]].astype(config.dtype)
+        if config.embed_scale != 1.0:
+            e = e * jnp.asarray(config.embed_scale, config.dtype)
+        e = constrain(e, "batch", "seq", None)
+    z = _mm(jnp.concatenate([norm(e, mtp["embed_norm"]),
+                             norm(h, mtp["hidden_norm"])], axis=-1),
+            mtp["w_eh"]).astype(config.dtype)
+    z = constrain(z, "batch", "seq", None)
+    if config.hc_mult > 1:
+        z = constrain(
+            jnp.broadcast_to(z[:, :, None, :], (b, t, config.hc_mult, d)),
+            "batch", "seq", None, None)
+    layer_fn = _layer_maker(config, _positions(b, t), mesh, rules,
+                            _context_size(config, mesh))(config.sliding_window)
+    (z, aux), stats = layer_fn((z, jnp.zeros((), jnp.float32)), mtp["block"])
+    if config.hc_mult > 1:
+        with jax.named_scope("hc_mix"):
+            z = jnp.sum(z.astype(jnp.float32), axis=2).astype(z.dtype)
+    return z, aux, stats
+
+
+@jax.named_scope("mtp")
+def _mtp_loss(h, params, tokens, config: LlamaConfig, mesh, rules,
+              chunked: bool):
+    """The multi-token prediction module's loss: (cross entropy of the
+    second-next token, the block's MoE aux loss, counters). Position i of
+    `_mtp_hidden` predicts token i + 2 through the module's own final
+    norm and the model's head; position t - 1 has no second-next token
+    among the t + 1 fed and is left out of the mean."""
+    mtp = params["mtp"]
+    b, t = tokens.shape[0], tokens.shape[1] - 1
+    constrain = _constrainer(mesh, rules)
+    norm = lambda x, w: rms_norm(x, w, config.rms_eps, config.norm_offset)
+    z, aux, stats = _mtp_hidden(h, params, tokens, config, mesh, rules)
+    # position i's target is token i + 2; the last position has none
+    targets = jnp.concatenate(
+        [tokens[:, 2:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
+    mask = (jnp.arange(t) < t - 1).astype(jnp.float32)[None, :] * jnp.ones(
+        (b, 1), jnp.float32)
+    if chunked:
+        ce = _next_token_ce_chunked(z, params, config, targets,
+                                    config.ce_chunks,
+                                    final_norm=mtp["final_norm"], mask=mask)
+    else:
+        with jax.named_scope("head_loss"):
+            logits = _head_logits(norm(z, mtp["final_norm"]), params, config)
+        ce = _next_token_ce(constrain(logits, "batch", "seq", "vocab"),
+                            targets, mask)
+    stats = dict(stats)
+    stats["mtp_ce"] = ce
+    stats["mtp_positions"] = jnp.asarray(b * (t - 1), jnp.float32)
+    return ce, aux, stats
+
+
 def loss_fn(params, tokens, config: LlamaConfig, mesh=None, rules=None):
     """Next-token cross entropy (+ MoE aux); tokens [b, t], loss over [:, 1:].
 
@@ -1153,7 +1547,13 @@ def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
     ssm_layers, ssm_conv_kernel_layers (the layers whose convolution ran
     as ops/causal_conv.py's kernels), ssm_chunks, ssm_kernel_chunks (the
     chunks that went through ops/ssm_scan.py's kernels) and, averaged
-    over those layers, ssm_dt_mean and ssm_state_carry
+    over those layers, ssm_dt_mean and ssm_state_carry; for a model of
+    several residual streams (models/hyper.py): hc_mappings and, over
+    those mappings, hc_res_offdiag, hc_pre_mean, hc_post_mean (means)
+    and hc_sinkhorn_residual (the largest); for a model with a
+    multi-token prediction module (_mtp_loss), whose loss enters at
+    mtp_loss_weight: ce (the next token's cross entropy alone), mtp_ce,
+    mtp_positions, and the module's block in every layer counter
     (docs/observability.md)."""
     rules = rules or ShardingRules()
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
@@ -1175,6 +1575,12 @@ def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
         logits = _constrainer(mesh, rules)(
             _lm_head(x, params, config), "batch", "seq", "vocab")
         ce = _next_token_ce(logits, targets)
+    if "mtp" in params:
+        ce2, aux2, mtp_stats = _mtp_loss(x, params, tokens, config, mesh,
+                                         rules, chunked)
+        stats = _add_counters({**stats, "ce": ce}, mtp_stats)
+        ce, aux = ce + config.mtp_loss_weight * ce2, aux + aux2
+    stats = finish_stats(stats)
     if "ssm_layers" in stats:
         # the layers' means were summed over the layers with their count
         stats = dict(stats)
@@ -1274,6 +1680,14 @@ def forward_pipelined_and_aux(
     shardings need manual collectives inside shard_map)."""
     config.require_single_pass("the pipelined forward")
     config.require_no_ssm("the pipelined forward")
+    config.require_one_stream("the pipelined forward")
+    if config.latent or config.num_nextn_predict_layers:
+        raise NotImplementedError(
+            "the pipelined forward's stages hand one another one [b, t, d] "
+            "activation through grouped-query layers of one head size: a "
+            "latent-attention (MLA) layer and a multi-token prediction "
+            "module (a second head over the last stage's state and the "
+            "first stage's embedding) have no stage program")
     if config.layer_windows is not None:
         # the pipeline scans ONE compiled layer program over stacked
         # params; a per-layer static mask can't vary inside the scan

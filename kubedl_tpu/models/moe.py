@@ -49,7 +49,11 @@ Two things a layer reads off its own arrays (docs/moe_performance.md):
   * the router's score function: softmax over all outputs with the
     GShard auxiliary loss (`_top_k_gating`), or, where the layer carries
     a `router_bias`, a sigmoid score whose top-k selection alone sees
-    the bias and which has no auxiliary loss (`_sigmoid_gating`).
+    the bias and which has no auxiliary loss (`_sigmoid_gating`);
+  * a shared expert (`shared_w1/w3/w2`): a dense SwiGLU of every token
+    beside the routed ones, added to whatever part of the routed result
+    the layer computes. In an expert-parallel deployment every chip
+    computes it alike, so of the shares' results it counts once.
 """
 from __future__ import annotations
 
@@ -66,7 +70,7 @@ from kubedl_tpu.parallel.mesh import ShardingRules
 
 
 def moe_param_specs(rules: Optional[ShardingRules] = None,
-                    router_bias: bool = False) -> Dict:
+                    router_bias: bool = False, shared: bool = False) -> Dict:
     """PartitionSpec pytree matching moe_init() for one MoE FFN layer."""
     r = rules or ShardingRules()
     specs = {
@@ -77,16 +81,22 @@ def moe_param_specs(rules: Optional[ShardingRules] = None,
     }
     if router_bias:
         specs["router_bias"] = r.spec(None)
+    if shared:
+        specs.update({"shared_w1": r.spec("embed", "mlp"),
+                      "shared_w3": r.spec("embed", "mlp"),
+                      "shared_w2": r.spec("mlp", "embed")})
     return specs
 
 
 def moe_init(
     key: jax.Array, d_model: int, d_ff: int, n_experts: int, dtype=jnp.bfloat16,
     n_held: Optional[int] = None, router_bias: bool = False,
+    d_ff_shared: int = 0,
 ) -> Dict:
     """One expert layer: a router over `n_experts` outputs and the
     `n_held` experts this layer holds (None = all of them). With
-    `router_bias`, the sigmoid router's selection bias (zeros)."""
+    `router_bias`, the sigmoid router's selection bias (zeros); with
+    `d_ff_shared`, a shared expert of that width."""
     ks = jax.random.split(key, 4)
     n_held = n_held or n_experts
 
@@ -108,6 +118,13 @@ def moe_init(
     }
     if router_bias:
         layer["router_bias"] = jnp.zeros((n_experts,), jnp.float32)
+    if d_ff_shared:
+        sk = jax.random.split(jax.random.fold_in(key, 1), 3)
+        layer.update({
+            "shared_w1": dense(sk[0], (d_model, d_ff_shared), d_model),
+            "shared_w3": dense(sk[1], (d_model, d_ff_shared), d_model),
+            "shared_w2": dense(sk[2], (d_ff_shared, d_model), d_ff_shared),
+        })
     return layer
 
 
@@ -123,6 +140,8 @@ def _top_k_gating(
     capacity: int,
     need_slots: bool = True,
     bias: Optional[jax.Array] = None,
+    routed_scale: float = 1.0,
+    norm_eps: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array,
            Tuple[jax.Array, jax.Array]]:
     """Routing as INDICES instead of one-hot planes.
@@ -153,7 +172,8 @@ def _top_k_gating(
     """
     s, e = gate_logits.shape
     if bias is not None:
-        experts, gates = _sigmoid_gating(gate_logits, bias, top_k)
+        experts, gates = _sigmoid_gating(gate_logits, bias, top_k,
+                                         routed_scale, norm_eps)
         me = ce = jnp.zeros((e,), jnp.float32)
     else:
         probs = jax.nn.softmax(gate_logits, axis=-1)
@@ -248,8 +268,9 @@ def _top_k_gating_reference(
     )
 
 
-# the family's modelling code adds this to the sum that normalises the k
-# selected scores (it is not a key of any config.json)
+# a family's modelling code adds this to the sum that normalises the k
+# selected scores (it is not a key of any config.json): LFM2's 1e-6, the
+# default; DeepSeek-V3's family 1e-20 (LlamaConfig.moe_norm_eps)
 SIGMOID_NORM_EPS = 1e-6
 
 
@@ -257,19 +278,35 @@ def _sigmoid_gating(
     gate_logits: jax.Array,  # [S, n_out] f32
     bias: jax.Array,  # [n_out] f32, moves the selection and nothing else
     top_k: int,
+    routed_scale: float = 1.0,
+    norm_eps: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Sigmoid router with a selection bias: (experts [k, S] i32,
     weights [k, S] f32). The score of every output is its own sigmoid;
     the k outputs with the largest `score + bias` are chosen, and each
     weighs its score (without the bias) over the sum of all k chosen
-    scores, held here or not. The bias takes no gradient: it reaches the
-    result through the indices alone."""
+    scores, held here or not, times `routed_scale` (the family's
+    `routed_scaling_factor`; no multiply at 1). The bias takes no
+    gradient: it reaches the result through the indices alone."""
     scores = jax.nn.sigmoid(gate_logits)
     _, topi = jax.lax.top_k(scores + bias.astype(scores.dtype), top_k)
     chosen = jnp.take_along_axis(scores, topi, axis=-1)  # [S, k]
     weights = chosen / (
-        jnp.sum(chosen, axis=-1, keepdims=True) + SIGMOID_NORM_EPS)
+        jnp.sum(chosen, axis=-1, keepdims=True)
+        + (SIGMOID_NORM_EPS if norm_eps is None else norm_eps))
+    if routed_scale != 1.0:
+        weights = weights * routed_scale
     return topi.T.astype(jnp.int32), weights.T
+
+
+@jax.named_scope("shared_expert")
+def _shared_expert(hf: jax.Array, params: Dict) -> jax.Array:
+    """The shared expert's SwiGLU over every row of hf [S, d]."""
+    from kubedl_tpu.models.quant import matmul as mm
+
+    gate = jax.nn.silu(mm(hf, params["shared_w1"]).astype(jnp.float32))
+    return mm(gate.astype(hf.dtype) * mm(hf, params["shared_w3"]),
+              params["shared_w2"]).astype(hf.dtype)
 
 
 def _dispatch_stats(eid: jax.Array, e: int) -> Dict:
@@ -883,6 +920,8 @@ def moe_layer(
     fused: Optional[bool] = None,
     a2a_chunks: int = 1,
     first_expert: int = 0,
+    routed_scale: float = 1.0,
+    norm_eps: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array, Dict]:
     """Returns (output [b,t,d], aux_load_balance_loss scalar, counters).
 
@@ -895,7 +934,11 @@ def moe_layer(
     of the result (module docstring): single-device dropless route only,
     since the rest of the result lives on chips this program does not
     exchange with. A `router_bias` in `params` selects the sigmoid
-    router (`_sigmoid_gating`).
+    router (`_sigmoid_gating`), whose weights take `routed_scale` and
+    `norm_eps` (None = SIGMOID_NORM_EPS). `shared_w1/w3/w2` in `params`
+    are a shared expert: its output is added on every route, whatever
+    part of the routed result the layer computes, and it is in none of
+    the counters.
 
     dropless=None (auto): use the grouped-matmul kernel only when there
     is no multi-device mesh — it processes exactly the routed tokens (no
@@ -961,17 +1004,31 @@ def moe_layer(
         return jax.lax.with_sharding_constraint(x, rules.sharding(mesh, *dims))
 
     hf = h.reshape(s, d)
+
+    def whole(y):
+        """The layer's output from the routed part y [S, d]: plus the
+        shared expert's, where the layer has one."""
+        if "shared_w1" in params:
+            y = y + _shared_expert(hf, params)
+        return y.reshape(b, t, d)
+
     if dropless and multi_device:
+        if routed_scale != 1.0 or norm_eps is not None:
+            raise NotImplementedError(
+                "the expert-parallel dropless route normalises its sigmoid "
+                "router's weights inside the shard body, where "
+                "routed_scale and norm_eps are not wired")
         # expert-parallel dropless: shard_map + all_to_all dispatch; the
         # router runs per-device inside the shard body
         y, aux = _dropless_mlp_sharded(
             hf, params, top_k=top_k, quota_factor=capacity_factor,
             mesh=mesh, rules=rules, e=e, fused=fused, a2a_chunks=a2a_chunks)
-        return y.reshape(b, t, d), aux, {}
+        return whole(y), aux, {}
     with jax.named_scope("moe_route"):
         gate_logits = _router_logits(hf, params["router"])
         experts, slots, weights, keeps, (me, ce) = _top_k_gating(
-            gate_logits, top_k, c, need_slots=not dropless, bias=bias)
+            gate_logits, top_k, c, need_slots=not dropless, bias=bias,
+            routed_scale=routed_scale, norm_eps=norm_eps)
         aux = n_out * jnp.sum(me * ce)
         if dropless and e != n_out:
             # a choice of an expert held elsewhere becomes the sentinel e:
@@ -983,7 +1040,7 @@ def moe_layer(
         # normalized over all k choices — true dropless
         y = _dropless_mlp(hf, params, experts, weights, e, fused=fused)
         stats = _dispatch_stats(experts.reshape(-1), e)
-        return y.reshape(b, t, d), aux, stats
+        return whole(y), aux, stats
 
     def emm(x, w, eq):
         """Batched expert matmul; int8 stacks ({q, s}, models/quant.py)
@@ -1018,4 +1075,4 @@ def moe_layer(
     y = jnp.zeros((s, d), h.dtype)
     for k in range(flat.shape[0]):
         y = y + weights[k][:, None].astype(h.dtype) * out_pad[flat[k]]
-    return y.reshape(b, t, d), aux, {}
+    return whole(y), aux, {}

@@ -11,6 +11,12 @@ Layout: [batch*heads, seq, head_dim]. The public entry handles GQA by
 broadcasting KV heads, pads ragged sequence lengths to block multiples, and
 installs a custom VJP wiring the two kernels together.
 
+q and k share one head size and v may have another (latent attention:
+keys of 192, values of 128): q, k, dq and dk ride at the q/k width, v, o,
+do, dv and the forward's accumulator at the value width, each padded to
+its own multiple of 128 lanes. Equal widths are the one-width kernels
+they always were.
+
 Each kernel reads a block's fate off its position (`_segments`): a block
 with no pair inside the causal diagonal, the window and the true length
 is never visited, and `block_plan` counts for a shape the blocks that
@@ -58,6 +64,12 @@ FLASH_MIN_SEQ = 1024
 # so TRAINING beyond this length belongs to ring attention / context
 # parallelism — the streamed path serves long-context inference prefill.
 STREAM_MIN_SEQ = 8192
+# What the whole-sequence kernels were sized for: a head's two operands
+# (K and V in the forward and in `flash_bwd_dq`, Q and dO in
+# `flash_bwd_dkv`) of STREAM_MIN_SEQ tokens by 128 lanes each, held twice
+# over (Mosaic double-buffers a BlockSpec), leave half of the default
+# 16 MB of scoped VMEM to the blocks and the loops' temporaries.
+WHOLE_SEQ_BYTES = 2 * 2 * STREAM_MIN_SEQ * (128 + 128)
 NEG_INF = -1e30
 # `jax.ad_checkpoint.checkpoint_name`s on the forward kernel's two outputs
 # under differentiation. A `jax.checkpoint` whose policy saves these names
@@ -167,6 +179,18 @@ def _where_live(mask, x, fill):
     return x if mask is None else jnp.where(mask, x, fill)
 
 
+def _whole_seq_params(seq: int, d_qk: int, d_v: int):
+    """Compiler parameters of a whole-sequence kernel at these (padded)
+    widths: none (the default scoped VMEM) where the two operands fit
+    what the kernels were sized for, and beyond that (q and k of 256
+    lanes at 8,192 tokens) a limit that grows by what they hold more."""
+    held = 2 * 2 * seq * (d_qk + d_v)
+    if held <= WHOLE_SEQ_BYTES:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=16 * 2**20 + held - WHOLE_SEQ_BYTES)
+
+
 # ---------------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------------
@@ -208,11 +232,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
     # f32 at full rate, while f32 inputs drop it several-fold. All
     # accumulation stays f32 via preferred_element_type.
     q = q_ref[0]  # [block_q, d]
-    head_dim = q.shape[-1]
 
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, head_dim), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
 
     def body(kb, carry):
         m, l, acc = carry
@@ -238,6 +261,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
 def _fwd(q, k, v, sm_scale, causal, window, block_q, block_k, true_len,
          softcap=None):
     bh, seq, d = q.shape
+    d_v = v.shape[-1]
     # dispatch on the TRUE length: lcm padding of mixed block sizes must
     # not shift the documented threshold
     if true_len > STREAM_MIN_SEQ:
@@ -254,21 +278,22 @@ def _fwd(q, k, v, sm_scale, causal, window, block_q, block_k, true_len,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, seq, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, seq, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, seq, d_v), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, seq, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
         ],
         cost_estimate=pl.CostEstimate(
-            flops=int(4 * bh * seq * seq * d * (0.5 if causal else 1.0)),
+            flops=int(2 * bh * seq * seq * (d + d_v) * (0.5 if causal else 1.0)),
             bytes_accessed=q.size * 2 + k.size * 2 + v.size * 2,
             transcendentals=bh * seq * seq,
         ),
+        compiler_params=_whole_seq_params(seq, d, d_v),
         interpret=interpret(),
         name="flash_fwd",
     )(q, k, v)
@@ -327,6 +352,7 @@ def _fwd_streamed_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s,
 def _fwd_streamed(q, k, v, sm_scale, causal, window, block_q, block_k,
                   true_len, softcap=None):
     bh, seq, d = q.shape
+    d_v = v.shape[-1]
     n_kb = pl.cdiv(seq, block_k)
     grid = (bh, pl.cdiv(seq, block_q), n_kb)
     out, lse = pl.pallas_call(
@@ -339,20 +365,20 @@ def _fwd_streamed(q, k, v, sm_scale, causal, window, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, seq, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -463,6 +489,7 @@ def _bwd(sm_scale, causal, window, block_q, block_k, true_len, res, dout,
          softcap=None):
     q, k, v, out, lse = res
     bh, seq, d = q.shape
+    d_v = v.shape[-1]
     # [bh, 1, seq] to match the lse layout (TPU-tileable blocks)
     delta = jnp.sum(
         out.astype(jnp.float32) * dout.astype(jnp.float32), axis=-1
@@ -477,13 +504,14 @@ def _bwd(sm_scale, causal, window, block_q, block_k, true_len, res, dout,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, seq, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, seq, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, seq, d_v), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
+        compiler_params=_whole_seq_params(seq, d, d_v),
         interpret=interpret(),
         name="flash_bwd_dq",
     )(q, k, v, dout, lse, delta)
@@ -494,19 +522,20 @@ def _bwd(sm_scale, causal, window, block_q, block_k, true_len, res, dout,
         in_specs=[
             pl.BlockSpec((1, seq, d), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, seq, d_v), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, 1, seq), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, 1, seq), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, seq, d_v), q.dtype),
         ],
+        compiler_params=_whole_seq_params(seq, d, d_v),
         interpret=interpret(),
         name="flash_bwd_dkv",
     )(q, k, v, dout, lse, delta)
@@ -516,6 +545,11 @@ def _bwd(sm_scale, causal, window, block_q, block_k, true_len, res, dout,
 # ---------------------------------------------------------------------------
 # public entry
 # ---------------------------------------------------------------------------
+
+
+def _lane_width(d: int) -> int:
+    """A head size padded to whole lanes of 128."""
+    return d + (-d) % 128
 
 
 def _pad_d(x, dk):
@@ -542,12 +576,13 @@ def _flash_fwd(q, k, v, sm_scale, causal, window, block_q, block_k, true_len,
     # not re-run the kernel for either.
     out = checkpoint_name(out, FLASH_OUT)
     lse = checkpoint_name(lse, FLASH_LSE)
-    # Residuals store only the true head dim: padded columns are zeros by
+    # Residuals store only the true head dims: padded columns are zeros by
     # construction, so slicing here and re-padding in backward is exact —
     # and halves attention residual HBM for d=64 models.
+    d_qk, d_v = true_d
     res = (
-        q[..., :true_d], k[..., :true_d], v[..., :true_d],
-        out[..., :true_d], lse,
+        q[..., :d_qk], k[..., :d_qk], v[..., :d_v],
+        out[..., :d_v], lse,
     )
     return out, res
 
@@ -560,7 +595,8 @@ BWD_MAX_SEQ = 8192
 
 def _flash_bwd(sm_scale, causal, window, block_q, block_k, true_len, true_d,
                softcap, res, dout):
-    dk_width = dout.shape[-1]
+    v_width = dout.shape[-1]
+    qk_width = _lane_width(true_d[0])
     q, k, v, out, lse = res
     if true_len > BWD_MAX_SEQ:
         raise ValueError(
@@ -571,8 +607,8 @@ def _flash_bwd(sm_scale, causal, window, block_q, block_k, true_len, true_d,
             f"serves inference prefill only"
         )
     res = (
-        _pad_d(q, dk_width), _pad_d(k, dk_width), _pad_d(v, dk_width),
-        _pad_d(out, dk_width), lse,
+        _pad_d(q, qk_width), _pad_d(k, qk_width), _pad_d(v, v_width),
+        _pad_d(out, v_width), lse,
     )
     return _bwd(sm_scale, causal, window, block_q, block_k, true_len, res,
                 dout, softcap=softcap)
@@ -613,6 +649,7 @@ def flash_attention(
     softcap: Optional[float] = None,
 ) -> jax.Array:
     """Blocked attention over [batch, q_heads, seq, head_dim] tensors.
+    v's head size may differ from q's and k's (the output takes v's).
 
     GQA: k/v may have fewer heads (q_heads % kv_heads == 0); KV heads are
     broadcast to the query groups.
@@ -633,7 +670,9 @@ def flash_attention(
     unfused path.
     """
     b, hq, sq, d = q.shape
-    hkv = k.shape[1]
+    hkv, d_v = k.shape[1], v.shape[-1]
+    if k.shape[-1] != d:
+        raise ValueError(f"q heads are {d} wide and k heads {k.shape[-1]}")
     if window is not None:
         if not causal:
             raise ValueError("window requires causal=True (sliding window "
@@ -669,13 +708,14 @@ def flash_attention(
     # lengths that reach here (>= FLASH_MIN_SEQ) the extra MXU work still
     # beats the unfused path's materialized [T, T] softmax (2.65x at
     # s=1024 d=64 on v5e).
-    d_pad = (-d) % 128
-    if d_pad:
-        widen = ((0, 0), (0, 0), (0, 0), (0, d_pad))
-        q = jnp.pad(q, widen)
-        k = jnp.pad(k, widen)
-        v = jnp.pad(v, widen)
-    dk = d + d_pad
+    # q/k and v each to their own width: a value head narrower than the
+    # keys (latent attention) costs the PV and dV products its own lanes.
+    def lanes(x):
+        pad = (-x.shape[-1]) % 128
+        return jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, pad))) if pad else x
+
+    q, k, v = lanes(q), lanes(k), lanes(v)
+    dk, dvw = q.shape[-1], v.shape[-1]
 
     # Clamp blocks to the sequence, keeping them lane-aligned (128) so
     # mid-size sequences stay on the fused kernel (padding fills the rest).
@@ -715,15 +755,15 @@ def flash_attention(
     target = pl.cdiv(sq, lcm) * lcm
     qf = _pad_seq_to(q.reshape(b * hq, sq, dk), target)
     kf = _pad_seq_to(k.reshape(b * hq, sq, dk), target)
-    vf = _pad_seq_to(v.reshape(b * hq, sq, dk), target)
+    vf = _pad_seq_to(v.reshape(b * hq, sq, dvw), target)
     # A kernel's HLO instruction takes the innermost name on the stack.
     # Under this scope that is always the kernel's own name= (%flash_fwd.N,
     # %flash_bwd_dq.N); without one a bare jax.grad of this function would
     # wrap it (jvp(flash_fwd) reads %jvp_flash_fwd_.N).
     with jax.named_scope("flash_attention"):
         out = _flash(qf, kf, vf, sm_scale, causal, window, block_q, block_k,
-                     sq, d, softcap)
-    return out[:, :sq, :d].reshape(b, hq, sq, d)
+                     sq, (d, d_v), softcap)
+    return out[:, :sq, :d_v].reshape(b, hq, sq, d_v)
 
 
 def attention_reference(q, k, v, *, causal: bool = True,

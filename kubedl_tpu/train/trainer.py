@@ -61,7 +61,9 @@ class StepRecorder:
         `train.compile` with their `fun`, times and `cache`.
         `counters`: what the step returned beside its loss and gradient
         norm (an expert model's `moe_*` / `gmm_*`, a looped stack's
-        `loop_*`, a state-space model's `ssm_*`: llama.loss_and_stats);
+        `loop_*`, a state-space model's `ssm_*`, a several-stream model's
+        `hc_*`, a multi-token prediction module's `ce` and `mtp_*`:
+        llama.loss_and_stats);
         they ride the step's record, read when its loss is."""
         if compiled:
             # the steps before it ended while the host compiled, or
@@ -107,7 +109,8 @@ def parse_args(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--model", default=os.environ.get("KUBEDL_MODEL", "tiny"),
                    choices=["tiny", "bench-1b", "llama-7b", "lfm2-8b-a1b",
-                            "ouro-2.6b", "granite-4.0-h-micro"])
+                            "ouro-2.6b", "granite-4.0-h-micro",
+                            "xing4.0-29b-a4b"])
     p.add_argument("--steps", type=int, default=int(os.environ.get("KUBEDL_STEPS", 100)))
     p.add_argument("--batch", type=int, default=int(os.environ.get("KUBEDL_BATCH", 8)))
     p.add_argument("--seq-len", type=int, default=int(os.environ.get("KUBEDL_SEQ_LEN", 512)))
@@ -463,8 +466,9 @@ def main(argv=None) -> int:
                         step_loss = loss_on(a_mesh)
                     else:
                         def step_loss(params, batch):
-                            # (loss, an expert model's, a looped stack's or
-                            # a state-space model's counters, which ride the
+                            # (loss, the counters of an expert model, a
+                            # looped stack, state-space layers, several
+                            # streams or a multi-token module, which ride the
                             # train.step record: {} for a dense model run once)
                             return llama.loss_and_stats(
                                 params, batch, config, mesh=a_mesh, rules=rules)
@@ -836,7 +840,8 @@ def main(argv=None) -> int:
                             step + 1, metrics["loss"], t_step0, data_span.dur,
                             dispatch_span.dur, compile_log.since(compiles_before),
                             {k: v for k, v in metrics.items()
-                             if k.startswith(("moe_", "gmm_", "loop_", "ssm_"))})
+                             if k.startswith(("moe_", "gmm_", "loop_", "ssm_",
+                                              "hc_", "mtp_")) or k == "ce"})
             if prof is not None and prof.should_stop(step):
                 settle(metrics["loss"])
                 prof.stop()

@@ -626,3 +626,97 @@ def test_the_block_mask_changes_no_bit(shape, streamed, monkeypatch):
     for a, b, name in zip(new, old, ("out", "lse", "dq", "dk", "dv")):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
 
+
+
+# -- a value head size of its own (latent attention: keys 192, values 128) ------------
+
+
+def rand_unlike(d_qk, d_v, b=1, h=2, s=256, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (b, h, s, d_qk), jnp.float32)
+    k = jax.random.normal(ks[1], (b, h, s, d_qk), jnp.float32)
+    v = jax.random.normal(ks[2], (b, h, s, d_v), jnp.float32)
+    do = jax.random.normal(ks[3], (b, h, s, d_v), jnp.float32)
+    return q, k, v, do
+
+
+UNLIKE = [(192, 128), (24, 16)]
+
+
+@pytest.mark.parametrize("d_qk,d_v", UNLIKE)
+def test_value_width_unlike_the_keys_forward(d_qk, d_v):
+    q, k, v, _ = rand_unlike(d_qk, d_v)
+    scale = 0.14468 if d_qk == 192 else None
+    out = flash_attention(q, k, v, causal=True, sm_scale=scale)
+    assert out.shape == v.shape
+    ref = attention_reference(q, k, v, causal=True, sm_scale=scale)
+    np.testing.assert_allclose(out, ref, atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize("wrt", [0, 1, 2], ids=["dq", "dk", "dv"])
+@pytest.mark.parametrize("d_qk,d_v", UNLIKE)
+def test_value_width_unlike_the_keys_gradients(d_qk, d_v, wrt):
+    q, k, v, do = rand_unlike(d_qk, d_v)
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a, causal=True) * do)
+    got = jax.grad(loss(flash_attention), argnums=wrt)(q, k, v)
+    want = jax.grad(loss(attention_reference), argnums=wrt)(q, k, v)
+    assert got.shape == (q, k, v)[wrt].shape
+    np.testing.assert_allclose(got, want, atol=5e-3, rtol=5e-3)
+
+
+def test_each_width_is_padded_to_its_own_lanes(monkeypatch):
+    """q, k, dq, dk ride at 256 lanes and v, o, do, dv at 128; the
+    residuals keep the true 192 and 128."""
+    q, k, v, do = rand_unlike(192, 128, s=128)
+    seen = {}
+    real_fwd, real_bwd = fa._fwd, fa._bwd
+
+    def fwd(q, k, v, *a, **kw):
+        seen["fwd"] = (q.shape[-1], k.shape[-1], v.shape[-1])
+        out = real_fwd(q, k, v, *a, **kw)
+        seen["out"] = out[0].shape[-1]
+        return out
+
+    def bwd(*a, **kw):
+        res, dout = a[6], a[7]
+        seen["bwd"] = tuple(x.shape[-1] for x in res[:4]) + (dout.shape[-1],)
+        grads = real_bwd(*a, **kw)
+        seen["grads"] = tuple(g.shape[-1] for g in grads)
+        return grads
+
+    monkeypatch.setattr(fa, "_fwd", fwd)
+    monkeypatch.setattr(fa, "_bwd", bwd)
+    jax.grad(lambda *a: jnp.sum(flash_attention(*a, causal=True) * do), (0, 1, 2))(q, k, v)
+    assert seen == {"fwd": (256, 256, 128), "out": 128,
+                    "bwd": (256, 256, 128, 128, 128), "grads": (256, 256, 128)}
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_equal_widths_are_the_one_width_kernels_bit_for_bit(d):
+    """Where v is as wide as q and k the kernels are the ones they were:
+    no compiler parameter, the one padded width for every operand, and,
+    bit for bit, what the two-width path gives for the same v handed over
+    with zero columns up to the next lane tile's width cut off again."""
+    q, k, v, do = rand_unlike(d, d, s=256)
+    loss = lambda *a: jnp.sum(flash_attention(*a, causal=True) * do)
+    out = flash_attention(q, k, v, causal=True)
+    grads = jax.grad(loss, (0, 1, 2))(q, k, v)
+    assert fa._whole_seq_params(8192, 128, 128) is None
+    assert fa._whole_seq_params(8192, 256, 128).vmem_limit_bytes == 20 * 2**20
+    # the same numbers through the two-width path: q and k carry 128 zero
+    # columns more (another lane tile), which add nothing to any score;
+    # the scale is said outright, since it follows q's width
+    wide = lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, 128)))
+    scale = 1.0 / d ** 0.5
+    out2 = flash_attention(wide(q), wide(k), v, causal=True, sm_scale=scale)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
+    g2 = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        wide(q), wide(k), v, causal=True, sm_scale=scale) * do), (0, 1, 2))(q, k, v)
+    for a, b, name in zip(grads, g2, ("dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+
+
+def test_unlike_q_and_k_widths_are_refused():
+    q, k, v, _ = rand_unlike(64, 64)
+    with pytest.raises(ValueError, match="q heads are 64 wide and k heads 32"):
+        flash_attention(q, k[..., :32], v)

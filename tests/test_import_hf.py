@@ -300,9 +300,14 @@ def test_rope_scaling_linear_and_rejections():
     # highest frequency (short wavelength) untouched; lowest divided
     assert l3[0] == pytest.approx(base[0])
     assert l3[-1] == pytest.approx(base[-1] / 8.0)
-    # monotype guard: unknown kinds refuse loudly
+    # monotype guard: unknown kinds refuse loudly (yarn is a kind since
+    # latent attention trains: tests/test_latent_model.py has its table)
     with pytest.raises(ValueError, match="unknown rope scaling"):
-        _rope_freqs(8, 10000.0, RopeScaling(kind="yarn", factor=2.0))
+        _rope_freqs(8, 10000.0, RopeScaling(kind="dynamic", factor=2.0))
+    yarn = _rope_freqs(8, 10000.0, RopeScaling(
+        kind="yarn", factor=2.0, original_max_position_embeddings=64))
+    assert yarn[0] == pytest.approx(base[0])
+    assert yarn[-1] == pytest.approx(base[-1] / 2.0)
 
     hf_config = transformers.LlamaConfig(
         vocab_size=64, hidden_size=32, intermediate_size=64,

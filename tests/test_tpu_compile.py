@@ -112,6 +112,22 @@ def test_flash_fwd_bwd_compiles(one_chip, on_tpu, shape, window):
     assert _flash_calls(text) == dict.fromkeys(FLASH_INSTRUCTIONS, 1)
 
 
+def test_flash_with_a_value_width_of_its_own_compiles(one_chip, on_tpu):
+    """The latent-attention cell's shape: keys of 192 padded to 256 lanes
+    beside values of 128, whole sequences of 8,192 in a scoped VMEM that
+    grows by what the wider operands hold."""
+    qk = jax.ShapeDtypeStruct((2, 32, 8192, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 32, 8192, 128), jnp.bfloat16, sharding=one_chip)
+
+    def f(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, sm_scale=0.14468).astype(jnp.float32))
+
+    text = _compile(jax.value_and_grad(f, argnums=(0, 1, 2)), qk, qk, v)
+    assert _flash_calls(text) == dict.fromkeys(FLASH_INSTRUCTIONS, 1)
+    assert "bf16[64,8192,256]" in text and "bf16[64,8192,128]" in text
+
+
 def test_flash_streamed_fwd_compiles(one_chip, on_tpu):
     """Past STREAM_MIN_SEQ the forward is the K-streaming kernel (serving
     prefill), one block a grid step under `pl.when(live)`."""

@@ -428,21 +428,32 @@ def recompile_run(tmp_path_factory):
     from kubedl_tpu.native import loader
     from kubedl_tpu.train import trainer
 
-    tmp = tmp_path_factory.mktemp("recompile")
-    shard = tmp / "shard-0.bin"
-    shard.write_bytes(b"\0" * 64)
     steps = 6
-    with pytest.MonkeyPatch.context() as mp:
-        trace_dir = _trace_env(mp, tmp)
-        mp.setattr(loader, "TokenLoader", _OddBatchLoader)
-        out = io.StringIO()
-        with redirect_stdout(out):
-            rc = trainer.main([
-                "--model", "tiny", "--batch", "8", "--seq-len", "17",
-                "--steps", str(steps), "--log-every", "1000",
-                "--data-path", str(shard),
-                "--profile-dir", str(tmp / "prof"), "--profile-steps", "3"])
-    assert rc == 0, out.getvalue()
+
+    def run_once(tmp):
+        shard = tmp / "shard-0.bin"
+        shard.write_bytes(b"\0" * 64)
+        with pytest.MonkeyPatch.context() as mp:
+            trace_dir = _trace_env(mp, tmp)
+            mp.setattr(loader, "TokenLoader", _OddBatchLoader)
+            out = io.StringIO()
+            with redirect_stdout(out):
+                rc = trainer.main([
+                    "--model", "tiny", "--batch", "8", "--seq-len", "17",
+                    "--steps", str(steps), "--log-every", "1000",
+                    "--data-path", str(shard),
+                    "--profile-dir", str(tmp / "prof"), "--profile-steps", "3"])
+        assert rc == 0, out.getvalue()
+        return trace_dir
+
+    # The same run once before, its records thrown away: JAX's own helpers
+    # for an odd batch (`_multi_slice`) are compiled by whichever call of
+    # this process meets them first, and a dispatch's compiles are joined
+    # into its record's `fun`; after this run they are this worker's
+    # already, whatever tests it ran before (PERF.md Open question 30).
+    run_once(tmp_path_factory.mktemp("recompile_warm"))
+    tmp = tmp_path_factory.mktemp("recompile")
+    trace_dir = run_once(tmp)
     return {"trace_dir": trace_dir, "profile_dir": str(tmp / "prof"),
             "spans": load_spans(trace_dir), "steps": steps, "tmp": tmp}
 
