@@ -177,6 +177,8 @@ def _span_detail(attrs) -> str:
         detail.append(f"sinkhorn_residual={attrs['hc_sinkhorn_residual']:.1e}")
         detail.append(f"pre={attrs['hc_pre_mean']:.3f}")
         detail.append(f"post={attrs['hc_post_mean']:.3f}")
+        if "hc_kernel_mappings" in attrs:  # a record from before the kernels has none
+            detail.append(f"kernel_mappings={int(attrs['hc_kernel_mappings'])}")
     if "mtp_ce" in attrs:
         detail.append(f"ce={attrs['ce']:.4f}")
         detail.append(f"mtp_ce={attrs['mtp_ce']:.4f}")

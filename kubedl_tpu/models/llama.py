@@ -28,8 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kubedl_tpu.models.hyper import (WORST_OF, finish_stats, hc_init, hc_map,
-                                     hc_mix, hc_param_specs, hc_pre)
+from kubedl_tpu.models.hyper import (WORST_OF, finish_stats, hc_branch, hc_init,
+                                     hc_merge, hc_param_specs, hc_streams, hc_sum)
 from kubedl_tpu.models.mla import mla_init, mla_param_specs, mla_qkv
 from kubedl_tpu.models.moe import moe_init, moe_layer, moe_param_specs
 from kubedl_tpu.models.quant import matmul as _mm
@@ -892,17 +892,18 @@ def _proj(h, layer, name, lora=None, adapter_ids=None):
     return out
 
 
-def _branch_input(x, hc: Optional[Dict], config: LlamaConfig):
+def _branch_input(x, hc: Optional[Dict], config: LlamaConfig, mesh=None,
+                  rules=None):
     """(what a sublayer reads of the residual, what its output goes back
     onto): x itself and None, or, where the layer carries the sublayer's
-    hyper-connection leaves `hc`, the mix of the streams x [b, t, n, d]
-    by the mappings made from them, and (the streams, the mappings)
-    (models/hyper.py)."""
+    hyper-connection leaves `hc`, the mix of the streams x [b, t, n*d]
+    by the mappings made from them, and the streams with the mappings
+    (models/hyper.py hc_branch)."""
     if hc is None:
         return x, None
-    mapping = hc_map(x, hc, config.hc_sinkhorn_iters, config.hc_eps,
-                     (config.hc_res_clamp_min, config.hc_res_clamp_max))
-    return hc_pre(x, mapping), (x, mapping)
+    return hc_branch(x, hc, config.hc_mult, config.hc_sinkhorn_iters,
+                     config.hc_eps, (config.hc_res_clamp_min,
+                                     config.hc_res_clamp_max), mesh, rules)
 
 
 def _add_branch(x, out, config: LlamaConfig, onto=None):
@@ -915,12 +916,12 @@ def _add_branch(x, out, config: LlamaConfig, onto=None):
         out = (out.astype(jnp.float32)
                * config.residual_multiplier).astype(x.dtype)
     if onto is not None:
-        return hc_mix(onto[0], out, onto[1])
+        return hc_merge(onto, out)
     return x + out
 
 
 def _hc_counters(onto) -> Dict:
-    return {} if onto is None else onto[1]["stats"]
+    return {} if onto is None else onto.mapping["stats"]
 
 
 def _add_counters(into: Dict, new: Dict) -> Dict:
@@ -1067,7 +1068,7 @@ def _mixer_block(x, layer, config: LlamaConfig, positions, mesh, rules,
     """The layer's token mixer, by what the layer holds, and its counters
     ({} but for a state-space layer and for a layer of several streams,
     whose mixer reads a mix of them: `_branch_input`)."""
-    u, onto = _branch_input(x, layer.get("hc_mixer"), config)
+    u, onto = _branch_input(x, layer.get("hc_mixer"), config, mesh, rules)
     if "ssm_in" in layer:
         y, stats = _ssm_block(u, layer, config, mesh, rules, onto)
         return y, {**stats, **_hc_counters(onto)}
@@ -1086,7 +1087,7 @@ def _mlp_block(x, layer, config: LlamaConfig, mesh=None, rules=None,
     dense one. lora/adapter_ids: per-row serving adapters on w1/w3/w2
     (see _proj); MoE layers carry no dense projections for adapters to
     target."""
-    x, onto = _branch_input(x, layer.get("hc_mlp"), config)
+    x, onto = _branch_input(x, layer.get("hc_mlp"), config, mesh, rules)
     h = rms_norm(x, layer["mlp_norm"], config.rms_eps, config.norm_offset)
     stats = {}
     if "moe" in layer:
@@ -1123,7 +1124,7 @@ def _constrainer(mesh, rules):
 def _layer_maker(config: LlamaConfig, positions, mesh, rules, context_size):
     """window -> the function that applies one layer, `layer_fn((x, aux),
     layer) -> ((x, aux), counters)`, rematerialised as the config says.
-    x is [b, t, d], or [b, t, n, d] where the layers mix several
+    x is [b, t, d], or [b, t, n*d] where the layers mix several
     streams. The stack and the multi-token prediction module's block run
     their layers through it."""
     constrain = _constrainer(mesh, rules)
@@ -1193,10 +1194,7 @@ def _backbone(
         x = constrain(x, "batch", "seq", None)
         if config.hc_mult > 1:
             # every stream starts as the embedding
-            x = constrain(
-                jnp.broadcast_to(x[:, :, None, :], (b, t, config.hc_mult,
-                                                    x.shape[-1])),
-                "batch", "seq", None, None)
+            x = constrain(hc_streams(x, config.hc_mult), "batch", "seq", None)
 
     make_layer_fn = _layer_maker(config, positions, mesh, rules, context_size)
 
@@ -1208,8 +1206,7 @@ def _backbone(
             (x, aux), counters = make_layer_fn(config.window_for(i))((x, aux), layer)
             _add_counters(stats, counters)
         if config.hc_mult > 1:
-            with jax.named_scope("hc_mix"):
-                x = jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
+            x = hc_sum(x, config.hc_mult)
         return x, aux, stats
 
     if not config.looped:
@@ -1466,7 +1463,7 @@ def _mtp_hidden(h, params, tokens, config: LlamaConfig, mesh, rules):
     over z (under hyper-connections, on streams that each start as z); E
     is the model's embedding. Position i has then read tokens 0..i+1."""
     mtp = params["mtp"]
-    b, t, d = h.shape
+    b, t, _ = h.shape
     constrain = _constrainer(mesh, rules)
     norm = lambda x, w: rms_norm(x, w, config.rms_eps, config.norm_offset)
     with jax.named_scope("embed"):
@@ -1480,15 +1477,12 @@ def _mtp_hidden(h, params, tokens, config: LlamaConfig, mesh, rules):
             mtp["w_eh"]).astype(config.dtype)
     z = constrain(z, "batch", "seq", None)
     if config.hc_mult > 1:
-        z = constrain(
-            jnp.broadcast_to(z[:, :, None, :], (b, t, config.hc_mult, d)),
-            "batch", "seq", None, None)
+        z = constrain(hc_streams(z, config.hc_mult), "batch", "seq", None)
     layer_fn = _layer_maker(config, _positions(b, t), mesh, rules,
                             _context_size(config, mesh))(config.sliding_window)
     (z, aux), stats = layer_fn((z, jnp.zeros((), jnp.float32)), mtp["block"])
     if config.hc_mult > 1:
-        with jax.named_scope("hc_mix"):
-            z = jnp.sum(z.astype(jnp.float32), axis=2).astype(z.dtype)
+        z = hc_sum(z, config.hc_mult)
     return z, aux, stats
 
 

@@ -415,15 +415,20 @@ def test_counters_count_mappings_mixing_and_the_modules_loss():
     assert float(stats["moe_rows_held"]) == 3 * 2 * 2 * SEQ
 
 
-def test_span_detail_prints_the_new_counters():
+# a record from before the hyper-connections' kernels has no hc_kernel_mappings
+@pytest.mark.parametrize("kernel_mappings,shown", [(None, ""), (12.0, " kernel_mappings=12"),
+                                                   (0.0, " kernel_mappings=0")])
+def test_span_detail_prints_the_new_counters(kernel_mappings, shown):
     from kubedl_tpu.cli import _span_detail
 
-    detail = _span_detail({"step": 2, "hc_mappings": 12.0, "hc_res_offdiag": 0.2912,
-                           "hc_sinkhorn_residual": 3e-7, "hc_pre_mean": 0.5,
-                           "hc_post_mean": 1.0, "ce": 9.7, "mtp_ce": 9.71,
-                           "mtp_positions": 16382.0})
-    assert detail == ("step=2 hc_mappings=12 offdiag=0.291 sinkhorn_residual=3.0e-07 "
-                      "pre=0.500 post=1.000 ce=9.7000 mtp_ce=9.7100 mtp_positions=16382")
+    attrs = {"step": 2, "hc_mappings": 12.0, "hc_res_offdiag": 0.2912,
+             "hc_sinkhorn_residual": 3e-7, "hc_pre_mean": 0.5,
+             "hc_post_mean": 1.0, "ce": 9.7, "mtp_ce": 9.71, "mtp_positions": 16382.0}
+    if kernel_mappings is not None:
+        attrs["hc_kernel_mappings"] = kernel_mappings
+    assert _span_detail(attrs) == (
+        "step=2 hc_mappings=12 offdiag=0.291 sinkhorn_residual=3.0e-07 "
+        f"pre=0.500 post=1.000{shown} ce=9.7000 mtp_ce=9.7100 mtp_positions=16382")
 
 
 # -- what cannot run such a model yet ------------------------------------------------------

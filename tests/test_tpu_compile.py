@@ -331,6 +331,57 @@ def test_state_space_loss_holds_one_piece_of_logits_and_float32_carries(one_chip
     assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
 
 
+# ops/hyper_mix.py's four kernels, likewise
+HC_INSTRUCTIONS = ("%hc_pre_fwd.", "%hc_post_fwd.", "%hc_post_bwd.", "%hc_pre_bwd.")
+
+
+def test_one_hyper_connection_mapping_compiles_with_no_float32_copy_of_the_streams(
+        one_chip, on_tpu):
+    """One mapping at the Xing4.0 cell's shape, forward and backward: four
+    streams of 3,584 as one [2, 8192, 14336] bf16 array, read and written
+    by the four kernels once each; no float32 copy of the streams and no
+    [.., 4, 3584] layout of them exists."""
+    from kubedl_tpu.models import hyper
+
+    n, d = 4, 3584
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    hc = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda: hyper.hc_init(jax.random.PRNGKey(0), d, n)))
+    x, y = sds((2, 8192, n * d), jnp.bfloat16), sds((2, 8192, d), jnp.bfloat16)
+
+    def f(x, y, hc, du, dx):
+        def mapping(x, y, hc):
+            u, onto = hyper.hc_branch(x, hc, n, 20, 1e-6, (-30.0, 30.0))
+            return u, hyper.hc_merge(onto, y)
+        out, vjp = jax.vjp(mapping, x, y, hc)
+        return out, vjp((du, dx))
+
+    compiled = jax.jit(f).lower(x, y, hc, y, x).compile()
+    text = compiled.as_text()
+    assert _calls(text, HC_INSTRUCTIONS) == dict.fromkeys(HC_INSTRUCTIONS, 1)
+    assert "f32[2,8192,14336]" not in text and "[2,8192,4,3584]" not in text
+    # the kernels' blocks and the mappings' [tokens, 128] arrays: well
+    # under one float32 copy of the streams (0.94 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.9e9
+
+
+def test_latent_step_holds_each_hyper_connection_kernel_its_times(one_chip, on_tpu):
+    """The Xing4.0 preset at its published widths cut to one dense block and
+    the module (four mappings), 1,024 tokens: under full remat each
+    mapping's `hc_pre_fwd` runs again in the backward pass, and so does
+    `hc_post_fwd` of the mappings whose streams the block's recompute
+    needs (the mixer's: the FFN reads them), never the FFN's."""
+    config = llama.LlamaConfig.xing4_0_29b_a4b(
+        n_layers=1, n_dense_layers=1, vocab_size=16384, max_seq_len=1024)
+    params = _abstract_params(
+        config, lambda t: jax.tree_util.tree_map(lambda _: one_chip, t))
+    tokens = jax.ShapeDtypeStruct((1, SEQ_LEN), jnp.int32, sharding=one_chip)
+    text = _compile(jax.value_and_grad(lambda p, t: llama.loss_fn(p, t, config)),
+                    params, tokens)
+    assert _calls(text, HC_INSTRUCTIONS) == {
+        "%hc_pre_fwd.": 8, "%hc_post_fwd.": 6, "%hc_post_bwd.": 4, "%hc_pre_bwd.": 4}
+
+
 def test_state_space_layer_under_fsdp_rides_a_shard_map(topo, on_tpu):
     """One state-space layer at the published widths under fsdp: 4 on the
     described 2x2, a sequence of 1,024 a chip: GSPMD cannot partition a

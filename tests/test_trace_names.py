@@ -86,7 +86,8 @@ def test_kernel_names_are_the_ones_the_metrics_read():
     names = sorted(n for p in PALLAS_CALLS for n in _literal_names(p.values[0]))
     assert names == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd",
                      "flash_fwd_streamed", "gmm", "gmm_drhs", "gmm_scaled",
-                     "gmm_swiglu", "moe_gather", "ssm_conv_bwd",
+                     "gmm_swiglu", "hc_post_bwd", "hc_post_fwd", "hc_pre_bwd",
+                     "hc_pre_fwd", "moe_gather", "ssm_conv_bwd",
                      "ssm_conv_fwd", "ssm_scan_bwd", "ssm_scan_fwd"]
     with open(os.path.join(ROOT, "benchmarks", "metrics",
                            "flash_bwd_roofline.train.json")) as f:
