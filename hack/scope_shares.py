@@ -4,7 +4,8 @@
     python hack/scope_shares.py <trace dir or .xplane.pb> [out.json]
 
 The model's and the step's `jax.named_scope`s (embed, attn > attn_core
-(a latent layer's attn > mla_q, mla_kv, attn_core), hc_map and hc_mix
+(a latent layer's attn > mla_q, mla_kv, attn_core; a gated layer's
+attn > attn_core, attn_gate), hc_map and hc_mix
 around a several-stream layer's sublayers, mtp around a multi-token
 prediction module (`mtp>attn_core`, ...), short_conv, ssm > ssm_conv / ssm_scan > ssm_carry / ssm_gate_norm,
 mlp > moe_route / moe_permute / moe_experts / moe_combine / shared_expert,
@@ -51,7 +52,7 @@ from benchmarks import trace as tr  # interval arithmetic only; no JAX
 
 # innermost first: an operation under attn/attn_core counts as attn_core
 SCOPES = ("hc_map", "hc_mix", "mla_q", "mla_kv", "shared_expert",
-          "attn_core", "attn", "short_conv",
+          "attn_core", "attn_gate", "attn", "short_conv",
           "ssm_carry", "ssm_scan", "ssm_conv", "ssm_gate_norm", "ssm",
           "moe_route", "moe_permute",
           "moe_experts", "moe_combine", "mlp", "head_loss", "exit_gate",

@@ -149,8 +149,10 @@ def _span_detail(attrs) -> str:
     was, of a looped stack's step its passes and exit distribution (the
     `loop_*` counters), of a state-space model's step what its scans
     carried (the `ssm_*` counters), of a several-stream model's step how
-    its streams mixed (the `hc_*` counters) and of a multi-token
-    prediction module's step its two losses (docs/observability.md)."""
+    its streams mixed (the `hc_*` counters), of a step of gated or
+    per-layer-RoPE attention its layer kinds and mean gate (the `attn_*`
+    counters) and of a multi-token prediction module's step its two
+    losses (docs/observability.md)."""
     detail = [f"{k}={attrs[k]}" for k in
               ("step", "stage", "cause", "outcome", "shape", "reason", "error",
                "fun", "cache")
@@ -179,6 +181,11 @@ def _span_detail(attrs) -> str:
         detail.append(f"post={attrs['hc_post_mean']:.3f}")
         if "hc_kernel_mappings" in attrs:  # a record from before the kernels has none
             detail.append(f"kernel_mappings={int(attrs['hc_kernel_mappings'])}")
+    if "attn_windowed_layers" in attrs:
+        detail.append(f"windowed_layers={int(attrs['attn_windowed_layers'])}")
+        detail.append(f"nope_layers={int(attrs['attn_nope_layers'])}")
+        if "attn_gate_mean" in attrs:
+            detail.append(f"gate={attrs['attn_gate_mean']:.3f}")
     if "mtp_ce" in attrs:
         detail.append(f"ce={attrs['ce']:.4f}")
         detail.append(f"mtp_ce={attrs['mtp_ce']:.4f}")

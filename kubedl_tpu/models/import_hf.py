@@ -57,6 +57,15 @@ def config_from_hf(hf_config, **overrides) -> LlamaConfig:
             "written for their leaves, nor for the rotary columns' "
             "interleaved layout (LlamaConfig.xing4_0_29b_a4b trains the "
             "architecture from seeded weights)")
+    if model_type == "afmoe":
+        raise ValueError(
+            f"model_type {model_type!r} holds gated attention layers (the "
+            "core's output times sigmoid(h wg) before o_proj) with RoPE in "
+            "its sliding_attention layers and none in its full_attention "
+            "ones, and routed experts beside a shared one: no checkpoint "
+            "mapping is written for the gate's, the experts' or the four "
+            "norms' leaves (LlamaConfig.trinity_large_preview trains the "
+            "architecture from seeded weights)")
     if model_type not in ("llama", "mistral", "gemma", "gemma2", "qwen2"):
         raise ValueError(
             f"unsupported model_type {model_type!r} "
@@ -171,6 +180,7 @@ def config_from_hf(hf_config, **overrides) -> LlamaConfig:
         raise ValueError("attention/mlp bias tensors not supported "
                          "(this stack's projections are bias-free)")
     cfg = LlamaConfig(**kw)
+    cfg.require_plain_attention("the HF importer")
     expect_hd = hf_config.hidden_size // hf_config.num_attention_heads
     got_hd = getattr(hf_config, "head_dim", None) or expect_hd
     if cfg.head_dim != got_hd:
@@ -185,6 +195,8 @@ def params_from_state_dict(
 ) -> Dict:
     """Our param tree from an HF Llama state dict (torch tensors or arrays)."""
     import jax.numpy as jnp
+
+    config.require_plain_attention("the HF importer (params_from_state_dict)")
 
     def arr(key: str, transpose: bool = False):
         t = state_dict[key]

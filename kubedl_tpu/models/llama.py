@@ -239,6 +239,14 @@ class LlamaConfig:
     # mean). Training only; 0 or 1 modules.
     num_nextn_predict_layers: int = 0
     mtp_loss_weight: float = 0.3
+    # Gated attention (Trinity's): the core's output times sigmoid(h W_g),
+    # one gate a head and channel from the normed input h that feeds q, k
+    # and v, before the output projection (the wg leaf)
+    attn_gate: bool = False
+    # RoPE per layer: None = use_rope in every layer, else a tuple of
+    # n_layers booleans (see rope_for()). Like layer_windows, a static
+    # choice a layer: the stack closes over it
+    layer_rope: Optional[tuple] = None
 
     def __post_init__(self):
         if self.sliding_window is not None and self.sliding_window < 1:
@@ -255,6 +263,19 @@ class LlamaConfig:
                 if w is not None and w < 1:
                     raise ValueError(
                         f"layer_windows[{i}] must be >= 1 or None, got {w}")
+        if self.layer_rope is not None:
+            if len(self.layer_rope) != self.n_layers:
+                raise ValueError(
+                    f"layer_rope has {len(self.layer_rope)} entries "
+                    f"for {self.n_layers} layers")
+            for i, r in enumerate(self.layer_rope):
+                if not isinstance(r, bool):
+                    raise ValueError(f"layer_rope[{i}] must be a bool, got {r!r}")
+        if self.latent and (self.attn_gate or self.layer_rope is not None):
+            raise ValueError(
+                "a latent-attention (MLA) layer has no output gate and ropes "
+                "its decoupled key always: attn_gate and layer_rope are not "
+                "combined with kv_lora_rank")
         if self.layer_types is not None:
             if len(self.layer_types) != self.n_layers:
                 raise ValueError(
@@ -353,9 +374,28 @@ class LlamaConfig:
                 f"visited {self.total_ut_steps} times) and an exit by the "
                 f"gate; it trains (llama.loss_and_stats) and is not served")
 
+    def require_plain_attention(self, what: str) -> None:
+        """Refusal of the paths whose attention layer is wq, wk, wv and wo
+        alone, with RoPE in every layer or in none (use_rope)."""
+        if self.attn_gate:
+            raise NotImplementedError(
+                f"{what} reads an attention layer's wq, wk, wv and wo alone: "
+                f"a gated attention layer (attn_gate) multiplies the core's "
+                f"output by sigmoid(h wg), one gate a head and channel, "
+                f"before wo; it trains (llama.loss_and_stats) and is not "
+                f"served")
+        if self.layer_rope is not None:
+            raise NotImplementedError(
+                f"{what} applies RoPE in every layer or in none (use_rope): "
+                f"RoPE chosen per layer (layer_rope: "
+                f"{self.layer_rope.count(False)} of {self.n_layers} layers "
+                f"without a position embedding) needs the choice a layer; "
+                f"it trains (llama.loss_and_stats) and is not served")
+
     def require_kv_state_only(self, what: str) -> None:
         """Refusal of the paths that carry state from token to token and
         know keys and values alone, one entry a layer."""
+        self.require_plain_attention(what)
         self.require_single_pass(what)
         if self.layer_types is not None and "conv" in self.layer_types:
             raise NotImplementedError(
@@ -410,6 +450,13 @@ class LlamaConfig:
             return self.layer_windows[i]
         return self.sliding_window
 
+    def rope_for(self, i: int) -> bool:
+        """Whether layer i ropes its q and k: layer_rope wins, else
+        use_rope."""
+        if self.layer_rope is not None:
+            return self.layer_rope[i]
+        return self.use_rope
+
     @property
     def has_windows(self) -> bool:
         return self.sliding_window is not None or (
@@ -454,6 +501,7 @@ class LlamaConfig:
             "ouro-2.6b": LlamaConfig.ouro_2_6b,
             "granite-4.0-h-micro": LlamaConfig.granite_4_0_h_micro,
             "xing4.0-29b-a4b": LlamaConfig.xing4_0_29b_a4b,
+            "trinity-large-preview": LlamaConfig.trinity_large_preview,
         }
         if name not in factories:
             raise ValueError(
@@ -552,6 +600,35 @@ class LlamaConfig:
         return LlamaConfig(**defaults)
 
     @staticmethod
+    def trinity_large_preview(**kw) -> "LlamaConfig":
+        """Trinity-Large-Preview at its published sizes (arcee-ai/
+        Trinity-Large-Preview config.json, model_type afmoe): 60 layers of
+        hidden 3,072 with four norms each; gated GQA of 48 query and 8
+        key/value heads of 128 with q/k head norms, windowed at 4,096 with
+        RoPE in three layers of four and full with no position embedding
+        in every fourth; six leading dense SwiGLUs of 12,288, then 4 of 256
+        experts of 3,072 by a sigmoid router with a selection bias, weights
+        times 2.448, beside one shared expert; embeddings times
+        sqrt(3,072); an untied head over 200,192. 398.6B parameters,
+        about 13.4B active."""
+        n = kw.get("n_layers", 60)
+        windows = tuple(None if i % 4 == 3 else 4096 for i in range(n))
+        defaults = dict(
+            vocab_size=200192, d_model=3072, n_layers=n, n_heads=48,
+            n_kv_heads=8, head_dim_override=128, d_ff=12288,
+            max_seq_len=262144, rope_theta=10000.0, rms_eps=1e-5,
+            layer_windows=windows,
+            layer_rope=tuple(w is not None for w in windows),
+            attn_gate=True, qk_norm=True, post_block_norms=True,
+            embed_scale=3072 ** 0.5, n_experts=256, expert_top_k=4,
+            n_dense_layers=6, d_ff_expert=3072, moe_router="sigmoid",
+            n_shared_experts=1, routed_scaling_factor=2.448,
+            moe_norm_eps=1e-20,
+        )
+        defaults.update(kw)
+        return LlamaConfig(**defaults)
+
+    @staticmethod
     def bench_150m(**kw) -> "LlamaConfig":
         """~170M params — the single-chip quick-proof bench size."""
         defaults = dict(
@@ -605,6 +682,8 @@ def param_specs(config: LlamaConfig, rules: Optional[ShardingRules] = None) -> D
                               "bv": r.spec("heads")})
             if config.qk_norm:
                 layer.update({"q_norm": r.spec(None), "k_norm": r.spec(None)})
+            if config.attn_gate:
+                layer["wg"] = r.spec("embed", "heads")
         layer["mlp_norm"] = r.spec("embed")
         if config.post_block_norms:
             layer.update({"post_attn_norm": r.spec("embed"),
@@ -691,6 +770,9 @@ def init(config: LlamaConfig, key: jax.Array) -> Dict:
                                      jnp.float32)
                 layer["q_norm"] = head_norm
                 layer["k_norm"] = head_norm
+            if config.attn_gate:
+                # a key of its own: a model with no gate keeps its weights
+                layer["wg"] = dense(jax.random.fold_in(key, 8), (d, nq * hd), d)
         layer["mlp_norm"] = norm_init
         if config.post_block_norms:
             layer["post_attn_norm"] = norm_init
@@ -1006,25 +1088,37 @@ def _latent_attention(h, layer, config: LlamaConfig, positions, mesh, rules,
     return _mm(attn, layer["wo"]).astype(h.dtype)
 
 
+def _attention_counters(config: LlamaConfig, window, rope: bool) -> Dict:
+    """An attention layer's attn_windowed_layers and attn_nope_layers,
+    where the model reports them: one with the gate or with RoPE chosen
+    per layer."""
+    if not (config.attn_gate or config.layer_rope is not None):
+        return {}
+    return {"attn_windowed_layers": jnp.asarray(window is not None, jnp.float32),
+            "attn_nope_layers": jnp.asarray(not rope, jnp.float32)}
+
+
 @jax.named_scope("attn")
 def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
-                     context_size, window=None, onto=None):
-    """The residual after the layer's attention over x [b, t, d]; `onto`
-    as `_add_branch` takes it."""
+                     context_size, window=None, onto=None, rope=None):
+    """(the residual after the layer's attention over x [b, t, d], the
+    layer's attn_* counters); `onto` as `_add_branch` takes it, `rope`
+    whether q and k are roped (None = use_rope)."""
     b, t, d = x.shape
     hd, nq, nkv = config.head_dim, config.n_heads, config.n_kv_heads
+    rope = config.use_rope if rope is None else rope
     h = rms_norm(x, layer["attn_norm"], config.rms_eps, config.norm_offset)
     if "wkv_a" in layer:
         out = _latent_attention(h, layer, config, positions, mesh, rules,
                                 context_size, window)
-        return _add_branch(x, out, config, onto)
+        return _add_branch(x, out, config, onto), {}
     q = _proj(h, layer, "q").reshape(b, t, nq, hd).transpose(0, 2, 1, 3)
     k = _proj(h, layer, "k").reshape(b, t, nkv, hd).transpose(0, 2, 1, 3)
     v = _proj(h, layer, "v").reshape(b, t, nkv, hd).transpose(0, 2, 1, 3)
     if "q_norm" in layer:
         q = rms_norm(q, layer["q_norm"], config.rms_eps, config.norm_offset)
         k = rms_norm(k, layer["k_norm"], config.rms_eps, config.norm_offset)
-    if config.use_rope:
+    if rope:
         q = _rope(q, positions, config.rope_theta, config.rope_scaling)
         k = _rope(k, positions, config.rope_theta, config.rope_scaling)
     if config.q_prescale != 1.0:
@@ -1035,11 +1129,20 @@ def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
         v = jnp.repeat(v, rep, axis=1)
     attn = _attention_core(q, k, v, config, mesh, rules, context_size, window)
     attn = attn.transpose(0, 2, 1, 3).reshape(b, t, nq * hd)
+    stats = _attention_counters(config, window, rope)
+    if "wg" in layer:
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(jnp.matmul(
+                h, layer["wg"], preferred_element_type=jnp.float32))
+            attn = attn * gate.astype(attn.dtype)
+            stats["attn_gate_mean"] = jnp.mean(gate)
+            stats["attn_gate_spread"] = jnp.mean(jnp.square(gate - 0.5))
+            stats["attn_gated_layers"] = jnp.ones((), jnp.float32)
     out = _mm(attn, layer["wo"]).astype(x.dtype)
     if "post_attn_norm" in layer:
         out = rms_norm(out, layer["post_attn_norm"], config.rms_eps,
                        config.norm_offset)
-    return _add_branch(x, out, config, onto)
+    return _add_branch(x, out, config, onto), stats
 
 
 @jax.named_scope("short_conv")
@@ -1064,19 +1167,21 @@ def _ssm_block(x, layer, config: LlamaConfig, mesh, rules, onto=None):
 
 
 def _mixer_block(x, layer, config: LlamaConfig, positions, mesh, rules,
-                 context_size, window=None):
+                 context_size, window=None, rope=None):
     """The layer's token mixer, by what the layer holds, and its counters
-    ({} but for a state-space layer and for a layer of several streams,
-    whose mixer reads a mix of them: `_branch_input`)."""
+    ({} but for a state-space layer, for a gated attention layer or one
+    of a model that chooses RoPE by layer, and for a layer of several
+    streams, whose mixer reads a mix of them: `_branch_input`)."""
     u, onto = _branch_input(x, layer.get("hc_mixer"), config, mesh, rules)
     if "ssm_in" in layer:
         y, stats = _ssm_block(u, layer, config, mesh, rules, onto)
         return y, {**stats, **_hc_counters(onto)}
     if "conv_in" in layer:
         return _short_conv_block(u, layer, config, onto), _hc_counters(onto)
-    return _attention_block(u, layer, config, positions, mesh, rules,
-                            context_size, window=window,
-                            onto=onto), _hc_counters(onto)
+    y, stats = _attention_block(u, layer, config, positions, mesh, rules,
+                                context_size, window=window, onto=onto,
+                                rope=rope)
+    return y, {**stats, **_hc_counters(onto)}
 
 
 @jax.named_scope("mlp")
@@ -1122,21 +1227,22 @@ def _constrainer(mesh, rules):
 
 
 def _layer_maker(config: LlamaConfig, positions, mesh, rules, context_size):
-    """window -> the function that applies one layer, `layer_fn((x, aux),
-    layer) -> ((x, aux), counters)`, rematerialised as the config says.
-    x is [b, t, d], or [b, t, n*d] where the layers mix several
-    streams. The stack and the multi-token prediction module's block run
-    their layers through it."""
+    """(window, rope) -> the function that applies one layer,
+    `layer_fn((x, aux), layer) -> ((x, aux), counters)`, rematerialised as
+    the config says. x is [b, t, d], or [b, t, n*d] where the layers mix
+    several streams. The stack and the multi-token prediction module's
+    block run their layers through it."""
     constrain = _constrainer(mesh, rules)
 
-    def make_layer_fn(window):
-        # window is trace-time static (it selects the attention mask
-        # program), so it rides a closure, not a traced argument
+    def make_layer_fn(window, rope):
+        # window and rope are trace-time static (they select the mask
+        # program and whether RoPE is emitted), so they ride a closure,
+        # not a traced argument
         def layer_fn(carry, layer):
             x, aux = carry
             wide = (None,) * (x.ndim - 2)  # streams and embed, unsharded
             x, mixed = _mixer_block(x, layer, config, positions, mesh, rules,
-                                    context_size, window=window)
+                                    context_size, window=window, rope=rope)
             x = constrain(x, "batch", "seq", *wide)
             x, a, counters = _mlp_block(x, layer, config, mesh, rules)
             return (constrain(x, "batch", "seq", *wide), aux + a), _add_counters(
@@ -1203,7 +1309,8 @@ def _backbone(
         aux = jnp.zeros((), jnp.float32)
         stats: Dict = {}
         for i, layer in enumerate(params["layers"]):
-            (x, aux), counters = make_layer_fn(config.window_for(i))((x, aux), layer)
+            (x, aux), counters = make_layer_fn(
+                config.window_for(i), config.rope_for(i))((x, aux), layer)
             _add_counters(stats, counters)
         if config.hc_mult > 1:
             x = hc_sum(x, config.hc_mult)
@@ -1479,7 +1586,8 @@ def _mtp_hidden(h, params, tokens, config: LlamaConfig, mesh, rules):
     if config.hc_mult > 1:
         z = constrain(hc_streams(z, config.hc_mult), "batch", "seq", None)
     layer_fn = _layer_maker(config, _positions(b, t), mesh, rules,
-                            _context_size(config, mesh))(config.sliding_window)
+                            _context_size(config, mesh))(config.sliding_window,
+                                                         config.use_rope)
     (z, aux), stats = layer_fn((z, jnp.zeros((), jnp.float32)), mtp["block"])
     if config.hc_mult > 1:
         z = hc_sum(z, config.hc_mult)
@@ -1547,8 +1655,10 @@ def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
     and hc_sinkhorn_residual (the largest); for a model with a
     multi-token prediction module (_mtp_loss), whose loss enters at
     mtp_loss_weight: ce (the next token's cross entropy alone), mtp_ce,
-    mtp_positions, and the module's block in every layer counter
-    (docs/observability.md)."""
+    mtp_positions, and the module's block in every layer counter; for a
+    model with gated attention or RoPE chosen by layer:
+    attn_windowed_layers, attn_nope_layers and, over the gated layers,
+    attn_gate_mean and attn_gate_spread (docs/observability.md)."""
     rules = rules or ShardingRules()
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     chunked = config.ce_chunks > 1
@@ -1580,6 +1690,12 @@ def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
         stats = dict(stats)
         for k in ("ssm_dt_mean", "ssm_state_carry"):
             stats[k] = stats[k] / stats["ssm_layers"]
+    if "attn_gated_layers" in stats:
+        # the gated layers' means were summed over the layers with their count
+        stats = dict(stats)
+        gated = stats.pop("attn_gated_layers")
+        for k in ("attn_gate_mean", "attn_gate_spread"):
+            stats[k] = stats[k] / gated
     if "moe_rows_fullest" in stats:
         stats = dict(stats)
         held = config.n_experts_held or config.n_experts
@@ -1639,13 +1755,14 @@ def pipeline_layer_fn(config: LlamaConfig, t: int,
     (train/pipeline_runtime.py) all run this closure, so schedule parity
     can never drift into layer-math drift. `layer_fn(act, layer) ->
     (act, aux_scalar)`; `t` is the (static) sequence length."""
+    config.require_plain_attention("the pipelined forward")
     rules = rules or ShardingRules()
     positions1 = jnp.arange(t, dtype=jnp.int32)[None]
 
     def layer_fn(a, layer):
         pos = jnp.broadcast_to(positions1, (a.shape[0], t))
-        a = _attention_block(a, layer, config, pos, None, rules, 1,
-                             window=config.sliding_window)
+        a, _ = _attention_block(a, layer, config, pos, None, rules, 1,
+                                window=config.sliding_window)
         a, aux, _ = _mlp_block(a, layer, config)
         return a, aux
 
@@ -1672,6 +1789,7 @@ def forward_pipelined_and_aux(
     body, aux accumulated per valid microbatch window);
     tensor/context/expert must be size 1 on a pipelined mesh (those
     shardings need manual collectives inside shard_map)."""
+    config.require_plain_attention("the pipelined forward")
     config.require_single_pass("the pipelined forward")
     config.require_no_ssm("the pipelined forward")
     config.require_one_stream("the pipelined forward")

@@ -62,7 +62,8 @@ class StepRecorder:
         `counters`: what the step returned beside its loss and gradient
         norm (an expert model's `moe_*` / `gmm_*`, a looped stack's
         `loop_*`, a state-space model's `ssm_*`, a several-stream model's
-        `hc_*`, a multi-token prediction module's `ce` and `mtp_*`:
+        `hc_*`, a multi-token prediction module's `ce` and `mtp_*`, a
+        model with gated attention or RoPE chosen by layer `attn_*`:
         llama.loss_and_stats);
         they ride the step's record, read when its loss is."""
         if compiled:
@@ -110,7 +111,7 @@ def parse_args(argv=None):
     p.add_argument("--model", default=os.environ.get("KUBEDL_MODEL", "tiny"),
                    choices=["tiny", "bench-1b", "llama-7b", "lfm2-8b-a1b",
                             "ouro-2.6b", "granite-4.0-h-micro",
-                            "xing4.0-29b-a4b"])
+                            "xing4.0-29b-a4b", "trinity-large-preview"])
     p.add_argument("--steps", type=int, default=int(os.environ.get("KUBEDL_STEPS", 100)))
     p.add_argument("--batch", type=int, default=int(os.environ.get("KUBEDL_BATCH", 8)))
     p.add_argument("--seq-len", type=int, default=int(os.environ.get("KUBEDL_SEQ_LEN", 512)))
@@ -841,7 +842,7 @@ def main(argv=None) -> int:
                             dispatch_span.dur, compile_log.since(compiles_before),
                             {k: v for k, v in metrics.items()
                              if k.startswith(("moe_", "gmm_", "loop_", "ssm_",
-                                              "hc_", "mtp_")) or k == "ce"})
+                                              "hc_", "mtp_", "attn_")) or k == "ce"})
             if prof is not None and prof.should_stop(step):
                 settle(metrics["loss"])
                 prof.stop()

@@ -207,7 +207,7 @@ def test_attention_layer_takes_no_position():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, SEQ, 64), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(SEQ, dtype=jnp.int32)[None], (2, SEQ))
     rules = ShardingRules()
-    out = lambda p, c: llama._attention_block(x, layer, c, p, None, rules, 1)
+    out = lambda p, c: llama._attention_block(x, layer, c, p, None, rules, 1)[0]
     np.testing.assert_array_equal(np.asarray(out(pos, config)),
                                   np.asarray(out(pos * 7 + 100, config)))
     roped = dataclasses.replace(config, use_rope=True)
