@@ -9,7 +9,7 @@ ring attention builds on — ops/ring_attention.py).
 
 Layout: [batch*heads, seq, head_dim]. The public entry handles GQA by
 broadcasting KV heads, pads ragged sequence lengths to block multiples, and
-installs a custom VJP wiring the two kernels together.
+installs a custom VJP wiring the forward kernel to the backward one.
 
 q and k share one head size and v may have another (latent attention:
 keys of 192, values of 128): q, k, dq and dk ride at the q/k width, v, o,
@@ -25,13 +25,20 @@ are and those whose every pair is live. The mask has one definition,
 compared with its row index, so that a program holds no
 `[block_q, block_k]` integer (three of them, kept in VMEM and read back
 every block, cost the forward a tenth of its time under a window:
-PERF.md section 6, PR 31). The forward and `flash_bwd_dkv` mask every
-block they visit; `flash_bwd_dq` runs its interior blocks through a body
-with no mask, the one kernel that gained by it (PR 27).
+PERF.md section 6). Both kernels mask every block they visit.
+
+The backward is one kernel, `flash_bwd_dqkv`: a program a head and K
+block walks the Q blocks that see it and computes S, P and dP once a
+block pair, for all three gradients. dK and dV are summed in the loop's
+carry; dQ in a float32 VMEM scratch of the whole sequence that lives
+across the head's K blocks, the grid's sequential axis, and is written
+out at the last of them. So each Q block's dQ gathers its terms in
+ascending K block from a float32 zero, as a loop over the K blocks under
+one Q block adds them.
 
 Each `pallas_call` carries a fixed `name=`, which the device trace shows as
-the event's name: `flash_fwd`, `flash_fwd_streamed`, `flash_bwd_dq`,
-`flash_bwd_dkv`. The benchmark's by-name metrics read them (PERF.md section 3).
+the event's name: `flash_fwd`, `flash_fwd_streamed`, `flash_bwd_dqkv`. The
+benchmark's by-name metrics read them (PERF.md section 3).
 """
 from __future__ import annotations
 
@@ -60,15 +67,16 @@ DEFAULT_BLOCK_K = 512
 FLASH_MIN_SEQ = 1024
 # Above this sequence length the default kernel's full-K/V-in-VMEM
 # BlockSpecs crowd the 16 MB scoped VMEM; the forward streams K/V blocks
-# through a 3D grid instead. The backward kernels keep whole-tensor loads,
+# through a 3D grid instead. The backward kernel keeps whole-tensor loads,
 # so TRAINING beyond this length belongs to ring attention / context
 # parallelism — the streamed path serves long-context inference prefill.
 STREAM_MIN_SEQ = 8192
 # What the whole-sequence kernels were sized for: a head's two operands
-# (K and V in the forward and in `flash_bwd_dq`, Q and dO in
-# `flash_bwd_dkv`) of STREAM_MIN_SEQ tokens by 128 lanes each, held twice
-# over (Mosaic double-buffers a BlockSpec), leave half of the default
-# 16 MB of scoped VMEM to the blocks and the loops' temporaries.
+# (K and V in the forward, Q and dO in `flash_bwd_dqkv`) of
+# STREAM_MIN_SEQ tokens by 128 lanes each, held twice over (Mosaic
+# double-buffers a BlockSpec), leave half of the default 16 MB of scoped
+# VMEM to the blocks and the loops' temporaries. The backward holds the
+# head's dQ besides (`_whole_seq_params`).
 WHOLE_SEQ_BYTES = 2 * 2 * STREAM_MIN_SEQ * (128 + 128)
 NEG_INF = -1e30
 # `jax.ad_checkpoint.checkpoint_name`s on the forward kernel's two outputs
@@ -83,8 +91,8 @@ FLASH_LSE = "flash_lse"
 # which blocks a kernel visits, and which of them need a mask
 # ---------------------------------------------------------------------------
 
-# The two inner loops: forward and dq walk K blocks under one Q block,
-# dk/dv walks Q blocks under one K block.
+# The two inner loops: the forward walks K blocks under one Q block, the
+# backward Q blocks under one K block.
 SIDES = ("fwd_dq", "dkv")
 
 
@@ -134,10 +142,10 @@ def _segments(outer, side, *, seq_len, window, block_q, block_k, causal,
 def block_plan(seq_len, window, block_q, block_k, causal, side):
     """`(visited, interior)`: the block pairs one head's call of a kernel
     visits, and how many of them hold live pairs alone. `side` is "fwd_dq"
-    (`flash_fwd`, `flash_bwd_dq`) or "dkv" (`flash_bwd_dkv`). `flash_bwd_dq`
-    runs its interior blocks with no mask; the other two mask every block
-    they visit. A function of the shape alone, from the kernels' own
-    `_segments` in numpy: no device is touched."""
+    (`flash_fwd`) or "dkv" (`flash_bwd_dqkv`); both sides count the same
+    pairs, and both kernels mask every block they visit. A function of
+    the shape alone, from the kernels' own `_segments` in numpy: no
+    device is touched."""
     outer = block_q if side == "fwd_dq" else block_k
     start, lo, hi, stop = _segments(
         np.arange(pl.cdiv(seq_len, outer)), side, seq_len=seq_len,
@@ -179,16 +187,25 @@ def _where_live(mask, x, fill):
     return x if mask is None else jnp.where(mask, x, fill)
 
 
-def _whole_seq_params(seq: int, d_qk: int, d_v: int):
+def _whole_seq_params(seq: int, d_qk: int, d_v: int, *, dq: bool = False):
     """Compiler parameters of a whole-sequence kernel at these (padded)
     widths: none (the default scoped VMEM) where the two operands fit
     what the kernels were sized for, and beyond that (q and k of 256
-    lanes at 8,192 tokens) a limit that grows by what they hold more."""
-    held = 2 * 2 * seq * (d_qk + d_v)
-    if held <= WHOLE_SEQ_BYTES:
+    lanes at 8,192 tokens) a limit that grows by what they hold more.
+
+    `dq` is the backward's: it also holds the head's whole dQ, as a
+    float32 sum and as the bf16 output block held twice over (8 bytes a
+    token and lane: 8 MB at 8,192 x 128, 16 MB at 256 lanes, so a limit
+    of 24 and 36 MB of the v5e's 128 MiB), and its K-block axis runs in
+    order, since each K block adds to that sum."""
+    held = 2 * 2 * seq * (d_qk + d_v) + (8 * seq * d_qk if dq else 0)
+    limit = (16 * 2**20 + held - WHOLE_SEQ_BYTES
+             if held > WHOLE_SEQ_BYTES else None)
+    if limit is None and not dq:
         return None
     return pltpu.CompilerParams(
-        vmem_limit_bytes=16 * 2**20 + held - WHOLE_SEQ_BYTES)
+        dimension_semantics=("parallel", "arbitrary") if dq else None,
+        vmem_limit_bytes=limit)
 
 
 # ---------------------------------------------------------------------------
@@ -390,70 +407,28 @@ def _fwd_streamed(q, k, v, sm_scale, causal, window, block_q, block_k,
 
 
 # ---------------------------------------------------------------------------
-# backward kernels
+# backward kernel
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, sm_scale, causal, window, block_q, block_k, seq_len,
-                   softcap):
-    qb = pl.program_id(1)
-    q = q_ref[0]  # bf16 into the MXU; f32 accumulation
-    do = do_ref[0]
-    lse = lse_ref[0, 0][:, None]
-    delta = delta_ref[0, 0][:, None]
-
-    def body(masked):
-        def step(kb, dq):
-            k = k_ref[0, pl.ds(kb * block_k, block_k), :]
-            v = v_ref[0, pl.ds(kb * block_k, block_k), :]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * sm_scale
-            if softcap is not None:
-                s = _softcap_scores(s, softcap)
-            p = jnp.exp(s - lse)
-            if masked:
-                p = _where_live(_block_mask(
-                    qb * block_q, kb * block_k, block_q=block_q,
-                    block_k=block_k, seq_len=seq_len, causal=causal,
-                    window=window), p, 0.0)
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - delta)
-            if softcap is not None:
-                # d/dx[cap*tanh(x/cap)] = 1 - tanh(x/cap)^2 = 1 - (s/cap)^2
-                ds = ds * (1.0 - (s / softcap) ** 2)
-            return dq + jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        return step
-
-    # ascending as one loop would run: the blocks the window's edge crosses,
-    # the interior ones with no mask, the blocks the diagonal or the padded
-    # tail crosses
-    start_kb, lo, hi, num_kb = _segments(
-        qb, "fwd_dq", seq_len=seq_len, window=window, block_q=block_q,
-        block_k=block_k, causal=causal)
-    dq = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-    dq = jax.lax.fori_loop(start_kb, lo, body(True), dq)
-    dq = jax.lax.fori_loop(lo, hi, body(False), dq)
-    dq = jax.lax.fori_loop(hi, num_kb, body(True), dq)
-    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                    *, sm_scale, causal, window, block_q, block_k, seq_len,
-                    softcap):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, *, sm_scale, causal, window,
+                block_q, block_k, seq_len, softcap):
     kb = pl.program_id(1)
     k = k_ref[0]  # bf16 into the MXU; f32 accumulation
     v = v_ref[0]
 
+    @pl.when(kb == 0)
+    def _zero_dq():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
     def body(qb, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(qb * block_q, block_q), :]
-        do = do_ref[0, pl.ds(qb * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(qb * block_q, block_q)][:, None]
-        delta = delta_ref[0, 0, pl.ds(qb * block_q, block_q)][:, None]
+        rows = pl.ds(qb * block_q, block_q)
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, 0, rows][:, None]
+        delta = delta_ref[0, 0, rows][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
         if softcap is not None:
@@ -471,8 +446,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         if softcap is not None:
             # d/dx[cap*tanh(x/cap)] = 1 - tanh(x/cap)^2 = 1 - (s/cap)^2
             ds = ds * (1.0 - (s / softcap) ** 2)
-        dk = dk + jax.lax.dot_general(ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        ds = ds.astype(q.dtype)
+        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
+        dq_acc[rows, :] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         return dk, dv
 
     start_qb, _, _, num_qb = _segments(
@@ -483,6 +461,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
     dk, dv = jax.lax.fori_loop(start_qb, num_qb, body, (dk0, dv0))
     dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _write_dq():
+        dq_ref[0] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
 def _bwd(sm_scale, causal, window, block_q, block_k, true_len, res, dout,
@@ -495,49 +477,28 @@ def _bwd(sm_scale, causal, window, block_q, block_k, true_len, res, dout,
         out.astype(jnp.float32) * dout.astype(jnp.float32), axis=-1
     )[:, None, :]
 
-    kern = dict(sm_scale=sm_scale, causal=causal, window=window,
-                block_q=block_q, block_k=block_k, seq_len=true_len,
-                softcap=softcap)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **kern),
-        grid=(bh, pl.cdiv(seq, block_q)),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, seq, d_v), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
-        compiler_params=_whole_seq_params(seq, d, d_v),
-        interpret=interpret(),
-        name="flash_bwd_dq",
-    )(q, k, v, dout, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kern),
+    whole = lambda width: pl.BlockSpec((1, seq, width), lambda b, i: (b, 0, 0))
+    block = lambda width: pl.BlockSpec((1, block_k, width), lambda b, i: (b, i, 0))
+    row = pl.BlockSpec((1, 1, seq), lambda b, i: (b, 0, 0))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, sm_scale=sm_scale, causal=causal, window=window,
+            block_q=block_q, block_k=block_k, seq_len=true_len,
+            softcap=softcap),
         grid=(bh, pl.cdiv(seq, block_k)),
-        in_specs=[
-            pl.BlockSpec((1, seq, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d_v), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq, d_v), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, seq), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, seq), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d_v), lambda b, i: (b, i, 0)),
-        ],
+        in_specs=[whole(d), block(d), block(d_v), whole(d_v), row, row],
+        # dQ's block is the head's whole sequence: it stays in VMEM across
+        # the head's K blocks and goes back to HBM once, after the last
+        out_specs=[whole(d), block(d), block(d_v)],
         out_shape=[
+            jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, seq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, seq, d_v), q.dtype),
         ],
-        compiler_params=_whole_seq_params(seq, d, d_v),
+        scratch_shapes=[pltpu.VMEM((seq, d), jnp.float32)],
+        compiler_params=_whole_seq_params(seq, d, d_v, dq=True),
         interpret=interpret(),
-        name="flash_bwd_dkv",
+        name="flash_bwd_dqkv",
     )(q, k, v, dout, lse, delta)
     return dq, dk, dv
 
@@ -588,7 +549,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, window, block_q, block_k, true_len,
 
 
 # Bound at import (NOT an alias of the monkeypatchable dispatch knob): the
-# backward kernels load whole-sequence tensors into VMEM and cannot fit
+# backward kernel loads whole-sequence tensors into VMEM and cannot fit
 # beyond this — training longer sequences is context parallelism's job.
 BWD_MAX_SEQ = 8192
 
@@ -758,7 +719,7 @@ def flash_attention(
     vf = _pad_seq_to(v.reshape(b * hq, sq, dvw), target)
     # A kernel's HLO instruction takes the innermost name on the stack.
     # Under this scope that is always the kernel's own name= (%flash_fwd.N,
-    # %flash_bwd_dq.N); without one a bare jax.grad of this function would
+    # %flash_bwd_dqkv.N); without one a bare jax.grad of this function would
     # wrap it (jvp(flash_fwd) reads %jvp_flash_fwd_.N).
     with jax.named_scope("flash_attention"):
         out = _flash(qf, kf, vf, sm_scale, causal, window, block_q, block_k,

@@ -340,8 +340,8 @@ def test_softcap_streamed_path():
 
 
 # ---------------------------------------------------------------------------
-# Blocks by position: dead ones skipped in every kernel, interior ones
-# mask-free in dq (ops/flash_attention.py:_segments)
+# Blocks by position: dead ones skipped in every kernel
+# (ops/flash_attention.py:_segments)
 # ---------------------------------------------------------------------------
 
 
@@ -388,41 +388,6 @@ def test_every_kind_of_block_matches_reference(shape):
     for a, b, name in zip(gf, gr, "qkv"):
         np.testing.assert_allclose(a, b, atol=5e-3, rtol=5e-3,
                                    err_msg=f"d{name}")
-
-
-@pytest.mark.parametrize("shape", [
-    (512, 300, 128, 128, True, None),
-    (450, None, 128, 256, True, None),
-    (450, None, 256, 128, False, None),
-    (512, 300, 128, 128, True, 20.0),
-], ids=["window", "ragged_wide_k_blocks", "not_causal_ragged", "softcap"])
-def test_the_mask_free_body_changes_no_bit(shape, monkeypatch):
-    """Every block under the masked body (an empty interior range) against
-    the kernels as they are: dq's `where(mask, p, 0)` with the mask all
-    true is `p`, so `out` and the three gradients are equal bit for bit."""
-    seq, window, block_q, block_k, causal, softcap = shape
-    q, k, v = rand_qkv(b=1, hq=2, hkv=2, s=seq, d=64)
-    kw = dict(causal=causal, window=window, softcap=softcap,
-              block_q=block_q, block_k=block_k)
-
-    def run():
-        out = flash_attention(q, k, v, **kw)
-        return (out,) + jax.grad(_loss(flash_attention, **kw), (0, 1, 2))(q, k, v)
-
-    real = run()
-    segments = fa._segments
-    interior = []
-
-    def all_masked(*a, **kwargs):
-        start, lo, hi, stop = segments(*a, **kwargs)
-        interior.append((lo, hi))
-        return start, stop, stop, stop
-
-    monkeypatch.setattr(fa, "_segments", all_masked)
-    masked = run()
-    assert len(interior) >= 3  # forward, dq and dk/dv all asked
-    for a, b, name in zip(real, masked, ("out", "dq", "dk", "dv")):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
 
 
 def _live_pairs(seq, window, block_q, block_k, causal):
@@ -622,7 +587,8 @@ def test_the_block_mask_changes_no_bit(shape, streamed, monkeypatch):
 
     monkeypatch.setattr(fa, "_block_mask", pair_mask)
     old = run()
-    assert len(asked) >= 3  # forward, dq and dk/dv all asked
+    # the forward twice (alone and under grad), the one backward kernel once
+    assert len(asked) == 3
     for a, b, name in zip(new, old, ("out", "lse", "dq", "dk", "dv")):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
 
@@ -720,3 +686,72 @@ def test_unlike_q_and_k_widths_are_refused():
     q, k, v, _ = rand_unlike(64, 64)
     with pytest.raises(ValueError, match="q heads are 64 wide and k heads 32"):
         flash_attention(q, k[..., :32], v)
+
+
+# -- one backward kernel: S, P and dP once a block pair, dQ summed in VMEM ----
+
+
+def _pallas_names(fn, *args):
+    """The `name=` of every Pallas call in `fn`'s jaxpr, nested ones too."""
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) else (value,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+# (seq, window, block_q, block_k, causal, softcap, d_qk, d_v)
+FUSED = {
+    "full_causal": (512, None, 128, 128, True, None, 64, 64),
+    "window": (512, 300, 128, 128, True, None, 64, 64),
+    "window_of_whole_blocks": (512, 256, 128, 128, True, None, 64, 64),
+    "padded_tail": (450, 192, 128, 128, True, None, 64, 64),
+    "wide_q_blocks": (512, None, 256, 128, True, None, 64, 64),
+    "wide_k_blocks_ragged": (600, 256, 128, 256, True, None, 64, 64),
+    "not_causal_ragged": (450, None, 256, 128, False, None, 64, 64),
+    "softcap": (512, 300, 128, 128, True, 20.0, 64, 64),
+    "latent_192_128": (384, None, 128, 128, True, None, 192, 128),
+}
+
+
+@pytest.mark.parametrize("shape", FUSED.values(), ids=FUSED.keys())
+def test_one_backward_kernel_gives_all_three_gradients(shape):
+    """dq, dk and dv out of `flash_bwd_dqkv` against the plain-XLA
+    reference: the backward is that one kernel, called once, beside the
+    forward, whatever the blocks, window, mask, tail, cap and widths."""
+    seq, window, block_q, block_k, causal, softcap, d_qk, d_v = shape
+    q, k, v, do = rand_unlike(d_qk, d_v, s=seq, seed=seq)
+    kw = dict(causal=causal, window=window, softcap=softcap)
+    loss = lambda fn, **blocks: lambda *a: jnp.sum(fn(*a, **kw, **blocks) * do)
+    grad = jax.grad(loss(flash_attention, block_q=block_q, block_k=block_k),
+                    (0, 1, 2))
+    assert sorted(_pallas_names(grad, q, k, v)) == ["flash_bwd_dqkv", "flash_fwd"]
+    got = grad(q, k, v)
+    want = jax.grad(loss(attention_reference), (0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, atol=5e-3, rtol=5e-3, err_msg=name)
+
+
+def test_the_backward_holds_the_heads_dq_in_vmem():
+    """The backward's compiler parameters: the K-block axis in order, and
+    a scoped VMEM grown by the whole sequence's dQ (float32 sum and the
+    bf16 block held twice) beside Q and dO, at both of the benchmark's
+    widths; the forward's are what they were."""
+    one = fa._whole_seq_params(8192, 128, 128, dq=True)
+    two = fa._whole_seq_params(8192, 256, 128, dq=True)
+    assert one.dimension_semantics == two.dimension_semantics == (
+        "parallel", "arbitrary")
+    assert (one.vmem_limit_bytes, two.vmem_limit_bytes) == (24 * 2**20, 36 * 2**20)
+    short = fa._whole_seq_params(1024, 128, 128, dq=True)
+    assert short.vmem_limit_bytes is None and short.dimension_semantics
+    assert fa._whole_seq_params(8192, 128, 128) is None

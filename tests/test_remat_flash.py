@@ -12,7 +12,7 @@ import pytest
 from kubedl_tpu.models import llama
 from kubedl_tpu.parallel.mesh import ShardingRules, build_mesh
 
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+KERNELS = ("flash_fwd", "flash_bwd_dqkv")
 
 
 def _value_and_grad(config, mesh):
@@ -82,7 +82,7 @@ def test_backward_holds_one_forward_kernel_a_layer(
 
     bare_checkpoint()
     calls = _kernel_calls(_gradient_equations(config, params, tokens, mesh))
-    assert calls == {"flash_fwd": 2 * n, "flash_bwd_dq": n, "flash_bwd_dkv": n}
+    assert calls == {"flash_fwd": 2 * n, "flash_bwd_dqkv": n}
     loss0, grads0 = jax.jit(_value_and_grad(config, mesh))(params, tokens)
 
     assert np.asarray(loss) == np.asarray(loss0)
@@ -134,8 +134,9 @@ def test_the_tag_is_the_identity_outside_a_policy():
 
 
 def test_a_window_adds_no_kernel():
-    """Edge and interior blocks are loops inside `flash_bwd_dq`, not
-    kernels of their own: the benchmark's by-name metrics count calls."""
+    """Edge and interior blocks are visits of the loops inside the
+    kernels, not kernels of their own: the benchmark's by-name metrics
+    count calls."""
     config = llama.LlamaConfig.tiny(sliding_window=8)
     params, tokens = _inputs(config)
     calls = _kernel_calls(_gradient_equations(config, params, tokens))

@@ -11,6 +11,7 @@ import ast
 import glob
 import json
 import os
+import re
 import signal
 import types
 
@@ -84,14 +85,35 @@ def test_every_pallas_call_has_a_literal_name(name_node):
 
 def test_kernel_names_are_the_ones_the_metrics_read():
     names = sorted(n for p in PALLAS_CALLS for n in _literal_names(p.values[0]))
-    assert names == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd",
+    assert names == ["flash_bwd_dqkv", "flash_fwd",
                      "flash_fwd_streamed", "gmm", "gmm_drhs", "gmm_scaled",
                      "gmm_swiglu", "hc_post_bwd", "hc_post_fwd", "hc_pre_bwd",
                      "hc_pre_fwd", "moe_gather", "ssm_conv_bwd",
                      "ssm_conv_fwd", "ssm_scan_bwd", "ssm_scan_fwd"]
-    with open(os.path.join(ROOT, "benchmarks", "metrics",
-                           "flash_bwd_roofline.train.json")) as f:
-        assert "flash_bwd_(dq|dkv)" in json.load(f)["params"]["patterns"]["bwd"]
+
+
+# the instruction each flash kernel defines, as a trace's event names it
+FLASH_EVENTS = {"flash_fwd": "%flash_fwd.3 = (bf16[64,8192,128]{2,1,0}, ",
+                "flash_bwd_dqkv": "%flash_bwd_dqkv.7 = (bf16[64,8192,128]{2,1,0}, "}
+NAMED_FLASH_METRICS = sorted(
+    glob.glob(os.path.join(ROOT, "benchmarks", "metrics", "flash_time_share_*.train.json"))
+    + glob.glob(os.path.join(ROOT, "benchmarks", "metrics", "flash_bwd_roofline*.train.json")))
+
+
+@pytest.mark.parametrize("path", NAMED_FLASH_METRICS, ids=os.path.basename)
+def test_the_by_name_metrics_find_the_one_backward_kernel(path):
+    """Every flash metric that reads kernels by name finds
+    `flash_bwd_dqkv`: the five time shares the forward beside it, so they
+    still read the whole attention core, and the five backward rooflines
+    it alone (which count two events a call, so read half its share)."""
+    with open(path) as f:
+        params = json.load(f)["params"]
+    if "pattern" in params:
+        wanted, pattern = FLASH_EVENTS, params["pattern"]
+    else:
+        wanted, pattern = ("flash_bwd_dqkv",), params["patterns"]["bwd"]
+    for kernel in FLASH_EVENTS:
+        assert bool(re.search(pattern, FLASH_EVENTS[kernel])) == (kernel in wanted), kernel
 
 
 # -- (b) scopes and names in the lowered step --------------------------------
@@ -153,8 +175,7 @@ def test_kernel_names_reach_the_lowered_text(lowered_text):
     # the kernel's own name is the innermost entry of the stack, which
     # is what its HLO instruction is called after
     for name in ("attn_core/flash_attention/flash_fwd/",
-                 "attn_core/flash_attention/flash_bwd_dq/",
-                 "attn_core/flash_attention/flash_bwd_dkv/"):
+                 "attn_core/flash_attention/flash_bwd_dqkv/"):
         assert name in text
     # remat keeps the forward kernel's out and lse (llama._remat_policy):
     # the backward recomputes the projections around it, not the kernel
