@@ -15,7 +15,9 @@ so it differs wherever a line of a kernel's file moved or the checkout
 lies elsewhere; what the kernels compute is compared by the other half:
 the jaxpr (which holds every `pallas_call`'s kernel, grid and compiler
 parameters and no source location) of `flash_attention`'s forward and
-backward at the 8k cells' shapes, hashed in both trees.
+backward at the 8k cells' shapes, and of the state-space convolution's
+kernels (`ops/causal_conv.py:split_conv`) at the Granite cell's, hashed
+in both trees.
 (`git archive <commit> | tar -x -C .parent` makes the second checkout.)
 """
 from __future__ import annotations
@@ -33,6 +35,8 @@ BODY = re.compile(r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22')
 # [batch, heads, sequence, head size], window: the Mistral, LFM2 and Ouro cells'
 FLASH_SHAPES = [((2, 32, 8192, 128), 4096), ((1, 32, 8192, 128), 4096),
                 ((2, 32, 8192, 64), None), ((2, 16, 8192, 128), None)]
+# u, taps, bias, offset, widths: the Granite cell's convolution (ops/causal_conv.py)
+GRANITE_CONV = ((2, 8192, 8512), (4352, 4), (4352,), 4096, (4096, 128, 128))
 
 
 def in_tree(cells) -> int:
@@ -87,7 +91,17 @@ def in_tree(cells) -> int:
             q, k, v, causal=True, window=window).astype(jnp.float32))
         jaxpr = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(x, x, x))
         hashes.append(hashlib.sha256(jaxpr.encode()).hexdigest()[:16])
-    print(json.dumps({"flash_jaxprs": hashes}), flush=True)
+    from kubedl_tpu.ops import causal_conv as cc
+
+    cc.interpret = lambda: False
+    u = jax.ShapeDtypeStruct(GRANITE_CONV[0], jnp.bfloat16)
+    w, b = (jax.ShapeDtypeStruct(s, jnp.float32) for s in GRANITE_CONV[1:3])
+    loss = lambda u, w, b: sum(jnp.sum(v.astype(jnp.float32)) for v in cc.split_conv(
+        u, w, b, *GRANITE_CONV[3:]))
+    jaxpr = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(u, w, b))
+    print(json.dumps({"flash_jaxprs": hashes,
+                      "conv_jaxpr": hashlib.sha256(jaxpr.encode()).hexdigest()[:16]}),
+          flush=True)
     return 0
 
 
@@ -127,6 +141,9 @@ def main(argv=None) -> int:
     c, p = change["flash_jaxprs"], parent.get("flash_jaxprs")
     print(f"flash kernels' jaxprs at {FLASH_SHAPES}: {c['flash_jaxprs']}"
           + (f", equal to the parent's {p['flash_jaxprs'] == c['flash_jaxprs']}" if p else ""))
+    print(f"the state-space convolution's kernels' jaxpr at {GRANITE_CONV}: "
+          f"{c['conv_jaxpr']}"
+          + (f", equal to the parent's {p['conv_jaxpr'] == c['conv_jaxpr']}" if p else ""))
     return 0
 
 

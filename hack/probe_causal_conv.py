@@ -23,9 +23,15 @@ the first form's (norm of the difference over the norm), and how many
 elements of x, B and C differ at all. `--short-conv` times
 `models/short_conv.py:short_conv` at the LFM2 cell's shape (`[2, 8192,
 2048]`, K = 3) whole and its two projections alone, forward and forward
-with backward: what is left is the gates and the taps. `--tiny` is the
-rehearsal on the CPU: a small shape, the gaps compared, every time "not
-measured" (a CPU time is no device number).
+with backward, and its gates and taps alone in each form: `gates_kernel`
+(ops/causal_conv.py's `short_conv_fwd` / `short_conv_bwd`, what
+`gated_taps` takes at this shape on a TPU), `gates_xla` (the XLA
+operations every other shape takes) and, with `--parent`, that
+checkout's XLA operations; for each also the device ms a call (all its
+operations, and each kernel by name) against the memory bound, and the
+gaps of y, du and dw to the kernel's. `--tiny` is the rehearsal on the
+CPU: a small shape, the gaps compared, every time "not measured" (a CPU
+time is no device number).
 """
 from __future__ import annotations
 
@@ -58,6 +64,7 @@ TINY = (2, 384, 256, 128, 8, 4)
 SHORT = (2, 8192, 2048, 3)
 SHORT_TINY = (2, 256, 128, 3)
 KERNELS = ("ssm_conv_fwd", "ssm_conv_bwd")
+SHORT_KERNELS = ("short_conv_fwd", "short_conv_bwd")
 OUTPUTS = ("x", "B", "C")
 GRADS = ("dh", "dw", "db")
 
@@ -109,23 +116,29 @@ def calls_of(step, shape):
     return jax.jit(lambda args: fn(*args)), jax.jit(both)
 
 
-def this_checkout(kernel: bool, tiny: bool):
-    """`ssm.split_conv`'s x, B and C, steered as `hack/probe_ssm_scan.py`
-    steers the scan: nothing on a TPU, the backend's answer on the CPU,
-    the choice itself for the XLA form."""
+def steered(module, call, kernel: bool, tiny: bool):
+    """`call` of this checkout, its form chosen in `module` as
+    `hack/probe_ssm_scan.py` steers the scan: nothing on a TPU, the
+    backend's answer on the CPU, the choice itself for the XLA form."""
     name, steer = (("conv_takes_kernel", lambda *a, **kw: False) if not kernel
                    else ("interpret", lambda: False) if tiny else (None, None))
 
-    def step(h, w, bias, d_inner, state):
-        was = getattr(ssm, name) if name else None
+    def step(*args):
+        was = getattr(module, name) if name else None
         if name:
-            setattr(ssm, name, steer)
+            setattr(module, name, steer)
         try:
-            return ssm.split_conv(h, w, bias, d_inner, state)[0][1:4]
+            return call(*args)
         finally:
             if name:
-                setattr(ssm, name, was)
+                setattr(module, name, was)
     return step
+
+
+def this_checkout(kernel: bool, tiny: bool):
+    """`ssm.split_conv`'s x, B and C."""
+    return steered(ssm, lambda h, w, bias, d_inner, state: ssm.split_conv(
+        h, w, bias, d_inner, state)[0][1:4], kernel, tiny)
 
 
 def timed(fn, args, calls: int):
@@ -138,9 +151,10 @@ def timed(fn, args, calls: int):
     return (time.perf_counter() - t0) / calls * 1e3, out
 
 
-def kernel_ms(fn, args, calls: int):
-    """Median device ms of each kernel over `calls` runs of `fn` under the
-    profiler; `{}` where the trace holds no such event."""
+def device_ms(fn, args, calls: int, kernels):
+    """Device ms a call of `fn` under the profiler: every operation's time
+    summed over `calls` runs, over `calls`; and the median of each named
+    kernel's events (`{}` where the trace holds none)."""
     with tempfile.TemporaryDirectory() as trace_dir:
         with jax.profiler.trace(trace_dir):
             for _ in range(calls):
@@ -148,14 +162,15 @@ def kernel_ms(fn, args, calls: int):
         path = tr.find_xplane(trace_dir)
         trace = tr.load(path) if path else {"planes": []}
     fmt = tr.trace_format()
-    found = {}
+    total, found = 0.0, {}
     for plane in tr.device_planes(trace, fmt)[:1]:
-        for kernel in KERNELS:
-            events = tr.matching(tr.op_events(plane, fmt),
-                                 rf"^%{kernel}[.\d]* = ")
-            if events:
-                found[kernel] = statistics.median(ev[2] for ev in events) / 1e6
-    return found
+        events = tr.op_events(plane, fmt)
+        total = sum(ev[2] for ev in events) / 1e6 / calls
+        for kernel in kernels:
+            named = tr.matching(events, rf"^%{kernel}[.\d]* = ")
+            if named:
+                found[kernel] = statistics.median(ev[2] for ev in named) / 1e6
+    return total, found
 
 
 def gap(got, want) -> float:
@@ -183,7 +198,7 @@ def probe_forms(args, record) -> None:
         row = {}
         if not args.tiny:
             row = {"fwd_ms": fwd_ms, "fwd_bwd_ms": both_ms,
-                   "kernels_ms": kernel_ms(both, (ins, douts), args.calls)}
+                   "kernels_ms": device_ms(both, (ins, douts), args.calls, KERNELS)[1]}
         first = first or out
         row["gaps"] = {k: gap(g, w) for k, g, w in zip(OUTPUTS + GRADS, out, first)}
         row["differing"] = {k: differing(g, w)
@@ -199,33 +214,79 @@ def probe_forms(args, record) -> None:
               flush=True)
 
 
+def short_parent(tree: str):
+    """The gates and taps as a checkout's `models/short_conv.py` writes
+    them in XLA operations (its `causal_taps`)."""
+    parent = load(tree, "short_conv", "short_conv_of_parent")
+
+    def step(u, w):
+        b_, c_, z = jnp.split(u, 3, axis=-1)
+        return c_ * parent.causal_taps(b_ * z, w)
+    return step
+
+
 def probe_short_conv(args, record) -> None:
-    """`short_conv` whole, and its two projections alone."""
+    """`short_conv` whole, its two projections alone, and its gates and
+    taps alone in each form: ms a call on the host's clock and on the
+    device, forward and forward with backward, beside the memory bound
+    (bf16 arrays of [b, t, d] at 819 GB/s: B, C, z read and y written
+    forward; B, C, z and dy read and dB, dC, dz written backward)."""
     b, t, d, k = SHORT_TINY if args.tiny else SHORT
-    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    ks = jax.random.split(jax.random.PRNGKey(1), 6)
     bf = jnp.bfloat16
     layer = short_conv.short_conv_init(ks[0], d, k)
     u = jax.random.normal(ks[1], (b, t, d), jnp.float32).astype(bf)
     gated = jax.random.normal(ks[2], (b, t, d), jnp.float32).astype(bf)
     d_out = jax.random.normal(ks[3], (b, t, d), jnp.float32).astype(bf)
     d_in = jax.random.normal(ks[4], (b, t, 3 * d), jnp.float32).astype(bf)
+    array_ms = b * t * d * 2 / 819e9 * 1e3
+    bound = {"fwd_ms": 4 * array_ms, "fwd_bwd_ms": 11 * array_ms}
     pieces = {
-        "whole": (short_conv.short_conv, (u, layer), d_out),
+        "whole": (lambda u, layer: short_conv.short_conv(u, layer)[0], (u, layer), d_out),
         "in_projection": (_mm, (u, layer["conv_in"]), d_in),
         "out_projection": (_mm, (gated, layer["conv_out"]), d_out),
     }
+    gated = lambda u, w: short_conv.gated_taps(u, w)[0]
+    gates = {"gates_kernel": steered(short_conv, gated, True, args.tiny),
+             "gates_xla": steered(short_conv, gated, False, args.tiny)}
+    if args.parent:
+        gates["gates_parent"] = short_parent(args.parent)
+    for name, step in gates.items():
+        pieces[name] = (step, (d_in, layer["conv_w"]), d_out)
+    first = None
     for name, (fn, ins, cotangent) in pieces.items():
         def both(ins, cotangent, fn=fn):
             out, vjp = jax.vjp(fn, *ins)
             return (out,) + vjp(cotangent)
 
-        fwd_ms, _ = timed(jax.jit(lambda ins, fn=fn: fn(*ins)), (ins,), args.calls)
-        both_ms, _ = timed(jax.jit(both), (ins, cotangent), args.calls)
+        fwd = jax.jit(lambda ins, fn=fn: fn(*ins))
+        fwd_ms, _ = timed(fwd, (ins,), args.calls)
+        both_ms, out = timed(jax.jit(both), (ins, cotangent), args.calls)
         row = {} if args.tiny else {"fwd_ms": fwd_ms, "fwd_bwd_ms": both_ms}
+        line = "not measured" if args.tiny else (
+            f"forward {fwd_ms:.3f} ms, with backward {both_ms:.3f} ms")
+        if name.startswith("gates"):
+            first = first or out
+            row["gaps"] = {g: gap(o, f) for g, o, f in zip(("y", "du", "dw"), out, first)}
+            row["differing"] = {g: differing(o, f) for g, o, f in
+                                zip(("y", "du", "dw"), out, first)}
+            line += "; gaps to the kernel's " + " ".join(
+                f"{g}={v:.2e}" for g, v in row["gaps"].items()) + "; elements that differ " + (
+                " ".join(f"{g}={v}" for g, v in row["differing"].items()))
+            if not args.tiny:
+                for key, call in (("fwd_ms", (fwd, (ins,))),
+                                  ("fwd_bwd_ms", (jax.jit(both), (ins, cotangent)))):
+                    total, kernels = device_ms(*call, args.calls, SHORT_KERNELS)
+                    row["device_" + key] = total
+                    row["kernels_" + key] = kernels
+                    row["bound_share_" + key] = bound[key] / total
+                line += (f"; on the device forward {row['device_fwd_ms']:.3f} ms "
+                         f"({100 * row['bound_share_fwd_ms']:.1f}% of the memory bound "
+                         f"{bound['fwd_ms']:.3f}), with backward {row['device_fwd_bwd_ms']:.3f} "
+                         f"ms ({100 * row['bound_share_fwd_bwd_ms']:.1f}% of "
+                         f"{bound['fwd_bwd_ms']:.3f}), kernels {row['kernels_fwd_bwd_ms']}")
         record["short_conv"][name] = row
-        print(f"short_conv {name:15s} " + (
-            "not measured" if args.tiny else
-            f"forward {fwd_ms:.3f} ms, with backward {both_ms:.3f} ms"), flush=True)
+        print(f"short_conv {name:15s} {line}", flush=True)
 
 
 def main(argv=None) -> int:

@@ -27,8 +27,9 @@ instant under a `while` or `conditional` counts once, for the innermost
 operation: `own_times`), the share that is remat recompute
 (`rematted_computation` in the `op_name`), what no scope covers, each
 named kernel's calls and time (`flash*`, `gmm*`, `moe_gather`,
-`ssm_scan_fwd`, `ssm_scan_bwd`, `ssm_conv_fwd`, `ssm_conv_bwd`, `hc_pre_fwd`,
-`hc_post_fwd`, `hc_post_bwd`, `hc_pre_bwd`), the
+`ssm_scan_fwd`, `ssm_scan_bwd`, `ssm_conv_fwd`, `ssm_conv_bwd`,
+`short_conv_fwd`, `short_conv_bwd`, `hc_pre_fwd`, `hc_post_fwd`,
+`hc_post_bwd`, `hc_pre_bwd`), the
 operations that took the most device time with their scope and what the
 compiler's cost analysis says they move (`largest_ops`, and each scope's
 own in `largest_ops_by_scope`, where the same fusion of every layer is one
@@ -260,7 +261,7 @@ def summarize(path: str) -> dict:
                         **{k: stats[k] for k in COST_STATS if k in stats}}
                 by_op[name]["calls"] += 1
                 by_op[name]["seconds"] += dur / 1e9
-                m = re.match(r"^%((?:flash|gmm|moe_gather|ssm_scan|ssm_conv|hc_pre|hc_post)\w*?)\.\d+ = ", name)
+                m = re.match(r"^%((?:flash|gmm|moe_gather|ssm_scan|ssm_conv|short_conv|hc_pre|hc_post)\w*?)\.\d+ = ", name)
                 if m:
                     k = kernels.setdefault(m[1], [0, 0])
                     k[0] += 1

@@ -148,11 +148,12 @@ def _span_detail(attrs) -> str:
     """The DETAIL column of `trace`: the attributes that say what a span
     was, of a looped stack's step its passes and exit distribution (the
     `loop_*` counters), of a state-space model's step what its scans
-    carried (the `ssm_*` counters), of a several-stream model's step how
-    its streams mixed (the `hc_*` counters), of a step of gated or
-    per-layer-RoPE attention its layer kinds and mean gate (the `attn_*`
-    counters) and of a multi-token prediction module's step its two
-    losses (docs/observability.md)."""
+    carried (the `ssm_*` counters), of a model with convolution layers
+    how many of them ran as kernels (`short_conv_*`), of a several-stream
+    model's step how its streams mixed (the `hc_*` counters), of a step
+    of gated or per-layer-RoPE attention its layer kinds and mean gate
+    (the `attn_*` counters) and of a multi-token prediction module's step
+    its two losses (docs/observability.md)."""
     detail = [f"{k}={attrs[k]}" for k in
               ("step", "stage", "cause", "outcome", "shape", "reason", "error",
                "fun", "cache")
@@ -173,6 +174,10 @@ def _span_detail(attrs) -> str:
         if "ssm_conv_kernel_layers" in attrs:
             detail.append(
                 f"conv_kernel_layers={int(attrs['ssm_conv_kernel_layers'])}")
+    if "short_conv_layers" in attrs:
+        detail.append(f"short_conv_layers={int(attrs['short_conv_layers'])}")
+        detail.append(
+            f"short_conv_kernel_layers={int(attrs['short_conv_kernel_layers'])}")
     if "hc_mappings" in attrs:
         detail.append(f"hc_mappings={int(attrs['hc_mappings'])}")
         detail.append(f"offdiag={attrs['hc_res_offdiag']:.3f}")

@@ -1146,11 +1146,14 @@ def _attention_block(x, layer, config: LlamaConfig, positions, mesh, rules,
 
 
 @jax.named_scope("short_conv")
-def _short_conv_block(x, layer, config: LlamaConfig, onto=None):
+def _short_conv_block(x, layer, config: LlamaConfig, mesh, rules, onto=None):
     """A convolution layer's mixer with its norm and residual, as
-    _attention_block is an attention layer's."""
+    _attention_block is an attention layer's, and the layer's counters
+    (models/short_conv.py). The mesh is for the gates' and taps' kernels,
+    which ride a shard_map over `batch`."""
     h = rms_norm(x, layer["conv_norm"], config.rms_eps, config.norm_offset)
-    return _add_branch(x, short_conv(h, layer).astype(x.dtype), config, onto)
+    out, stats = short_conv(h, layer, mesh, rules)
+    return _add_branch(x, out.astype(x.dtype), config, onto), stats
 
 
 @jax.named_scope("ssm")
@@ -1169,15 +1172,17 @@ def _ssm_block(x, layer, config: LlamaConfig, mesh, rules, onto=None):
 def _mixer_block(x, layer, config: LlamaConfig, positions, mesh, rules,
                  context_size, window=None, rope=None):
     """The layer's token mixer, by what the layer holds, and its counters
-    ({} but for a state-space layer, for a gated attention layer or one
-    of a model that chooses RoPE by layer, and for a layer of several
-    streams, whose mixer reads a mix of them: `_branch_input`)."""
+    ({} but for a state-space or convolution layer, for a gated
+    attention layer or one of a model that chooses RoPE by layer, and for
+    a layer of several streams, whose mixer reads a mix of them:
+    `_branch_input`)."""
     u, onto = _branch_input(x, layer.get("hc_mixer"), config, mesh, rules)
     if "ssm_in" in layer:
         y, stats = _ssm_block(u, layer, config, mesh, rules, onto)
         return y, {**stats, **_hc_counters(onto)}
     if "conv_in" in layer:
-        return _short_conv_block(u, layer, config, onto), _hc_counters(onto)
+        y, stats = _short_conv_block(u, layer, config, mesh, rules, onto)
+        return y, {**stats, **_hc_counters(onto)}
     y, stats = _attention_block(u, layer, config, positions, mesh, rules,
                                 context_size, window=window, onto=onto,
                                 rope=rope)
@@ -1649,7 +1654,10 @@ def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
     ssm_layers, ssm_conv_kernel_layers (the layers whose convolution ran
     as ops/causal_conv.py's kernels), ssm_chunks, ssm_kernel_chunks (the
     chunks that went through ops/ssm_scan.py's kernels) and, averaged
-    over those layers, ssm_dt_mean and ssm_state_carry; for a model of
+    over those layers, ssm_dt_mean and ssm_state_carry; for a model with
+    convolution layers (models/short_conv.py): short_conv_layers and
+    short_conv_kernel_layers (the layers whose gates and taps ran as
+    ops/causal_conv.py's kernels); for a model of
     several residual streams (models/hyper.py): hc_mappings and, over
     those mappings, hc_res_offdiag, hc_pre_mean, hc_post_mean (means)
     and hc_sinkhorn_residual (the largest); for a model with a

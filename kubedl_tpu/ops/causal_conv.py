@@ -42,6 +42,34 @@ once and dxBC written once. No padded copy, no float32 tensor.
 The columns of u beside xBC (z before, dt after) pass through
 `split_conv` untouched, so that its backward can hand u's cotangent back
 as one concatenation, which the in projection's backward needs anyway.
+
+The gated form, a convolution layer's gates and taps
+(models/short_conv.py): for u [b, t, 3d] = [B, C, z] by columns and taps
+w [d, K], both in the model's dtype,
+
+    g[t]  = dtype(B[t] * z[t])                            g zero before the sequence
+    c[t]  = dtype(sum_j f32(w[j]) * g[t - (K-1) + j])     float32 sum, j = 0 first
+    y[t]  = dtype(C[t] * c[t])                            `short_conv_fwd`
+
+and given dy, with g and c recomputed:
+
+    dC[t] = dtype(dy[t] * c[t]),    dc[t] = dtype(dy[t] * C[t])
+    dg[t] = dtype(sum_j f32(w[j]) * f32(dc[t + (K-1) - j]))   j = K-1 first, dc zero after
+    dB[t] = dtype(dg[t] * z[t]),    dz[t] = dtype(dg[t] * B[t])
+    dw[j] = sum_{i, t} f32(dc[t]) * f32(g[t - (K-1) + j])     `short_conv_bwd`
+
+the XLA form's operations at the rounding points where autodiff's jaxpr
+puts them: y is that form's bit for bit, and du and dw are its autodiff's
+taken one operation at a time, dw but for its float32 sum's order. XLA's
+fusion of that autodiff drops some of those roundings (its excess
+precision: on the CPU it keeps dc float32; on a v5e a sixth of du's
+elements and two fifths of dw's land a bf16 rounding away from the
+kernel's). The forward reads B, C and z where they lie in u and writes y
+once; the backward walks as `ssm_conv_bwd` does and writes du whole, so
+that the in projection's backward takes it with no concatenation: a
+program's three channel blocks of du are made once and leave in three
+steps of the grid's last axis, the second and third held in VMEM
+meanwhile.
 """
 from __future__ import annotations
 
@@ -106,15 +134,28 @@ def _up(cur, after, s: int):
     return jnp.concatenate([moved[:n - 8], tail], axis=0)
 
 
-def _pre(cur, before, w, bias, dtype):
-    """The pre-activation of a pass [rows, lanes] float32, and the pass
-    moved down by each tap's reach (tap j weighs `moved[j]`)."""
+def _taps(cur, before, w):
+    """The taps' sum over a pass [rows, lanes] float32, j = 0 first, and
+    the pass moved down by each tap's reach (tap j weighs `moved[j]`)."""
     k = len(w)
     moved = [_down(cur, before, k - 1 - j) for j in range(k)]
     taps = moved[0] * w[0]
     for j in range(1, k):
         taps = taps + moved[j] * w[j]
+    return taps, moved
+
+
+def _pre(cur, before, w, bias, dtype):
+    """The pre-activation of a pass [rows, lanes] float32, and the pass
+    moved down by each tap's reach."""
+    taps, moved = _taps(cur, before, w)
     return taps.astype(dtype).astype(_F32) + bias, moved
+
+
+def _fold(v):
+    """A sum over a pass's tokens, 8 rows apart: the rows are added up
+    once a channel block."""
+    return sum(v[r:r + 8] for r in range(0, v.shape[0], 8))
 
 
 def _sigmoid(v):
@@ -150,18 +191,15 @@ def _fwd_pass(cur, before, w, bias, *, dtype):
 def _bwd_pass(cur, before, dout, after, sums, w, bias, *, dtype):
     """A pass's dxBC, the first 8 rows of its ds (what the pass before
     it needs) and the running sums of dw's K taps and of dbias."""
-    k, rows = len(w), cur.shape[0]
+    k = len(w)
     pre, moved = _pre(cur, before, w, bias, dtype)
     sig = _sigmoid(pre)
     ds = dout.astype(_F32) * (sig * (1.0 + pre * (1.0 - sig)))
     dx = _up(ds, after, k - 1) * w[0]
     for t in range(1, k):
         dx = dx + _up(ds, after, k - 1 - t) * w[t]
-    # sums over a pass's tokens, 8 rows apart: the rows are added up once
-    # a channel block
-    fold = lambda v: sum(v[r:r + 8] for r in range(0, rows, 8))
-    sums = tuple(s + fold(ds * m) for s, m in zip(sums, moved)) + (
-        sums[k] + fold(ds),)
+    sums = tuple(s + _fold(ds * m) for s, m in zip(sums, moved)) + (
+        sums[k] + _fold(ds),)
     return dx.astype(dtype), ds[:8], sums
 
 
@@ -357,3 +395,194 @@ def split_conv(u: jax.Array, w: jax.Array, b: jax.Array, offset: int,
     # bare jax.grad would wrap it (ops/ssm_scan.py has the same).
     with jax.named_scope("ssm_conv_kernel"):
         return _split_conv(u, w, b, offset, tuple(widths))
+
+
+# -- the gated form: models/short_conv.py's gates and taps -----------------------
+
+
+def _gate(b, z, dtype):
+    """g = dtype(B * z) of two blocks of u, float32."""
+    return (b.astype(_F32) * z.astype(_F32)).astype(dtype).astype(_F32)
+
+
+def _gates_before(bh_ref, zh_ref, first, dtype):
+    """g of the 8 tokens before a block, float32; zero before a sequence."""
+    return jnp.where(first, 0.0, _gate(bh_ref[0], zh_ref[0], dtype)[_HALO - 8:])
+
+
+def _gated_fwd_kernel(b_ref, c_ref, z_ref, bh_ref, zh_ref, w_ref, y_ref, *, rows):
+    dtype, w = b_ref.dtype, _taps_of(w_ref)
+
+    def body(p, before):
+        at = pl.ds(pl.multiple_of(p * rows, rows), rows)
+        g = _gate(b_ref[0, at, :], z_ref[0, at, :], dtype)
+        c = _taps(g, before, w)[0].astype(dtype).astype(_F32)
+        y_ref[0, at, :] = (c_ref[0, at, :].astype(_F32) * c).astype(dtype)
+        return g[rows - 8:]
+
+    jax.lax.fori_loop(0, b_ref.shape[1] // rows, body,
+                      _gates_before(bh_ref, zh_ref, pl.program_id(1) == 0, dtype))
+
+
+def _gated_bwd_pass(b, c_gate, z, before, dy, after, sums, w, dtype):
+    """A pass's dB, dC and dz, the first 8 rows of its dc (what the pass
+    before it needs) and the running sums of dw's K taps."""
+    k = len(w)
+    g = _gate(b, z, dtype)
+    taps, moved = _taps(g, before, w)
+    dy = dy.astype(_F32)
+    dc_gate = (dy * taps.astype(dtype).astype(_F32)).astype(dtype)
+    dc = (dy * c_gate.astype(_F32)).astype(dtype).astype(_F32)
+    # autodiff's order: the tap on the token itself first
+    dg = dc * w[k - 1]
+    for j in range(k - 2, -1, -1):
+        dg = dg + _up(dc, after, k - 1 - j) * w[j]
+    dg = dg.astype(dtype).astype(_F32)
+    sums = tuple(s + _fold(dc * m) for s, m in zip(sums, moved))
+    return ((dg * z.astype(_F32)).astype(dtype), dc_gate,
+            (dg * b.astype(_F32)).astype(dtype), dc[:8], sums)
+
+
+def _gated_bwd_kernel(b_ref, c_ref, z_ref, bh_ref, zh_ref, w_ref, dy_ref,
+                      du_ref, dw_ref, after_ref, acc_ref, held_ref, *, rows):
+    i, ti, part = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    nb, nt = pl.num_programs(1), pl.num_programs(2)
+    dtype, w = b_ref.dtype, _taps_of(w_ref)
+    k, passes = len(w), b_ref.shape[1] // rows
+
+    def at_pass(c):
+        return pl.ds(pl.multiple_of(c * rows, rows), rows)
+
+    # part 0 computes the block's dB, dC and dz and writes dB; parts 1 and
+    # 2 write dC and dz, held in VMEM, into their own columns of du
+    @pl.when(part == 0)
+    def _():
+        @pl.when((i == 0) & (ti == 0))
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        @pl.when(ti == 0)  # the sequence's last block: nothing comes after
+        def _():
+            after_ref[...] = jnp.zeros_like(after_ref)
+
+        # blocks from the last: ti counts back
+        start = _gates_before(bh_ref, zh_ref, ti == nt - 1, dtype)
+
+        def body(step, carry):
+            c = passes - 1 - step
+            r0 = pl.multiple_of(c * rows, rows)
+            at = at_pass(c)
+            halo = pl.ds(pl.multiple_of(jnp.maximum(r0 - _HALO, 0), _HALO), _HALO)
+            before = jnp.where(c == 0, start, _gate(
+                b_ref[0, halo, :], z_ref[0, halo, :], dtype)[_HALO - 8:])
+            db, dc_gate, dz, after, sums = _gated_bwd_pass(
+                b_ref[0, at, :], c_ref[0, at, :], z_ref[0, at, :], before,
+                dy_ref[0, at, :], *carry, w, dtype)
+            du_ref[0, at, :] = db
+            held_ref[0, at, :] = dc_gate
+            held_ref[1, at, :] = dz
+            return after, sums
+
+        after, sums = jax.lax.fori_loop(
+            0, passes, body, (after_ref[...], tuple(acc_ref[m] for m in range(k))))
+        after_ref[...] = after
+        for m in range(k):
+            acc_ref[m] = sums[m]
+
+        @pl.when((i == nb - 1) & (ti == nt - 1))
+        def _():
+            dw_ref[...] = jnp.sum(acc_ref[...], axis=1)
+
+    @pl.when(part > 0)
+    def _():
+        def body(c, _):
+            du_ref[0, at_pass(c), :] = held_ref[part - 1, at_pass(c), :]
+            return 0
+
+        jax.lax.fori_loop(0, passes, body, 0)
+
+
+# one trace and one lowering for all of a step's calls, as above
+@jax.jit
+def _gated_fwd_call(u, w):
+    bsz, seq, width = u.shape
+    tq, nd = _token_block(seq), width // (3 * _LANES)
+    tiles = tq // _HALO
+    block = lambda part: pl.BlockSpec(
+        (1, tq, _LANES), lambda i, t, j: (i, t, part * nd + j))
+    halo = lambda part: pl.BlockSpec(
+        (1, _HALO, _LANES),
+        lambda i, t, j: (i, jnp.maximum(t * tiles - 1, 0), part * nd + j))
+    return pl.pallas_call(
+        functools.partial(_gated_fwd_kernel, rows=ROWS),
+        grid=(bsz, seq // tq, nd),
+        in_specs=[block(0), block(1), block(2), halo(0), halo(2),
+                  pl.BlockSpec((w.shape[1], _LANES), lambda i, t, j: (0, j))],
+        out_specs=pl.BlockSpec((1, tq, _LANES), lambda i, t, j: (i, t, j)),
+        out_shape=jax.ShapeDtypeStruct((bsz, seq, width // 3), u.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_VMEM),
+        interpret=interpret(),
+        name="short_conv_fwd",
+    )(u, u, u, u, u, w.astype(_F32).T)
+
+
+@jax.jit
+def _gated_bwd_call(u, w, dy):
+    bsz, seq, width = u.shape
+    tq, nd = _token_block(seq), width // (3 * _LANES)
+    k, nt, tiles = w.shape[1], seq // tq, tq // _HALO
+    back = lambda t: nt - 1 - t
+    block = lambda part: pl.BlockSpec(
+        (1, tq, _LANES), lambda j, i, t, p: (i, back(t), part * nd + j))
+    halo = lambda part: pl.BlockSpec(
+        (1, _HALO, _LANES),
+        lambda j, i, t, p: (i, jnp.maximum(back(t) * tiles - 1, 0), part * nd + j))
+    taps = pl.BlockSpec((k, _LANES), lambda j, i, t, p: (0, j))
+    du, dw = pl.pallas_call(
+        functools.partial(_gated_bwd_kernel, rows=ROWS),
+        grid=(nd, bsz, nt, 3),
+        in_specs=[block(0), block(1), block(2), halo(0), halo(2), taps,
+                  pl.BlockSpec((1, tq, _LANES), lambda j, i, t, p: (i, back(t), j))],
+        out_specs=[
+            pl.BlockSpec((1, tq, _LANES), lambda j, i, t, p: (i, back(t), p * nd + j)),
+            taps],
+        out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype),
+                   jax.ShapeDtypeStruct((k, width // 3), _F32)],
+        scratch_shapes=[pltpu.VMEM((8, _LANES), _F32),
+                        pltpu.VMEM((k, 8, _LANES), _F32),
+                        pltpu.VMEM((2, tq, _LANES), u.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM),
+        interpret=interpret(),
+        name="short_conv_bwd",
+    )(u, u, u, u, u, w.astype(_F32).T, dy)
+    return du, dw.T.astype(w.dtype)
+
+
+@jax.custom_vjp
+def _gated_conv(u, w):
+    return _gated_fwd_call(u, w)
+
+
+def _gated_conv_fwd(u, w):
+    return _gated_conv(u, w), (u, w)
+
+
+def _gated_conv_bwd(res, dy):
+    return _gated_bwd_call(*res, dy)
+
+
+_gated_conv.defvjp(_gated_conv_fwd, _gated_conv_bwd)
+
+
+def gated_conv(u: jax.Array, w: jax.Array) -> jax.Array:
+    """C * causal_taps(B * z, w) in u's dtype, for [B, C, z] = u [b, t, 3d]
+    split in three along its columns and taps w [d, K] (tap K-1 weighs
+    the token itself). `supports(t, 0, (d, d, d), K)` holds."""
+    # under this scope the kernels' instructions keep their own names, as
+    # `split_conv`'s do
+    with jax.named_scope("short_conv_kernel"):
+        return _gated_conv(u, w)

@@ -61,9 +61,10 @@ class StepRecorder:
         `train.compile` with their `fun`, times and `cache`.
         `counters`: what the step returned beside its loss and gradient
         norm (an expert model's `moe_*` / `gmm_*`, a looped stack's
-        `loop_*`, a state-space model's `ssm_*`, a several-stream model's
-        `hc_*`, a multi-token prediction module's `ce` and `mtp_*`, a
-        model with gated attention or RoPE chosen by layer `attn_*`:
+        `loop_*`, a state-space model's `ssm_*`, a model with convolution
+        layers `short_conv_*`, a several-stream model's `hc_*`, a
+        multi-token prediction module's `ce` and `mtp_*`, a model with
+        gated attention or RoPE chosen by layer `attn_*`:
         llama.loss_and_stats);
         they ride the step's record, read when its loss is."""
         if compiled:
@@ -842,7 +843,8 @@ def main(argv=None) -> int:
                             dispatch_span.dur, compile_log.since(compiles_before),
                             {k: v for k, v in metrics.items()
                              if k.startswith(("moe_", "gmm_", "loop_", "ssm_",
-                                              "hc_", "mtp_", "attn_")) or k == "ce"})
+                                              "short_conv_", "hc_", "mtp_",
+                                              "attn_")) or k == "ce"})
             if prof is not None and prof.should_stop(step):
                 settle(metrics["loss"])
                 prof.stop()

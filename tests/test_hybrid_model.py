@@ -369,7 +369,7 @@ def test_convolution_sees_no_token_after_its_own():
              "conv_out": jax.random.normal(ks[2], (d, d)) * 0.2}
     u = jax.random.normal(ks[3], (2, t, d))
     moved = u.at[:, at].add(jax.random.normal(ks[4], (2, d)))
-    a, b = short_conv(u, layer), short_conv(moved, layer)
+    a, b = short_conv(u, layer)[0], short_conv(moved, layer)[0]
     np.testing.assert_array_equal(np.asarray(a[:, :at]), np.asarray(b[:, :at]))
     # it reaches exactly two tokens back: t, t+1, t+2 move, t+3 does not
     changed = np.any(np.asarray(a != b), axis=(0, 2))

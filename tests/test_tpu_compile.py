@@ -335,6 +335,36 @@ def test_state_space_loss_holds_one_piece_of_logits_and_float32_carries(one_chip
     assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
 
 
+# ops/causal_conv.py's gated kernels, likewise
+SHORT_CONV_INSTRUCTIONS = ("%short_conv_fwd.", "%short_conv_bwd.")
+
+
+def test_convolution_layer_reads_its_gates_in_place_and_writes_du_whole(one_chip, on_tpu):
+    """An LFM2 convolution layer and an attention layer at the published
+    widths (dense FFNs, an eighth of the vocabulary), 2 x 8,192 tokens:
+    the gates and taps are their two kernels (the forward again in the
+    remat copy), reading B, C and z where they lie in the in projection's
+    [2, 8192, 6144] output and writing its cotangent whole, so no
+    [2, 8192, 2048] third of either is cut out or joined back, and no
+    float32 or padded copy of the gates exists."""
+    config = llama.LlamaConfig.lfm2_8b_a1b(
+        n_layers=2, layer_types=("conv", "attention"), n_dense_layers=2,
+        vocab_size=8192, max_seq_len=8192)
+    params = _abstract_params(
+        config, lambda t: jax.tree_util.tree_map(lambda _: one_chip, t))
+    tokens = jax.ShapeDtypeStruct((2, 8193), jnp.int32, sharding=one_chip)
+    text = _compile(jax.value_and_grad(lambda p, t: llama.loss_fn(p, t, config)),
+                    params, tokens)
+    assert _calls(text, SHORT_CONV_INSTRUCTIONS) == {
+        "%short_conv_fwd.": 2, "%short_conv_bwd.": 1}
+    # the XLA form cuts u in three (bf16[2,8192,2048] slices), pads the
+    # gates by the taps' reach ([2,8194,2048]) and joins du's thirds back
+    # by padding each to [2,8192,6144]
+    assert not re.search(r"\[2,8192,2048\]\{[^}]*\} slice\(", text)
+    assert not re.search(r"\[2,8192,6144\]\{[^}]*\} (pad|concatenate)\(", text)
+    assert "[2,8194,2048]" not in text
+
+
 # ops/hyper_mix.py's four kernels, likewise
 HC_INSTRUCTIONS = ("%hc_pre_fwd.", "%hc_post_fwd.", "%hc_post_bwd.", "%hc_pre_bwd.")
 

@@ -88,8 +88,9 @@ def test_kernel_names_are_the_ones_the_metrics_read():
     assert names == ["flash_bwd_dqkv", "flash_fwd",
                      "flash_fwd_streamed", "gmm", "gmm_drhs", "gmm_scaled",
                      "gmm_swiglu", "hc_post_bwd", "hc_post_fwd", "hc_pre_bwd",
-                     "hc_pre_fwd", "moe_gather", "ssm_conv_bwd",
-                     "ssm_conv_fwd", "ssm_scan_bwd", "ssm_scan_fwd"]
+                     "hc_pre_fwd", "moe_gather", "short_conv_bwd",
+                     "short_conv_fwd", "ssm_conv_bwd", "ssm_conv_fwd",
+                     "ssm_scan_bwd", "ssm_scan_fwd"]
 
 
 # the instruction each flash kernel defines, as a trace's event names it
@@ -183,6 +184,41 @@ def test_kernel_names_reach_the_lowered_text(lowered_text):
     assert ("rematted_computation/attn/attn_core/flash_attention/flash_fwd/"
             not in text)
     assert "flash_fwd" not in lowered_text[(False, True)]
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+def test_convolution_kernels_keep_their_names_under_the_scope(monkeypatch, devices):
+    """A convolution layer's gates and taps as their kernels (steered to
+    the form a TPU takes; interpreted here) in a lowered train step, on
+    one device and inside a shard_map over two: the call sits under the
+    scope `short_conv` in the forward, under `jax.grad`'s transpose and
+    in the remat copy, inside it `short_conv_kernel`, and each kernel's
+    operations under its own name, which its HLO instruction takes on a
+    TPU (tests/test_tpu_compile.py)."""
+    from kubedl_tpu.models import short_conv
+
+    monkeypatch.setattr(short_conv, "interpret", lambda: False)
+    config = llama.LlamaConfig.tiny(n_layers=2, layer_types=("conv", "attention"),
+                                    use_flash=False)
+    mesh = build_mesh({"fsdp": devices}, devices=jax.devices()[:devices])
+    rules = ShardingRules()
+    init_state, train_step = make_train_step(
+        lambda p, t: llama.loss_fn(p, t, config, mesh=mesh, rules=rules),
+        optax.adamw(3e-4), mesh, llama.param_specs(config, rules),
+        rules.spec("batch", None), rules)
+    params = jax.eval_shape(lambda k: llama.init(config, k), jax.random.PRNGKey(0))
+    state = jax.eval_shape(init_state.jit, params)
+    tokens = jax.ShapeDtypeStruct((2, 129), jnp.int32)
+    text = train_step.lower(state, tokens).as_text(debug_info=True)
+    # inside a shard_map the body's own names start again at its scope
+    call = "shard_map" if devices > 1 else "short_conv_kernel/"
+    for path in (r"jvp\(short_conv\)/",  # the forward
+                 r'transpose\([^"]*/checkpoint/rematted_computation/short_conv/',
+                 r'transpose\([^"]*/checkpoint/short_conv/'):  # the backward
+        assert re.search(r'"jit\(train_step\)/' + path + call, text), path
+    assert ('"short_conv_kernel/jit' in text) == (devices > 1)
+    for kernel in ("short_conv_fwd", "short_conv_bwd"):
+        assert f'"{kernel}/pallas_call"' in text, kernel
 
 
 # -- (c) spans on the profiler's clock ---------------------------------------
