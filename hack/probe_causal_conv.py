@@ -118,10 +118,10 @@ def calls_of(step, shape):
 
 def steered(module, call, kernel: bool, tiny: bool):
     """`call` of this checkout, its form chosen in `module` as
-    `hack/probe_ssm_scan.py` steers the scan: nothing on a TPU, the
-    backend's answer on the CPU, the choice itself for the XLA form."""
-    name, steer = (("conv_takes_kernel", lambda *a, **kw: False) if not kernel
-                   else ("interpret", lambda: False) if tiny else (None, None))
+    `hack/probe_ssm_scan.py` steers the scan: nothing for the kernels on
+    a TPU, the choice itself on the CPU and for the XLA form."""
+    name, steer = (("conv_takes_kernel", lambda *a, **kw: kernel) if tiny or not kernel
+                   else (None, None))
 
     def step(*args):
         was = getattr(module, name) if name else None

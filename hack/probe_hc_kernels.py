@@ -165,8 +165,8 @@ ARGS = (20, 1e-6, (-30.0, 30.0))  # the cell's iterations, eps, clamp
 def this_checkout(kernel: bool, tiny: bool):
     from kubedl_tpu.models import hyper
 
-    name, steer = (("mix_takes_kernel", lambda *a, **kw: False) if not kernel
-                   else ("interpret", lambda: False) if tiny else (None, None))
+    name, steer = (("mix_takes_kernel", lambda *a, **kw: kernel) if tiny or not kernel
+                   else (None, None))
 
     def step(x, y, hc):
         was = getattr(hyper, name) if name else None

@@ -82,10 +82,10 @@ def inputs_of(shape, seed=0):
 
 def calls_of(module, chunk: int, kernel: bool, tiny: bool):
     """Jitted forward and forward-with-backward of a checkout's scan."""
-    # what steers the choice while the scan is traced: nothing on a TPU,
-    # the backend's answer on the CPU, the choice itself for the XLA form
-    name, steer = (("scan_takes_kernel", lambda *a, **kw: False) if not kernel
-                   else ("interpret", lambda: False) if tiny else (None, None))
+    # what steers the choice while the scan is traced: nothing for the
+    # kernels on a TPU, the choice itself on the CPU and for the XLA form
+    name, steer = (("scan_takes_kernel", lambda *a, **kw: kernel) if tiny or not kernel
+                   else (None, None))
 
     def scan(*args):
         if name is None or not hasattr(module, name):  # or a tree before PR 33

@@ -144,16 +144,31 @@ def _grow_widths(widths, row) -> None:
             widths[i] = max(widths[i], len(str(cell)) + 2)
 
 
+# The DETAIL column's counters of a step, in order: (counter, label,
+# format), each shown where its record holds it ("d": a count). The
+# counters are the model's (llama.loss_and_stats, docs/observability.md).
+_STEP_DETAIL = (
+    ("ssm_layers", "ssm_layers", "d"), ("ssm_chunks", "chunks", "d"),
+    ("ssm_state_carry", "carry", ".3f"), ("ssm_dt_mean", "dt", ".4f"),
+    ("ssm_kernel_chunks", "kernel_chunks", "d"),
+    ("ssm_conv_kernel_layers", "conv_kernel_layers", "d"),
+    ("short_conv_layers", "short_conv_layers", "d"),
+    ("short_conv_kernel_layers", "short_conv_kernel_layers", "d"),
+    ("hc_mappings", "hc_mappings", "d"), ("hc_res_offdiag", "offdiag", ".3f"),
+    ("hc_sinkhorn_residual", "sinkhorn_residual", ".1e"),
+    ("hc_pre_mean", "pre", ".3f"), ("hc_post_mean", "post", ".3f"),
+    ("hc_kernel_mappings", "kernel_mappings", "d"),
+    ("attn_windowed_layers", "windowed_layers", "d"),
+    ("attn_nope_layers", "nope_layers", "d"), ("attn_gate_mean", "gate", ".3f"),
+    ("ce", "ce", ".4f"), ("mtp_ce", "mtp_ce", ".4f"),
+    ("mtp_positions", "mtp_positions", "d"),
+)
+
+
 def _span_detail(attrs) -> str:
     """The DETAIL column of `trace`: the attributes that say what a span
-    was, of a looped stack's step its passes and exit distribution (the
-    `loop_*` counters), of a state-space model's step what its scans
-    carried (the `ssm_*` counters), of a model with convolution layers
-    how many of them ran as kernels (`short_conv_*`), of a several-stream
-    model's step how its streams mixed (the `hc_*` counters), of a step
-    of gated or per-layer-RoPE attention its layer kinds and mean gate
-    (the `attn_*` counters) and of a multi-token prediction module's step
-    its two losses (docs/observability.md)."""
+    was, of a looped stack's step its passes and exit distribution, and
+    of any step the counters of `_STEP_DETAIL` it holds."""
     detail = [f"{k}={attrs[k]}" for k in
               ("step", "stage", "cause", "outcome", "shape", "reason", "error",
                "fun", "cache")
@@ -164,37 +179,8 @@ def _span_detail(attrs) -> str:
         detail.append("exit=" + "/".join(
             f"{attrs.get(f'loop_exit_mass_{t}', 0.0):.2f}"
             for t in range(1, passes + 1)))
-    if "ssm_layers" in attrs:
-        detail.append(f"ssm_layers={int(attrs['ssm_layers'])}")
-        detail.append(f"chunks={int(attrs['ssm_chunks'])}")
-        detail.append(f"carry={attrs['ssm_state_carry']:.3f}")
-        detail.append(f"dt={attrs['ssm_dt_mean']:.4f}")
-        if "ssm_kernel_chunks" in attrs:  # a record from before the kernels has none
-            detail.append(f"kernel_chunks={int(attrs['ssm_kernel_chunks'])}")
-        if "ssm_conv_kernel_layers" in attrs:
-            detail.append(
-                f"conv_kernel_layers={int(attrs['ssm_conv_kernel_layers'])}")
-    if "short_conv_layers" in attrs:
-        detail.append(f"short_conv_layers={int(attrs['short_conv_layers'])}")
-        detail.append(
-            f"short_conv_kernel_layers={int(attrs['short_conv_kernel_layers'])}")
-    if "hc_mappings" in attrs:
-        detail.append(f"hc_mappings={int(attrs['hc_mappings'])}")
-        detail.append(f"offdiag={attrs['hc_res_offdiag']:.3f}")
-        detail.append(f"sinkhorn_residual={attrs['hc_sinkhorn_residual']:.1e}")
-        detail.append(f"pre={attrs['hc_pre_mean']:.3f}")
-        detail.append(f"post={attrs['hc_post_mean']:.3f}")
-        if "hc_kernel_mappings" in attrs:  # a record from before the kernels has none
-            detail.append(f"kernel_mappings={int(attrs['hc_kernel_mappings'])}")
-    if "attn_windowed_layers" in attrs:
-        detail.append(f"windowed_layers={int(attrs['attn_windowed_layers'])}")
-        detail.append(f"nope_layers={int(attrs['attn_nope_layers'])}")
-        if "attn_gate_mean" in attrs:
-            detail.append(f"gate={attrs['attn_gate_mean']:.3f}")
-    if "mtp_ce" in attrs:
-        detail.append(f"ce={attrs['ce']:.4f}")
-        detail.append(f"mtp_ce={attrs['mtp_ce']:.4f}")
-        detail.append(f"mtp_positions={int(attrs['mtp_positions'])}")
+    detail += [f"{label}={format(int(attrs[k]) if fmt == 'd' else attrs[k], fmt)}"
+               for k, label, fmt in _STEP_DETAIL if k in attrs]
     return " ".join(detail)
 
 

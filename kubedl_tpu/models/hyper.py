@@ -50,7 +50,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from kubedl_tpu.ops import hyper_mix, interpret
+from kubedl_tpu import ops
+from kubedl_tpu.ops import hyper_mix
 from kubedl_tpu.parallel.mesh import ShardingRules
 
 
@@ -131,6 +132,13 @@ def _post_res(logits: jax.Array, hc: Dict, n: int, iters: int, eps: float,
     return post, res
 
 
+# how a step combines these counters (models/llama.py `_add_counters`):
+# the residual is the worst mapping's, the three means are over the mappings
+COMBINE = {"hc_sinkhorn_residual": "max",
+           **dict.fromkeys(("hc_res_offdiag", "hc_pre_mean", "hc_post_mean"),
+                           "hc_mappings")}
+
+
 def _stats(pre: jax.Array, post: jax.Array, res: jax.Array, n: int,
            kernel: bool) -> Dict:
     """A sublayer's counters from its mappings."""
@@ -199,14 +207,12 @@ def hc_sum(x: jax.Array, n: int) -> jax.Array:
 
 def mix_takes_kernel(seq: int, n: int, d: int, dtype, mesh=None) -> bool:
     """Whether a sublayer's mappings and mixes run as the Pallas kernels
-    (ops/hyper_mix.py): on a TPU, for streams in bfloat16 (whose values
-    the projection's split takes as exact), where a stream is whole
-    128-lane blocks and a sequence whole token tiles, and under no mesh
-    that shards the model's width (a Mosaic call cannot be partitioned;
-    over `batch` it rides a shard_map, each device on its own sequences)."""
-    if interpret() or dtype != jnp.bfloat16 or not hyper_mix.supports(seq, n, d):
-        return False
-    return mesh is None or mesh.shape.get("tensor", 1) == 1
+    (ops/hyper_mix.py): for streams in bfloat16 (whose values the
+    projection's split takes as exact), where a stream is whole 128-lane
+    blocks and a sequence whole token tiles, and where ops.takes_kernel
+    lets a kernel run."""
+    return (dtype == jnp.bfloat16 and hyper_mix.supports(seq, n, d)
+            and ops.takes_kernel(mesh))
 
 
 _POST_RES_LEAVES = ("a_post", "b_post", "a_res", "b_res")
@@ -223,19 +229,6 @@ class Onto(NamedTuple):
     rules: Optional[ShardingRules] = None
 
 
-def _on_rows(fn, mesh, rules, n_rows: int, n_whole: int, n_out: int):
-    """fn over the batch's rows, each device its own, where the mesh has
-    more than one (GSPMD cannot partition a Mosaic call); the whole
-    arguments' cotangents are summed over the devices by the map's
-    transpose."""
-    if mesh is None or mesh.size == 1:
-        return fn
-    rows = (rules or ShardingRules()).spec("batch", None, None)
-    out = rows if n_out == 1 else (rows,) * n_out
-    return jax.shard_map(fn, mesh=mesh, in_specs=(rows,) * n_rows + (P(),) * n_whole,
-                         out_specs=out, check_vma=False)
-
-
 def hc_branch(x: jax.Array, hc: Dict, n: int, iters: int, eps: float,
               clamp: Tuple[float, float], mesh=None,
               rules: Optional[ShardingRules] = None) -> Tuple[jax.Array, Onto]:
@@ -246,8 +239,10 @@ def hc_branch(x: jax.Array, hc: Dict, n: int, iters: int, eps: float,
     if mix_takes_kernel(t, n, d, x.dtype, mesh):
         # H_post and H_res inside `pre`, whose backward takes their VJP
         maps_of = functools.partial(_post_res, n=n, iters=iters, eps=eps, clamp=clamp)
-        pre = _on_rows(lambda x_, w, a, b_, p: hyper_mix.pre(x_, w, a, b_, p, maps_of, eps),
-                       mesh, rules, 1, 4, 5)
+        rows = (rules or ShardingRules()).spec("batch", None, None)
+        pre = ops.on_rows(
+            lambda x_, w, a, b_, p: hyper_mix.pre(x_, w, a, b_, p, maps_of, eps),
+            mesh, (rows,) + (P(),) * 4, (rows,) * 5)
         with jax.named_scope("hc_map"):
             u, lp, post, res, x = pre(x, _projection(hc), hc["a_pre"], hc["b_pre"],
                                       {k: hc[k] for k in _POST_RES_LEAVES})
@@ -267,23 +262,9 @@ def hc_merge(onto: Onto, y: jax.Array) -> jax.Array:
     b, t, nd = x.shape
     n = mapping["post"].shape[-1]
     if onto.kernel:
-        post = _on_rows(hyper_mix.post, onto.mesh, onto.rules, 4, 0, 1)
+        rows = (onto.rules or ShardingRules()).spec("batch", None, None)
+        post = ops.on_rows(hyper_mix.post, onto.mesh, (rows,) * 4, rows)
         with jax.named_scope("hc_mix"):
             return post(x, y, mapping["post"], mapping["res"])
     return hc_mix(x.reshape(b, t, n, nd // n), y, mapping).reshape(b, t, nd)
 
-
-# the one counter that layers combine by their largest, not their sum
-WORST_OF = ("hc_sinkhorn_residual",)
-
-
-def finish_stats(stats: Dict) -> Dict:
-    """The step's hc_* counters from the sublayers' sums: the count stays
-    a sum, the means are over the mappings, the residual is the worst
-    mapping's (`WORST_OF`: whoever adds counters up takes its largest)."""
-    if "hc_mappings" not in stats:
-        return stats
-    out = dict(stats)
-    for k in ("hc_res_offdiag", "hc_pre_mean", "hc_post_mean"):
-        out[k] = out[k] / out["hc_mappings"]
-    return out

@@ -28,8 +28,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from kubedl_tpu.models.hyper import (WORST_OF, finish_stats, hc_branch, hc_init,
-                                     hc_merge, hc_param_specs, hc_streams, hc_sum)
+from kubedl_tpu import ops
+from kubedl_tpu.models import PRESETS, hyper, moe, ssm
+from kubedl_tpu.models.hyper import (hc_branch, hc_init, hc_merge, hc_param_specs,
+                                     hc_streams, hc_sum)
 from kubedl_tpu.models.mla import mla_init, mla_param_specs, mla_qkv
 from kubedl_tpu.models.moe import moe_init, moe_layer, moe_param_specs
 from kubedl_tpu.models.quant import matmul as _mm
@@ -491,23 +493,12 @@ class LlamaConfig:
 
     @staticmethod
     def config_for(name: str) -> "LlamaConfig":
-        """Named configs shared by the trainer/generate CLIs."""
-        factories = {
-            "tiny": LlamaConfig.tiny,
-            "bench-150m": LlamaConfig.bench_150m,
-            "bench-1b": LlamaConfig.bench_1b,
-            "llama-7b": LlamaConfig.llama_7b,
-            "lfm2-8b-a1b": LlamaConfig.lfm2_8b_a1b,
-            "ouro-2.6b": LlamaConfig.ouro_2_6b,
-            "granite-4.0-h-micro": LlamaConfig.granite_4_0_h_micro,
-            "xing4.0-29b-a4b": LlamaConfig.xing4_0_29b_a4b,
-            "trinity-large-preview": LlamaConfig.trinity_large_preview,
-        }
-        if name not in factories:
+        """Named configs shared by the trainer/generate CLIs (PRESETS)."""
+        if name not in PRESETS:
             raise ValueError(
-                f"unknown model {name!r} (choose from {sorted(factories)})"
+                f"unknown model {name!r} (choose from {sorted(PRESETS)})"
             )
-        return factories[name]()
+        return getattr(LlamaConfig, name.replace("-", "_").replace(".", "_"))()
 
     @staticmethod
     def lfm2_8b_a1b(**kw) -> "LlamaConfig":
@@ -1006,17 +997,46 @@ def _hc_counters(onto) -> Dict:
     return {} if onto is None else onto.mapping["stats"]
 
 
+# attention's own declaration: the gate's two means are over the gated
+# layers, whose count the step then leaves out
+_ATTN_COMBINE = {"attn_gate_mean": "attn_gated_layers",
+                 "attn_gate_spread": "attn_gated_layers",
+                 "attn_gated_layers": "drop"}
+
+# How a step's counters combine, as the module that makes them declares it
+# (its COMBINE; a counter it leaves out is a sum). Over sublayers, layers
+# and passes: a sum, or the largest ("max"). Once the step's are all in
+# (`_finish_counters`, in this order): a counter declared with a count's
+# name is divided by that count (a mean), one declared "drop" is left out,
+# and one declared with a function is finished by it, fn(counters, config).
+_COMBINE = {**hyper.COMBINE, **ssm.COMBINE, **_ATTN_COMBINE, **moe.COMBINE}
+
+
 def _add_counters(into: Dict, new: Dict) -> Dict:
-    """`into` with a sublayer's or a layer's counters added: sums, but
-    for the few that are a largest (models/hyper.py WORST_OF)."""
+    """`into` with a sublayer's or a layer's counters added (`_COMBINE`)."""
     for k, v in new.items():
         if k not in into:
             into[k] = v
-        elif k in WORST_OF:
+        elif _COMBINE.get(k) == "max":
             into[k] = jnp.maximum(into[k], v)
         else:
             into[k] = into[k] + v
     return into
+
+
+def _finish_counters(stats: Dict, config: "LlamaConfig") -> Dict:
+    """The step's counters from their sums (`_COMBINE`)."""
+    stats = dict(stats)
+    for k, rule in _COMBINE.items():
+        if k not in stats or rule == "max":
+            continue
+        if rule == "drop":
+            del stats[k]
+        elif callable(rule):
+            rule(stats, config)
+        else:
+            stats[k] = stats[k] / stats[rule]
+    return stats
 
 
 # The named scopes below (embed, attn > attn_core, mlp, head_loss) reach
@@ -1058,14 +1078,9 @@ def _attention_core(q, k, v, config: LlamaConfig, mesh, rules, context_size,
         flash = functools.partial(
             flash_attention, causal=True, window=window, softcap=softcap,
             **scale)
-        if mesh is not None and mesh.size > 1:
-            # GSPMD cannot partition a Mosaic kernel: each device runs it
-            # on its own batch and head shard (attention mixes neither)
-            spec = rules.spec("batch", "heads", None, None)
-            flash = jax.shard_map(
-                flash, mesh=mesh, in_specs=(spec, spec, spec),
-                out_specs=spec, check_vma=False)
-        return flash(q, k, v)
+        # each device on its own batch and head shard (attention mixes neither)
+        spec = (rules or ShardingRules()).spec("batch", "heads", None, None)
+        return ops.on_rows(flash, mesh, (spec, spec, spec), spec)(q, k, v)
     from kubedl_tpu.ops.flash_attention import attention_reference
 
     return attention_reference(q, k, v, causal=True, window=window,
@@ -1344,8 +1359,9 @@ def _backbone(
 
     _, (states, aux, stats) = _scan_passes(
         one_pass, x, None, length=config.total_ut_steps)
-    return states, jnp.sum(aux), jax.tree_util.tree_map(
-        lambda v: jnp.sum(v, axis=0), stats)
+    return states, jnp.sum(aux), {
+        k: (jnp.max if _COMBINE.get(k) == "max" else jnp.sum)(v, axis=0)
+        for k, v in sorted(stats.items())}
 
 
 # the loop over the passes of a looped stack (hack/probe_loop_shape.py
@@ -1643,30 +1659,11 @@ def loss_fn(params, tokens, config: LlamaConfig, mesh=None, rules=None):
 
 def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
     """(loss_fn's loss, the step's counters): what
-    make_train_step(has_aux=True) returns as metrics beside the loss.
-    {} for a dense model run once; for a model with expert layers on the
-    single-device dropless route, summed over its layers:
-    moe_rows_routed, moe_rows_held, gmm_live_tiles, gmm_grid_tiles,
-    moe_rows_moved, moe_rows_spanned, and moe_load_max_over_mean over
-    the held experts; for a looped stack (_looped_loss): loop_passes,
-    loop_layer_applications, loop_exit_mass_<t>, loop_ce_<t>,
-    loop_exit_entropy; for a model with state-space layers (models/ssm.py):
-    ssm_layers, ssm_conv_kernel_layers (the layers whose convolution ran
-    as ops/causal_conv.py's kernels), ssm_chunks, ssm_kernel_chunks (the
-    chunks that went through ops/ssm_scan.py's kernels) and, averaged
-    over those layers, ssm_dt_mean and ssm_state_carry; for a model with
-    convolution layers (models/short_conv.py): short_conv_layers and
-    short_conv_kernel_layers (the layers whose gates and taps ran as
-    ops/causal_conv.py's kernels); for a model of
-    several residual streams (models/hyper.py): hc_mappings and, over
-    those mappings, hc_res_offdiag, hc_pre_mean, hc_post_mean (means)
-    and hc_sinkhorn_residual (the largest); for a model with a
-    multi-token prediction module (_mtp_loss), whose loss enters at
-    mtp_loss_weight: ce (the next token's cross entropy alone), mtp_ce,
-    mtp_positions, and the module's block in every layer counter; for a
-    model with gated attention or RoPE chosen by layer:
-    attn_windowed_layers, attn_nope_layers and, over the gated layers,
-    attn_gate_mean and attn_gate_spread (docs/observability.md)."""
+    make_train_step(has_aux=True) returns as metrics beside the loss, and
+    what the trainer's step record carries. {} for a dense model run once;
+    otherwise float32 scalars, each named by the module that makes it and
+    combined over sublayers, layers and passes as that module declares
+    (`_COMBINE`). docs/observability.md lists them."""
     rules = rules or ShardingRules()
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     chunked = config.ce_chunks > 1
@@ -1692,24 +1689,7 @@ def loss_and_stats(params, tokens, config: LlamaConfig, mesh=None, rules=None):
                                          rules, chunked)
         stats = _add_counters({**stats, "ce": ce}, mtp_stats)
         ce, aux = ce + config.mtp_loss_weight * ce2, aux + aux2
-    stats = finish_stats(stats)
-    if "ssm_layers" in stats:
-        # the layers' means were summed over the layers with their count
-        stats = dict(stats)
-        for k in ("ssm_dt_mean", "ssm_state_carry"):
-            stats[k] = stats[k] / stats["ssm_layers"]
-    if "attn_gated_layers" in stats:
-        # the gated layers' means were summed over the layers with their count
-        stats = dict(stats)
-        gated = stats.pop("attn_gated_layers")
-        for k in ("attn_gate_mean", "attn_gate_spread"):
-            stats[k] = stats[k] / gated
-    if "moe_rows_fullest" in stats:
-        stats = dict(stats)
-        held = config.n_experts_held or config.n_experts
-        stats["moe_load_max_over_mean"] = (
-            stats.pop("moe_rows_fullest") * held
-            / jnp.maximum(stats["moe_rows_held"], 1.0))
+    stats = _finish_counters(stats, config)
     return ce + config.moe_aux_coef * aux, stats
 
 

@@ -335,6 +335,20 @@ def _dispatch_stats(eid: jax.Array, e: int) -> Dict:
     }
 
 
+def _load_max_over_mean(stats: Dict, config) -> None:
+    """The step's moe_load_max_over_mean, in place of the layers' summed
+    moe_rows_fullest: the fullest held expert's rows over a held expert's
+    mean."""
+    held = config.n_experts_held or config.n_experts
+    stats["moe_load_max_over_mean"] = (
+        stats.pop("moe_rows_fullest") * held
+        / jnp.maximum(stats["moe_rows_held"], 1.0))
+
+
+# how a step combines these counters (models/llama.py `_add_counters`)
+COMBINE = {"moe_rows_fullest": _load_max_over_mean}
+
+
 # ---------------------------------------------------------------------------
 # dropless dispatch stages. _gmm_ffn composes plan -> permute -> ffn ->
 # gather, each under a named scope of its own (moe_route, moe_permute,

@@ -38,8 +38,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from kubedl_tpu import ops
 from kubedl_tpu.models.quant import matmul as _mm
-from kubedl_tpu.ops import causal_conv, interpret
+from kubedl_tpu.ops import causal_conv
 from kubedl_tpu.parallel.mesh import ShardingRules
 
 
@@ -83,13 +84,10 @@ def causal_taps(g: jax.Array, w: jax.Array) -> jax.Array:
 
 def conv_takes_kernel(seq: int, d: int, taps: int, mesh=None) -> bool:
     """Whether the gates and taps run as the Pallas kernels
-    (ops/causal_conv.py): on a TPU, where B, C and z are whole 128-lane
-    blocks of the in projection's output and the sequence whole 128-token
-    blocks, and under no mesh that shards the channels (over `batch` it
-    rides a shard_map, as models/ssm.py's convolution does)."""
-    if interpret() or not causal_conv.supports(seq, 0, (d, d, d), taps):
-        return False
-    return mesh is None or mesh.shape.get("tensor", 1) == 1
+    (ops/causal_conv.py): where B, C and z are whole 128-lane blocks of
+    the in projection's output and the sequence whole 128-token blocks,
+    and where ops.takes_kernel lets a kernel run."""
+    return causal_conv.supports(seq, 0, (d, d, d), taps) and ops.takes_kernel(mesh)
 
 
 def gated_taps(u: jax.Array, w: jax.Array, mesh=None,
@@ -102,14 +100,8 @@ def gated_taps(u: jax.Array, w: jax.Array, mesh=None,
     and the mesh: ops/causal_conv.py's kernels or the XLA operations
     below."""
     if conv_takes_kernel(u.shape[1], u.shape[2] // 3, w.shape[1], mesh):
-        conv = causal_conv.gated_conv
-        if mesh is not None and mesh.size > 1:
-            # each device its own sequences; the taps' gradient is summed
-            # over the devices by the map's transpose
-            rows = (rules or ShardingRules()).spec("batch", None, None)
-            conv = jax.shard_map(conv, mesh=mesh, in_specs=(rows, P()),
-                                 out_specs=rows, check_vma=False)
-        return conv(u, w), True
+        rows = (rules or ShardingRules()).spec("batch", None, None)
+        return ops.on_rows(causal_conv.gated_conv, mesh, (rows, P()), rows)(u, w), True
     b_, c_, z = jnp.split(u, 3, axis=-1)
     return c_ * causal_taps(b_ * z, w), False
 

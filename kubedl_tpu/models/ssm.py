@@ -57,9 +57,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from kubedl_tpu import ops
 from kubedl_tpu.models.quant import matmul as _mm
 from kubedl_tpu.models.short_conv import causal_taps
-from kubedl_tpu.ops import causal_conv, interpret, ssm_scan
+from kubedl_tpu.ops import causal_conv, ssm_scan
 from kubedl_tpu.parallel.mesh import ShardingRules
 
 
@@ -109,26 +110,24 @@ def ssm_init(key: jax.Array, d_model: int, heads: int, head_dim: int,
 def scan_takes_kernel(x_shape: Tuple[int, ...], state: int, chunk: int,
                       mesh=None) -> bool:
     """Whether chunked_scan runs as the Pallas kernels (ops/ssm_scan.py):
-    on a TPU, at shapes of whole tiles, and under no mesh that shards the
-    inner channels (a Mosaic call cannot be partitioned; over `batch` it
-    rides a shard_map)."""
+    at shapes of whole tiles, and where ops.takes_kernel lets a kernel
+    run."""
     _, t, h, p = x_shape
-    if interpret() or not ssm_scan.supports(h, p, state, chunk, t):
-        return False
-    return mesh is None or mesh.shape.get("tensor", 1) == 1
+    return ssm_scan.supports(h, p, state, chunk, t) and ops.takes_kernel(mesh)
 
 
 @jax.named_scope("ssm_scan")
 def chunked_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b_: jax.Array,
                  c_: jax.Array, chunk: int, mesh=None,
                  rules: Optional[ShardingRules] = None
-                 ) -> Tuple[jax.Array, jax.Array]:
+                 ) -> Tuple[jax.Array, jax.Array, bool]:
     """y_t = S_t C_t for S_t = exp(dt_t a) S_{t-1} + dt_t x_t B_t^T, S_0 = 0.
 
     x [b, t, h, p] and b_, c_ [b, t, n] in the model's dtype; dt [b, t, h]
     (after the softplus) and a [h] (negative) float32. Returns y
-    [b, t, h, p] float32 and, [b, chunks, h], the product of each chunk's
-    decays: the share of a chunk's incoming state that leaves it.
+    [b, t, h, p] float32; [b, chunks, h], the product of each chunk's
+    decays: the share of a chunk's incoming state that leaves it; and
+    whether the kernels made them.
 
     A sequence that is no multiple of the chunk is padded with steps of
     dt = 0, which leave the state alone and whose outputs are dropped.
@@ -148,16 +147,12 @@ def chunked_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b_: jax.Array,
     f32, dtype = jnp.float32, x.dtype
     if scan_takes_kernel((bsz, t, h, p), n, chunk, mesh):
         cum = jnp.cumsum((dt * a).reshape(bsz, nc, q, h), axis=2)
-        scan = functools.partial(ssm_scan.scan, chunk=q)
-        if mesh is not None and mesh.size > 1:
-            # GSPMD cannot partition a Mosaic kernel: each device scans
-            # its own sequences (the scan mixes no two)
-            rows = (rules or ShardingRules()).spec("batch", None, None)
-            scan = jax.shard_map(scan, mesh=mesh, in_specs=(rows,) * 5,
-                                 out_specs=rows, check_vma=False)
+        rows = (rules or ShardingRules()).spec("batch", None, None)
+        scan = ops.on_rows(functools.partial(ssm_scan.scan, chunk=q), mesh,
+                           (rows,) * 5, rows)
         y = scan(x.reshape(bsz, nc * q, h * p), dt, cum.reshape(bsz, nc * q, h),
                  b_, c_)
-        return y.reshape(bsz, nc * q, h, p)[:, :t], jnp.exp(cum[:, :, -1])
+        return y.reshape(bsz, nc * q, h, p)[:, :t], jnp.exp(cum[:, :, -1]), True
     xdt = (x.astype(f32) * dt[..., None]).astype(dtype).reshape(bsz, nc, q, h, p)
     b_, c_ = b_.reshape(bsz, nc, q, n), c_.reshape(bsz, nc, q, n)
     # log of the decay from a chunk's start through each of its tokens
@@ -193,20 +188,17 @@ def chunked_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b_: jax.Array,
     y = y + jnp.einsum(
         "bcln,cbhpn->bclhp", c_, entering.astype(dtype),
         preferred_element_type=f32) * jnp.exp(cum)[..., None]
-    return y.reshape(bsz, nc * q, h, p)[:, :t], through
+    return y.reshape(bsz, nc * q, h, p)[:, :t], through, False
 
 
 def conv_takes_kernel(seq: int, d_inner: int, state: int, taps: int,
                       mesh=None) -> bool:
     """Whether the convolution runs as the Pallas kernels
-    (ops/causal_conv.py): on a TPU, where xBC and its three parts are
-    whole 128-lane blocks of the in projection's output and the sequence
-    whole 128-token blocks, and under no mesh that shards the inner
-    channels (over `batch` it rides a shard_map, as the scan does)."""
-    if interpret() or not causal_conv.supports(
-            seq, d_inner, (d_inner, state, state), taps):
-        return False
-    return mesh is None or mesh.shape.get("tensor", 1) == 1
+    (ops/causal_conv.py): where xBC and its three parts are whole 128-lane
+    blocks of the in projection's output and the sequence whole 128-token
+    blocks, and where ops.takes_kernel lets a kernel run."""
+    return (causal_conv.supports(seq, d_inner, (d_inner, state, state), taps)
+            and ops.takes_kernel(mesh))
 
 
 def split_conv(h: jax.Array, w: jax.Array, bias: jax.Array, d_inner: int,
@@ -222,14 +214,10 @@ def split_conv(h: jax.Array, w: jax.Array, bias: jax.Array, d_inner: int,
     and write x, B and C apart, or the XLA operations below."""
     widths = (d_inner, state, state)
     if conv_takes_kernel(h.shape[1], d_inner, state, w.shape[1], mesh):
-        conv = functools.partial(causal_conv.split_conv, offset=d_inner,
-                                 widths=widths)
-        if mesh is not None and mesh.size > 1:
-            # each device its own sequences; the taps' and the bias's
-            # gradients are summed over the devices by the map's transpose
-            rows = (rules or ShardingRules()).spec("batch", None, None)
-            conv = jax.shard_map(conv, mesh=mesh, in_specs=(rows, P(), P()),
-                                 out_specs=(rows,) * 5, check_vma=False)
+        rows = (rules or ShardingRules()).spec("batch", None, None)
+        conv = ops.on_rows(functools.partial(causal_conv.split_conv, offset=d_inner,
+                                             widths=widths),
+                           mesh, (rows, P(), P()), (rows,) * 5)
         with jax.named_scope("ssm_conv"):
             return conv(h, w, bias), True
     z, xbc, dt = jnp.split(h, [d_inner, 2 * d_inner + 2 * state], axis=-1)
@@ -238,6 +226,11 @@ def split_conv(h: jax.Array, w: jax.Array, bias: jax.Array, d_inner: int,
             causal_taps(xbc, w).astype(jnp.float32) + bias).astype(h.dtype)
     x, b_, c_ = jnp.split(xbc, [d_inner, d_inner + state], axis=-1)
     return (z, x, b_, c_, dt), False
+
+
+# how a step combines these counters (models/llama.py `_add_counters`):
+# the two means are over the layers
+COMBINE = dict.fromkeys(("ssm_dt_mean", "ssm_state_carry"), "ssm_layers")
 
 
 def ssm_mixer(u: jax.Array, layer: Dict, heads: int, head_dim: int,
@@ -255,8 +248,8 @@ def ssm_mixer(u: jax.Array, layer: Dict, heads: int, head_dim: int,
         d_inner, state, mesh, rules)
     x = x.reshape(bsz, t, heads, head_dim)
     dt = jax.nn.softplus(dt.astype(f32) + layer["ssm_dt_bias"])
-    y, through = chunked_scan(x, dt, -jnp.exp(layer["ssm_A_log"]), b_, c_, chunk,
-                              mesh, rules)
+    y, through, scan_kernel = chunked_scan(
+        x, dt, -jnp.exp(layer["ssm_A_log"]), b_, c_, chunk, mesh, rules)
     y = y + x.astype(f32) * layer["ssm_D"][:, None]
     with jax.named_scope("ssm_gate_norm"):
         g = y.reshape(bsz, t, d_inner) * jax.nn.silu(z.astype(f32))
@@ -266,8 +259,7 @@ def ssm_mixer(u: jax.Array, layer: Dict, heads: int, head_dim: int,
     stats = {"ssm_layers": jnp.ones((), f32),
              "ssm_conv_kernel_layers": jnp.asarray(conv_kernel, f32),
              "ssm_chunks": jnp.asarray(chunks, f32),
-             "ssm_kernel_chunks": jnp.asarray(
-                 chunks * scan_takes_kernel(x.shape, state, chunk, mesh), f32),
+             "ssm_kernel_chunks": jnp.asarray(chunks * scan_kernel, f32),
              "ssm_dt_mean": jnp.mean(dt),
              "ssm_state_carry": jnp.mean(through)}
     return _mm(g, layer["ssm_out"]), stats

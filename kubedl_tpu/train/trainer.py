@@ -24,6 +24,7 @@ import time
 from collections import deque
 from typing import Optional
 
+from kubedl_tpu.models import PRESETS
 from kubedl_tpu.obs.compiles import summed
 
 # steps the recorder leaves on the device while it waits for an older one
@@ -60,13 +61,8 @@ class StepRecorder:
         nothing for an ordinary step): such a step is written as
         `train.compile` with their `fun`, times and `cache`.
         `counters`: what the step returned beside its loss and gradient
-        norm (an expert model's `moe_*` / `gmm_*`, a looped stack's
-        `loop_*`, a state-space model's `ssm_*`, a model with convolution
-        layers `short_conv_*`, a several-stream model's `hc_*`, a
-        multi-token prediction module's `ce` and `mtp_*`, a model with
-        gated attention or RoPE chosen by layer `attn_*`:
-        llama.loss_and_stats);
-        they ride the step's record, read when its loss is."""
+        norm, the model's counters (llama.loss_and_stats); they ride the
+        step's record, read when its loss is."""
         if compiled:
             # the steps before it ended while the host compiled, or
             # earlier: their records end where this dispatch began, so
@@ -110,9 +106,7 @@ class StepRecorder:
 def parse_args(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--model", default=os.environ.get("KUBEDL_MODEL", "tiny"),
-                   choices=["tiny", "bench-1b", "llama-7b", "lfm2-8b-a1b",
-                            "ouro-2.6b", "granite-4.0-h-micro",
-                            "xing4.0-29b-a4b", "trinity-large-preview"])
+                   choices=PRESETS)
     p.add_argument("--steps", type=int, default=int(os.environ.get("KUBEDL_STEPS", 100)))
     p.add_argument("--batch", type=int, default=int(os.environ.get("KUBEDL_BATCH", 8)))
     p.add_argument("--seq-len", type=int, default=int(os.environ.get("KUBEDL_SEQ_LEN", 512)))
@@ -468,10 +462,8 @@ def main(argv=None) -> int:
                         step_loss = loss_on(a_mesh)
                     else:
                         def step_loss(params, batch):
-                            # (loss, the counters of an expert model, a
-                            # looped stack, state-space layers, several
-                            # streams or a multi-token module, which ride the
-                            # train.step record: {} for a dense model run once)
+                            # (loss, the counters that ride the train.step
+                            # record: {} for a dense model run once)
                             return llama.loss_and_stats(
                                 params, batch, config, mesh=a_mesh, rules=rules)
                     return make_train_step(
@@ -842,9 +834,7 @@ def main(argv=None) -> int:
                             step + 1, metrics["loss"], t_step0, data_span.dur,
                             dispatch_span.dur, compile_log.since(compiles_before),
                             {k: v for k, v in metrics.items()
-                             if k.startswith(("moe_", "gmm_", "loop_", "ssm_",
-                                              "short_conv_", "hc_", "mtp_",
-                                              "attn_")) or k == "ce"})
+                             if k not in ("loss", "grad_norm")})
             if prof is not None and prof.should_stop(step):
                 settle(metrics["loss"])
                 prof.stop()

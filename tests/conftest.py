@@ -7,6 +7,8 @@ must be in the environment before JAX makes its backends.
 import os
 import sys
 
+import pytest
+
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
@@ -21,3 +23,22 @@ CPU_ENV = {
     "JAX_PLATFORMS": "cpu",
     "XLA_FLAGS": "",
 }
+
+
+@pytest.fixture
+def kernel_form(monkeypatch):
+    """The layers choose their Pallas kernels as on a TPU: the one rule,
+    `kubedl_tpu.ops.takes_kernel`, answers as where the backend compiles
+    Mosaic, its mesh half as it stands. The kernels themselves still run
+    interpreted."""
+    from unittest import mock
+
+    from kubedl_tpu import ops
+
+    rule = ops.takes_kernel
+
+    def on_a_tpu(mesh=None):
+        with mock.patch.object(ops, "interpret", lambda: False):
+            return rule(mesh)
+
+    monkeypatch.setattr(ops, "takes_kernel", on_a_tpu)

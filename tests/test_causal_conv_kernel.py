@@ -7,9 +7,9 @@ mesh; `ssm_conv_kernel_layers`; remat; two devices. What Mosaic refuses
 is `tests/test_tpu_compile.py`'s to see.
 
 On the CPU `split_conv` takes the XLA form whatever the shape
-(`conv_takes_kernel` asks `ops.interpret`): the `kernel_form` fixture
-steers that one question in the test, and the kernels themselves still
-run interpreted. The scan stays XLA's here (`tests/test_ssm_scan_kernel.py`
+(`conv_takes_kernel` asks `ops.takes_kernel`): the `kernel_form` fixture
+(tests/conftest.py) steers that one rule in the test, and the kernels
+themselves still run interpreted. The scan stays XLA's here (`tests/test_ssm_scan_kernel.py`
 has its kernels), and a program's token block is cut to 128 tokens in
 passes of 32, so that a sequence of 384 crosses two block edges."""
 import dataclasses
@@ -38,9 +38,8 @@ def small_blocks(monkeypatch):
 
 
 @pytest.fixture
-def kernel_form(monkeypatch):
+def kernel_form(kernel_form, monkeypatch):
     """`split_conv` chooses as it would on a TPU; the scan stays XLA's."""
-    monkeypatch.setattr(ssm, "interpret", lambda: False)
     monkeypatch.setattr(ssm, "scan_takes_kernel", lambda *a, **kw: False)
 
 
@@ -159,17 +158,17 @@ def test_a_tokens_change_reaches_no_output_before_it(kernel_form):
     (27, 64, 16, 4, False),        # tests/test_ssm_model.py's size
 ])
 def test_the_form_is_chosen_from_shapes_backend_and_mesh(
-        monkeypatch, seq, d_inner, state, taps, takes):
+        request, seq, d_inner, state, taps, takes):
     assert causal_conv.supports(seq, d_inner, (d_inner, state, state), taps) == takes
     assert not ssm.conv_takes_kernel(seq, d_inner, state, taps)  # the CPU: XLA's
-    monkeypatch.setattr(ssm, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     assert ssm.conv_takes_kernel(seq, d_inner, state, taps) == takes
     mesh = lambda **axes: type("Mesh", (), {"shape": axes, "size": 4})()
     assert ssm.conv_takes_kernel(seq, d_inner, state, taps, mesh(fsdp=4)) == takes
     assert not ssm.conv_takes_kernel(seq, d_inner, state, taps, mesh(fsdp=2, tensor=2))
 
 
-def test_an_unaligned_width_or_nine_taps_trace_the_xla_form(monkeypatch):
+def test_an_unaligned_width_or_nine_taps_trace_the_xla_form(request):
     """No pallas_call where the shapes are not whole tiles, whatever the
     backend: the same equations the CPU traces."""
     def grad_of(d_inner, taps):
@@ -181,7 +180,7 @@ def test_an_unaligned_width_or_nine_taps_trace_the_xla_form(monkeypatch):
     cases = [(200, 4), (D_INNER, 9), (D_INNER, 4)]
     on_cpu = [grad_of(*case) for case in cases]
     assert not any("pallas_call" in text for text in on_cpu)
-    monkeypatch.setattr(ssm, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     assert [grad_of(*case) for case in cases[:2]] == on_cpu[:2]
     text = grad_of(*cases[2])
     assert all(f"name={k}" in text for k in KERNELS)
@@ -219,14 +218,14 @@ def model(seq, **kw):
 
 
 @pytest.mark.parametrize("seq,took", [(256, True), (300, False)])
-def test_kernel_layers_are_counted_where_the_kernels_ran(monkeypatch, seq, took):
+def test_kernel_layers_are_counted_where_the_kernels_ran(monkeypatch, request, seq, took):
     config, params, tokens = model(seq)
     stats_of = lambda: jax.jit(
         lambda p: llama.loss_and_stats(p, tokens, config))(params)
     loss, stats = stats_of()
     assert float(stats["ssm_layers"]) == 2
     assert float(stats["ssm_conv_kernel_layers"]) == 0  # the CPU: XLA's form
-    monkeypatch.setattr(ssm, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     monkeypatch.setattr(ssm, "scan_takes_kernel", lambda *a, **kw: False)
     loss_k, stats_k = stats_of()
     assert float(stats_k["ssm_conv_kernel_layers"]) == 2 * took

@@ -12,6 +12,7 @@ import pytest
 
 from kubedl_tpu.api.common import JobConditionType, has_condition
 from kubedl_tpu.core.store import NotFound
+from kubedl_tpu.models import PRESETS
 from kubedl_tpu.operator import Operator, OperatorConfig
 
 from fake_workload import TEST_KIND, TestJobController
@@ -218,6 +219,17 @@ def test_ttl_cleanup_end_to_end():
             pytest.fail("job was not TTL-deleted")
     finally:
         op.stop()
+
+
+@pytest.mark.parametrize("name", PRESETS)
+def test_trainer_model_choices_are_the_preset_table(name):
+    """The trainer's --model takes every name of the one preset table,
+    each a config `LlamaConfig.config_for` serves."""
+    from kubedl_tpu.models import llama
+    from kubedl_tpu.train.trainer import parse_args
+
+    assert parse_args(["--model", name]).model == name
+    assert isinstance(llama.LlamaConfig.config_for(name), llama.LlamaConfig)
 
 
 def test_trainer_memory_knobs_run_end_to_end():

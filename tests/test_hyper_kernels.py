@@ -8,9 +8,9 @@ one-stream model untouched; remat; two devices. What Mosaic refuses is
 `tests/test_tpu_compile.py`'s to see.
 
 On the CPU `hc_branch` takes the XLA form whatever the shape
-(`mix_takes_kernel` asks `ops.interpret`): the `kernel_form` fixture
-steers that one question in the test, and the kernels themselves still
-run interpreted."""
+(`mix_takes_kernel` asks `ops.takes_kernel`): the `kernel_form` fixture
+(tests/conftest.py) steers that one rule in the test, and the kernels
+themselves still run interpreted."""
 import dataclasses
 import functools
 import re
@@ -29,12 +29,6 @@ KERNELS = ("hc_pre_fwd", "hc_post_fwd", "hc_post_bwd", "hc_pre_bwd")
 N = 4
 ARGS = (20, 1e-6, (-30.0, 30.0))  # Sinkhorn's iterations, eps, clamp
 BF16_ULP = 2.0 ** -8  # the largest relative rounding to bfloat16
-
-
-@pytest.fixture
-def kernel_form(monkeypatch):
-    """`hc_branch` chooses as it would on a TPU."""
-    monkeypatch.setattr(hyper, "interpret", lambda: False)
 
 
 def leaves(d, seed):
@@ -156,7 +150,7 @@ def test_two_mappings_gradients_are_nearer_the_float32_gradient_than_autodiffs(
     grad = jax.jit(jax.grad(two_mappings, argnums=(0, 1, 2, 3)))
     got = grad(*args)
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(hyper, "interpret", lambda: True)
+        mp.setattr(hyper, "mix_takes_kernel", lambda *a, **kw: False)
         xla = jax.jit(jax.grad(two_mappings, argnums=(0, 1, 2, 3)))(*args)
         exact = jax.jit(jax.grad(two_mappings, argnums=(0, 1, 2, 3)))(
             x.astype(jnp.float32), *args[1:])
@@ -180,11 +174,11 @@ def test_two_mappings_gradients_are_nearer_the_float32_gradient_than_autodiffs(
     (64, 256, jnp.float32, None, False),  # streams the split cannot take exactly
 ])
 def test_the_form_is_chosen_from_shapes_backend_dtype_and_mesh(
-        monkeypatch, seq, d, dtype, mesh_axes, takes):
+        request, seq, d, dtype, mesh_axes, takes):
     mesh = None if mesh_axes is None else type(
         "Mesh", (), {"shape": mesh_axes, "size": 4})()
     assert not hyper.mix_takes_kernel(seq, N, d, dtype, mesh)  # the CPU
-    monkeypatch.setattr(hyper, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     assert hyper.mix_takes_kernel(seq, N, d, dtype, mesh) == takes
 
 
@@ -235,7 +229,7 @@ def steady_gaps(got, want, exact):
 
 
 def test_a_step_counts_its_kernel_mappings_and_is_as_near_the_float32_step_as_xlas(
-        monkeypatch):
+        monkeypatch, request):
     """`hc_kernel_mappings`: 0 where the XLA form ran (the CPU), all of
     `hc_mappings` where the kernels did; the loss and the other counters
     as the XLA form's, and the gradient as near the float32 model's, with
@@ -247,7 +241,7 @@ def test_a_step_counts_its_kernel_mappings_and_is_as_near_the_float32_step_as_xl
     (loss_x, stats_x), g_x = step(config)
     assert float(stats_x["hc_kernel_mappings"]) == 0 and float(stats_x["hc_mappings"]) == 6
     _, g_e = step(dataclasses.replace(config, dtype=jnp.float32), f32(params))
-    monkeypatch.setattr(hyper, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     text = str(jax.make_jaxpr(jax.grad(lambda p: llama.loss_fn(p, tokens, config)))(params))
     assert all(f"name={k}" in text for k in KERNELS)
     results = [step(config), step(dataclasses.replace(config, remat=False))]
@@ -270,7 +264,7 @@ def test_a_step_counts_its_kernel_mappings_and_is_as_near_the_float32_step_as_xl
     assert float(on) == pytest.approx(float(off), rel=1e-5)
 
 
-def test_a_one_stream_models_program_is_the_same_whatever_the_choice(monkeypatch):
+def test_a_one_stream_models_program_is_the_same_whatever_the_choice(monkeypatch, request):
     """hc_mult 1: no mapping, no kernel, and the jaxpr of the step the
     same whether or not the kernels would be taken."""
     config = llama.LlamaConfig.tiny(d_model=256, dtype=jnp.bfloat16)
@@ -280,7 +274,7 @@ def test_a_one_stream_models_program_is_the_same_whatever_the_choice(monkeypatch
     jaxpr = lambda: re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(jax.value_and_grad(
         lambda p: llama.loss_fn(p, tokens, config)))(params)))
     plain = jaxpr()
-    monkeypatch.setattr(hyper, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     monkeypatch.setattr(hyper, "mix_takes_kernel", lambda *a, **kw: True)
     assert jaxpr() == plain
     assert not any(k in plain for k in KERNELS) and "hc_" not in plain
@@ -298,7 +292,7 @@ def test_two_devices_under_fsdp_ride_a_shard_map_and_give_the_xla_forms_loss(
     assert "shard_map" in text and all(f"name={k}" in text for k in KERNELS)
     two = jax.jit(fn)(params)
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(hyper, "interpret", lambda: True)
+        mp.setattr(hyper, "mix_takes_kernel", lambda *a, **kw: False)
         xla = jax.jit(fn)(params)
     assert float(two[0]) == pytest.approx(float(xla[0]), rel=1e-4)
     # the mappings' leaves take the sum of both devices' sequences: one

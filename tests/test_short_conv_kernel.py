@@ -8,9 +8,9 @@ sequence; which form `gated_taps` takes, by shape, backend and mesh; the
 `tests/test_tpu_compile.py`'s to see.
 
 On the CPU `gated_taps` takes the XLA form whatever the shape
-(`conv_takes_kernel` asks `ops.interpret`): the `kernel_form` fixture
-steers that one question in the test, and the kernels themselves still
-run interpreted. A program's token block is cut to 128 tokens in passes
+(`conv_takes_kernel` asks `ops.takes_kernel`): the `kernel_form` fixture
+(tests/conftest.py) steers that one rule in the test, and the kernels
+themselves still run interpreted. A program's token block is cut to 128 tokens in passes
 of 32, so that a sequence of 384 crosses two block edges."""
 import dataclasses
 
@@ -31,12 +31,6 @@ D = 256
 def small_blocks(monkeypatch):
     monkeypatch.setattr(causal_conv, "TOKEN_BLOCK", 128)
     monkeypatch.setattr(causal_conv, "ROWS", 32)
-
-
-@pytest.fixture
-def kernel_form(monkeypatch):
-    """`gated_taps` chooses as it would on a TPU."""
-    monkeypatch.setattr(short_conv, "interpret", lambda: False)
 
 
 def xla_form(u, w):
@@ -193,17 +187,17 @@ def test_no_token_reads_a_later_one_or_another_sequence(kernel_form):
     (48, 64, 3, False),        # tests/test_hybrid_model.py's size
 ])
 def test_the_form_is_chosen_from_shapes_backend_and_mesh(
-        monkeypatch, seq, d, taps, takes):
+        request, seq, d, taps, takes):
     assert causal_conv.supports(seq, 0, (d, d, d), taps) == takes
     assert not short_conv.conv_takes_kernel(seq, d, taps)  # the CPU: XLA's
-    monkeypatch.setattr(short_conv, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     assert short_conv.conv_takes_kernel(seq, d, taps) == takes
     mesh = lambda **axes: type("Mesh", (), {"shape": axes, "size": 4})()
     assert short_conv.conv_takes_kernel(seq, d, taps, mesh(fsdp=4)) == takes
     assert not short_conv.conv_takes_kernel(seq, d, taps, mesh(fsdp=2, tensor=2))
 
 
-def test_an_unaligned_shape_or_nine_taps_trace_the_xla_form(monkeypatch):
+def test_an_unaligned_shape_or_nine_taps_trace_the_xla_form(request):
     """No pallas_call where the shapes are not whole tiles, whatever the
     backend: the same equations the CPU traces."""
     def grad_of(d, taps):
@@ -214,7 +208,7 @@ def test_an_unaligned_shape_or_nine_taps_trace_the_xla_form(monkeypatch):
     cases = [(200, 3), (D, 9), (D, 3)]
     on_cpu = [grad_of(*case) for case in cases]
     assert not any("pallas_call" in text for text in on_cpu)
-    monkeypatch.setattr(short_conv, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     assert [grad_of(*case) for case in cases[:2]] == on_cpu[:2]
     assert all(f"name={k}" in grad_of(*cases[2]) for k in KERNELS)
     # no split of u, no padded copy of the gates, no concatenation of du
@@ -240,14 +234,14 @@ def model(seq, **kw):
 
 
 @pytest.mark.parametrize("seq,took", [(256, True), (300, False)])
-def test_kernel_layers_are_counted_where_the_kernels_ran(monkeypatch, seq, took):
+def test_kernel_layers_are_counted_where_the_kernels_ran(request, seq, took):
     config, params, tokens = model(seq)
     stats_of = lambda: jax.jit(
         lambda p: llama.loss_and_stats(p, tokens, config))(params)
     loss, stats = stats_of()
     assert float(stats["short_conv_layers"]) == 2
     assert float(stats["short_conv_kernel_layers"]) == 0  # the CPU: XLA's form
-    monkeypatch.setattr(short_conv, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     loss_k, stats_k = stats_of()
     assert float(stats_k["short_conv_kernel_layers"]) == 2 * took
     assert float(stats_k["short_conv_layers"]) == 2

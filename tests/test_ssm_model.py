@@ -103,7 +103,7 @@ def scan_inputs(seq, seed=0):
 def test_chunked_scan_is_the_token_by_token_recurrence(chunk, seq):
     x, dt, a, b_, c_ = scan_inputs(seq)
     with jax.default_matmul_precision("highest"):
-        y, through = jax.jit(ssm.chunked_scan, static_argnums=5)(x, dt, a, b_, c_, chunk)
+        y, through, _ = jax.jit(ssm.chunked_scan, static_argnums=5)(x, dt, a, b_, c_, chunk)
     want = granite_ref.recurrence(x, jnp.exp(dt * a), dt, b_, c_, block=8)
     assert y.shape == want.shape == x.shape
     assert float(jnp.max(jnp.abs(y - want))) < 2e-5 * float(jnp.max(jnp.abs(want)))

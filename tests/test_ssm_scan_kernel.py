@@ -6,9 +6,9 @@ form `chunked_scan` takes, by shape and backend; `ssm_kernel_chunks`;
 remat. What Mosaic refuses is `tests/test_tpu_compile.py`'s to see.
 
 On the CPU `chunked_scan` takes the XLA form whatever the shape
-(`scan_takes_kernel` asks `ops.interpret`): the `kernel_form` fixture
-steers that one question in the test, and the kernels themselves still
-run interpreted."""
+(`scan_takes_kernel` asks `ops.takes_kernel`): the `kernel_form` fixture
+(tests/conftest.py) steers that one rule in the test, and the kernels
+themselves still run interpreted."""
 import contextlib
 import dataclasses
 
@@ -26,12 +26,6 @@ from kubedl_tpu.ops import ssm_scan
 STATE = 128
 KERNELS = ("ssm_scan_fwd", "ssm_scan_bwd")
 INPUTS = ("x", "dt", "a", "B", "C")
-
-
-@pytest.fixture
-def kernel_form(monkeypatch):
-    """`chunked_scan` chooses as it would on a TPU."""
-    monkeypatch.setattr(ssm, "interpret", lambda: False)
 
 
 @contextlib.contextmanager
@@ -77,9 +71,9 @@ def test_kernel_is_the_recurrence_and_the_xla_form(kernel_form, batch, seq, head
     args = scan_inputs(batch, seq, heads)
     assert ssm.scan_takes_kernel(args[0].shape, STATE, chunk)
     with jax.default_matmul_precision("highest"):
-        y, through = jax.jit(ssm.chunked_scan, static_argnums=5)(*args, chunk)
+        y, through, _ = jax.jit(ssm.chunked_scan, static_argnums=5)(*args, chunk)
         with xla_form():
-            y_xla, through_xla = jax.jit(
+            y_xla, through_xla, _ = jax.jit(
                 lambda *a: ssm.chunked_scan(*a, chunk))(*args)
     want = recurrence(*args)
     assert y.shape == want.shape == args[0].shape and y.dtype == jnp.float32
@@ -150,11 +144,11 @@ def test_bf16_operands_stay_within_their_rounding_of_the_xla_form(kernel_form):
     (8, 8, 128, 128, 512, False),      # eight heads fill half a lane tile
 ])
 def test_the_form_is_chosen_from_shapes_backend_and_mesh(
-        monkeypatch, heads, head_dim, state, chunk, seq, takes):
+        request, heads, head_dim, state, chunk, seq, takes):
     shape = (2, seq, heads, head_dim)
     assert ssm_scan.supports(heads, head_dim, state, chunk, seq) == takes
     assert not ssm.scan_takes_kernel(shape, state, chunk)  # the CPU: XLA's
-    monkeypatch.setattr(ssm, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     assert ssm.scan_takes_kernel(shape, state, chunk) == takes
     mesh = lambda **axes: type("Mesh", (), {"shape": axes, "size": 4})()
     assert ssm.scan_takes_kernel(shape, state, chunk, mesh(fsdp=4)) == takes
@@ -162,7 +156,7 @@ def test_the_form_is_chosen_from_shapes_backend_and_mesh(
 
 
 def test_an_unaligned_shape_traces_the_xla_form_and_an_aligned_one_the_kernels(
-        monkeypatch):
+        request):
     """No pallas_call where the shapes are not whole tiles, whatever the
     backend: the same equations the CPU traces. At whole tiles the two
     kernel names, and no [b, c, h, q, q] array."""
@@ -174,7 +168,7 @@ def test_an_unaligned_shape_traces_the_xla_form_and_an_aligned_one_the_kernels(
     on_cpu = jaxpr_of(scan(), *small), jaxpr_of(grad(), *aligned)
     assert not any("pallas_call" in text for text in on_cpu)
     assert "f32[1,2,8,128,128]" in on_cpu[1]
-    monkeypatch.setattr(ssm, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     assert jaxpr_of(scan(), *small) == on_cpu[0]
     text = jaxpr_of(grad(), *aligned)
     assert all(f"name={k}" in text for k in KERNELS)
@@ -211,14 +205,14 @@ def model(seq, **kw):
 
 
 @pytest.mark.parametrize("seq,chunks", [(256, 2), (300, 3), (100, 1)])
-def test_kernel_chunks_are_counted_where_the_kernels_ran(monkeypatch, seq, chunks):
+def test_kernel_chunks_are_counted_where_the_kernels_ran(request, seq, chunks):
     config, params, tokens = model(seq)
     stats_of = lambda: jax.jit(
         lambda p: llama.loss_and_stats(p, tokens, config))(params)
     loss, stats = stats_of()
     assert float(stats["ssm_chunks"]) == 2 * 2 * chunks
     assert float(stats["ssm_kernel_chunks"]) == 0  # the CPU: XLA's form
-    monkeypatch.setattr(ssm, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     loss_k, stats_k = stats_of()
     took = seq >= 128  # a sequence under one chunk stays XLA's
     assert float(stats_k["ssm_kernel_chunks"]) == took * 2 * 2 * chunks
@@ -226,12 +220,32 @@ def test_kernel_chunks_are_counted_where_the_kernels_ran(monkeypatch, seq, chunk
     assert float(loss_k) == pytest.approx(float(loss), rel=1e-5)
 
 
-def test_remat_on_and_off_agree_and_both_are_the_xla_forms_gradient(monkeypatch):
+def test_kernel_chunks_are_read_from_the_form_each_scan_took(monkeypatch):
+    """`ssm_kernel_chunks` is what each layer's scan did, not a second
+    answer of the predicate: one that says yes to its first question
+    alone leaves the count at the chunks of the one scan that ran as the
+    kernels."""
+    config, params, tokens = model(256)
+    fn = lambda p: llama.loss_and_stats(p, tokens, config)
+
+    def first_yes():
+        answers = iter([True])
+        monkeypatch.setattr(ssm, "scan_takes_kernel", lambda *a, **kw: next(answers, False))
+
+    first_yes()
+    assert jaxpr_of(fn, params).count("name=ssm_scan_fwd") == 1
+    first_yes()
+    _, stats = jax.jit(fn)(params)
+    assert float(stats["ssm_chunks"]) == 2 * 2 * 2  # two layers of two sequences
+    assert float(stats["ssm_kernel_chunks"]) == 2 * 2
+
+
+def test_remat_on_and_off_agree_and_both_are_the_xla_forms_gradient(request):
     config, params, tokens = model(256)
     grad = lambda c: jax.jit(jax.value_and_grad(
         lambda p: llama.loss_fn(p, tokens, c)))(params)
     loss_xla, g_xla = grad(config)
-    monkeypatch.setattr(ssm, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     assert "ssm_scan_bwd" in jaxpr_of(
         jax.grad(lambda p: llama.loss_fn(p, tokens, config)), params)
     (on, g_on), (off, g_off) = grad(config), grad(dataclasses.replace(config, remat=False))
@@ -249,12 +263,12 @@ def test_remat_on_and_off_agree_and_both_are_the_xla_forms_gradient(monkeypatch)
 
 
 def test_two_devices_under_fsdp_ride_a_shard_map_and_give_the_one_device_loss(
-        monkeypatch):
+        request):
     from kubedl_tpu.parallel.mesh import ShardingRules, build_mesh
 
     config, params, tokens = model(256)
     one = jax.jit(jax.value_and_grad(lambda p: llama.loss_fn(p, tokens, config)))(params)
-    monkeypatch.setattr(ssm, "interpret", lambda: False)
+    request.getfixturevalue("kernel_form")
     mesh, rules = build_mesh({"fsdp": 2}, devices=jax.devices()[:2]), ShardingRules()
     fn = lambda p: llama.loss_fn(p, tokens, config, mesh=mesh, rules=rules)
     text = jaxpr_of(jax.grad(fn), params)

@@ -187,7 +187,7 @@ def test_kernel_names_reach_the_lowered_text(lowered_text):
 
 
 @pytest.mark.parametrize("devices", [1, 2])
-def test_convolution_kernels_keep_their_names_under_the_scope(monkeypatch, devices):
+def test_convolution_kernels_keep_their_names_under_the_scope(kernel_form, devices):
     """A convolution layer's gates and taps as their kernels (steered to
     the form a TPU takes; interpreted here) in a lowered train step, on
     one device and inside a shard_map over two: the call sits under the
@@ -195,9 +195,6 @@ def test_convolution_kernels_keep_their_names_under_the_scope(monkeypatch, devic
     in the remat copy, inside it `short_conv_kernel`, and each kernel's
     operations under its own name, which its HLO instruction takes on a
     TPU (tests/test_tpu_compile.py)."""
-    from kubedl_tpu.models import short_conv
-
-    monkeypatch.setattr(short_conv, "interpret", lambda: False)
     config = llama.LlamaConfig.tiny(n_layers=2, layer_types=("conv", "attention"),
                                     use_flash=False)
     mesh = build_mesh({"fsdp": devices}, devices=jax.devices()[:devices])
